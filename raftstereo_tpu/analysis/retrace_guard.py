@@ -30,16 +30,15 @@ The guard counts process-wide (any thread): e2e budgets deliberately
 include compiles triggered on the batcher/stream worker threads.  It
 REFUSES to run when a persistent JAX compilation cache is configured —
 deserialized executables skip the backend-compile event, so the count
-would be meaningless (and that cache is known-broken on this container:
-CHANGES.md PR 2).
+would be meaningless (CPU runs, where the guard is used, set no cache:
+utils/platform.setup_compile_cache).
 """
 
 from __future__ import annotations
 
 import contextlib
-import os
 import threading
-from typing import Iterator, List, Optional
+from typing import Iterator, List
 
 __all__ = ["RetraceBudgetExceeded", "retrace_guard", "compile_events"]
 
@@ -76,17 +75,6 @@ def _ensure_installed() -> None:
         _installed = True
 
 
-def _persistent_cache_dir() -> Optional[str]:
-    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        return os.environ["JAX_COMPILATION_CACHE_DIR"]
-    try:
-        import jax
-
-        return jax.config.jax_compilation_cache_dir
-    except Exception:  # config flag not present on this jax
-        return None
-
-
 def compile_events() -> int:
     """Backend compiles observed since the guard was first installed."""
     _ensure_installed()
@@ -114,14 +102,16 @@ def retrace_guard(budget: int, what: str = "",
     ``min_duration_s`` each).  Yields a :class:`GuardReport` whose
     counts are valid after the block exits."""
     assert budget >= 0, budget
-    cache_dir = _persistent_cache_dir()
+    from ..utils.platform import compile_cache_dir
+
+    cache_dir = compile_cache_dir()
     if cache_dir:
         raise RuntimeError(
             f"retrace_guard requires no persistent JAX compile cache "
             f"(JAX_COMPILATION_CACHE_DIR={cache_dir!r}): deserialized "
             "executables skip the backend-compile event, so budgets "
-            "would not measure compiles — and that cache is "
-            "known-broken on this container (CHANGES.md PR 2)")
+            "would not measure compiles (CPU runs set no cache: "
+            "utils/platform.setup_compile_cache)")
     _ensure_installed()
     with _lock:
         start = len(_durations)

@@ -19,6 +19,7 @@ from __future__ import annotations
 import argparse
 import collections
 import dataclasses
+import json
 import logging
 import os
 import statistics
@@ -36,6 +37,7 @@ from ..eval.validate import validate_sl
 from ..models import RAFTStereo
 from ..models.raft_stereo import count_parameters
 from ..parallel import batch_sharded, make_mesh, replicated
+from ..parallel.context import use_corr_mesh
 from ..train.checkpoint import CheckpointManager, PreemptionGuard, save_weights
 from ..train.logger import Logger
 from ..train.optim import make_optimizer
@@ -206,6 +208,19 @@ def train(model_cfg, cfg: TrainConfig, dataset=None,
         raise ValueError(f"batch_size {cfg.batch_size} not divisible by "
                          f"{n_data} data-parallel devices")
     logger.info("Mesh: %s", dict(mesh.shape))
+    from ..utils.platform import describe_runtime
+
+    # Which devices, what every backend-keyed kernel gate resolves to in
+    # THIS process under the mesh the step traces with, and which device
+    # holds which rows of the batch (chip_smoke.py holds a chip run to
+    # this line).
+    with use_corr_mesh(mesh):
+        runtime = describe_runtime(model_cfg, cfg.batch_size, cfg.image_size)
+    runtime["batch_rows_by_device"] = {
+        str(d.id): [idx[0].start or 0, idx[0].stop or cfg.batch_size]
+        for d, idx in batch_sharded(mesh).devices_indices_map(
+            (cfg.batch_size,)).items()}
+    logger.info("runtime: %s", json.dumps(runtime))
 
     ckpt_dir = os.path.join(cfg.checkpoint_dir, cfg.name)
     manager = CheckpointManager(ckpt_dir, keep=cfg.keep_checkpoints,

@@ -75,10 +75,11 @@ class RAFTStereoConfig:
     fused_encoder: Optional[bool] = None
 
     # Test-mode GRU step backend (ops/pallas_gru.py).  "auto" resolves to
-    # the fused Pallas megakernel (motion encoder + gru0 gates + flow head
-    # in one VMEM-resident kernel per iteration) on a single-device TPU
-    # backend and to the XLA reference step everywhere else; "fused"/"xla"
-    # pin one numeric path (the fused step matches the XLA step to fp32
+    # the XLA reference step on every backend; "fused" is the Pallas
+    # megakernel (motion encoder + gru0 gates + flow head in one
+    # VMEM-resident kernel per iteration), which has only run in interpret
+    # mode — Mosaic has not lowered it at flagship size (PR 24);
+    # "fused"/"xla" pin one numeric path (the fused step matches the XLA step to fp32
     # accumulation-order tolerance, not bitwise).  Train-mode tracing and
     # device meshes always take the XLA step.  Serving executables are
     # cache-keyed by the RESOLVED backend (serve/engine.py).
@@ -1188,9 +1189,9 @@ def add_model_args(parser: argparse.ArgumentParser) -> None:
                         "numeric policy, inference only")
     g.add_argument("--gru_backend", choices=["auto", "fused", "xla"],
                    default="auto",
-                   help="test-mode GRU step backend: 'auto' = fused Pallas "
-                        "megakernel on single-device TPU, XLA elsewhere "
-                        "(ops/pallas_gru.py)")
+                   help="test-mode GRU step backend: 'auto' = the XLA "
+                        "step; 'fused' = the Pallas megakernel, which "
+                        "has not lowered on a chip yet (ops/pallas_gru.py)")
     g.add_argument("--remat", action="store_true",
                    help="rematerialize each GRU iteration in backward: "
                         "O(1) activation memory instead of O(iters); "

@@ -28,7 +28,7 @@ def _stem_layer1(enc, x):
     TPU.  ``x`` is the normalized input image.
 
     The plain path's four layer1 instance norms at flagship resolution
-    cost ~21 ms of XLA layout churn (measured — docs/perf_notes_r03.md);
+    cost ~21 ms of XLA layout churn (measured);
     the fused pipeline (ops/pallas_encoder.py) keeps the whole stage in
     row-major packed form.  When conv1 is stride 1 (downsample <= 2) it
     joins the pipeline as a packed Pallas 7x7 kernel too — removing the
@@ -78,7 +78,7 @@ def _stem_layer1(enc, x):
         # row tap — the first formulation rolled the 128-wide fp32
         # accumulator per offset and measured a net LOSS; restructured,
         # the stride-2 path flips to a +2.5-4% realtime win (alternating
-        # same-process A/B — the chip drifts, docs/perf_notes_r04.md).
+        # same-process A/B — the chip drifts).
         ok_geom = (x.shape[-1] == 3 and local_imgs <= 4 and local_h >= 3
                    and (stride == 1
                         or (x.shape[1] % 2 == 0 and x.shape[2] % 4 == 0)))
@@ -108,7 +108,7 @@ def _trunk_layer2(enc, x):
     """layer2 (two ResidualBlocks, first stride-2 + projection), with the
     fused Pallas fast path on TPU: round-5 profiling puts ~15 ms of the
     flagship fixed stage in XLA's layer2+ convs and their blocked-layout
-    relayouts (docs/perf_notes_r05.md); the fused stage keeps everything
+    relayouts; the fused stage keeps everything
     row-major (ops/pallas_layer2.py).  Numerically pinned against this
     exact module path in tests/test_pallas_layer2.py."""
     from ..ops.pallas_layer2 import (fused_layer2, fused_layer2_bn,

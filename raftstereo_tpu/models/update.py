@@ -32,7 +32,7 @@ tap_head_override = None
 def _use_tap_head() -> bool:
     """The tap-matmul form of the narrow 3x3 head conv is a TPU fix (N=2
     output channels waste the MXU's 128 N-lanes — measured 3.5 TF/s,
-    costing as much as a 256->128 conv; docs/perf_notes_r03.md).  CPU/GPU
+    costing as much as a 256->128 conv).  CPU/GPU
     keep the plain conv.  The tap combination has two epilogues chosen by
     per-shard batch inside tap_conv3x3 (both A/B-measured, the tap form
     wins at every batch size with the right epilogue)."""
@@ -60,7 +60,7 @@ def tap_conv3x3(conv_mod, y):
     z_t = y . K[t] is pointwise — so one (ci -> 9*co) matmul (padded to a
     full MXU N-tile instead of 2/128 lanes) replaces the narrow conv.
     Two epilogues combine the taps, chosen by per-shard batch
-    (alternating same-process A/Bs, docs/perf_notes_r04.md):
+    (alternating same-process A/Bs):
 
     * batch <= 2: 9 shifted adds of the 28x-smaller z (batch 1
       9.80 -> 10.45 pairs/sec vs plain; realtime +2.8%; the selector
@@ -219,7 +219,7 @@ class ConvGRU(nn.Module):
         # kernel[:, :, hd:] convolves x, summed — arithmetically identical
         # (a conv is linear in its input channels), parameters unchanged.
         # The concats are real HBM round trips inside the scan loop
-        # (~1.3 ms/iter at batch 8, profiled — docs/perf_notes_r03.md).
+        # (~1.3 ms/iter at batch 8, profiled).
         zr = (_sliced_conv(self.convzr, h, 0, hd, bias=False)
               + _sliced_conv(self.convzr, x, hd, None))
         z = nn.sigmoid(zr[..., :hd] + cz)
@@ -268,7 +268,7 @@ class PointwisePaddedConv(nn.Module):
     to match at apply time, which is arithmetically identical.  Lets the
     Pallas corr backend emit a lane-friendly channel count (36 correlation
     lanes made the consuming fusion read at ~39 GB/s, measured
-    60 us/iteration at flagship shapes — docs/perf_notes_r03.md)."""
+    60 us/iteration at flagship shapes)."""
 
     features: int
     in_features: int
@@ -424,7 +424,7 @@ class BasicMultiUpdateBlock(nn.Module):
             # iteration's mask is consumed, and it depends only on net[0],
             # so the model computes it once after the loop (upsample_mask)
             # — measured ~0.18 ms/iter of conv + f32 cast + carry traffic
-            # at flagship shapes (docs/perf_notes_r03.md).
+            # at flagship shapes.
             return net, None, delta
         return net, self.upsample_mask(net[0]), delta
 

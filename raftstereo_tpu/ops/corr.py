@@ -64,7 +64,7 @@ def build_corr_volume(fmap1: jax.Array, fmap2: jax.Array,
     # single-pass bf16 path is not the right default (the reference likewise
     # pins the volume to fp32: core/raft_stereo.py:92).  "highest" is exact
     # 6-pass emulation and stays the default: the cheaper forms measured NO
-    # speedup on the flagship path (docs/perf_notes_r03.md), so there is
+    # speedup on the flagship path, so there is
     # nothing to trade accuracy for.
     corr = jnp.einsum("bhwc,bhvc->bhwv", fmap1, fmap2,
                       preferred_element_type=jnp.float32,

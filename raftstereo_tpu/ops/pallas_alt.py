@@ -128,8 +128,8 @@ def _radial_cols(f1_ref, f2_ref, x_ref, *, scale, bounds, radius, prec,
     to the hat form — hat(j - (b0+f+k-r)) is nonzero exactly at
     j = b0+k-r (weight 1-f) and j+1 (weight f) — including zero-outside
     edges (out-of-range windows sum nothing) and NaN coords (f = NaN
-    poisons the lerp).  ~1.7x fewer VPU ops on the kernel's dominant cost
-    (docs/perf_notes_r03.md)."""
+    poisons the lerp).  ~1.7x fewer VPU ops on the kernel's dominant
+    cost."""
     f1 = f1_ref[...]                              # (R, blk, C)
     f2 = f2_ref[...]                              # (R, W2cat, C)
     x = x_ref[...].astype(jnp.float32)            # (R, blk, L)

@@ -51,10 +51,7 @@ _BLOCK_ROWS = 8
 # Row-blocked grids need more scoped VMEM than Mosaic's 16 MB default
 # (R=8 fp32 flagship blocks are ~44 MB across double buffers); v5e carries
 # 128 MB of VMEM per core, so raise the scoped limit rather than shrink R.
-# (``TPUCompilerParams`` is the pre-0.4.34 name of ``CompilerParams``.)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-_COMPILER_PARAMS = _CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
 
 
 def _pad_rows(x: jax.Array, r: int = _BLOCK_ROWS) -> jax.Array:

@@ -6,7 +6,7 @@ churn — every cross-(H,W) reduction forces ~4 full-tensor relayouts of
 135 MB each between the convs' space-to-depth blocked layouts and the
 reduce's, and NO XLA-side formulation escapes it (lane-packed views,
 direct/fp32 reduces, MXU ones-vector matmuls, 128-channel padding ALL
-measured 27-62 ms; scripts/mb_encoder.py, docs/perf_notes_r03.md).
+measured 27-62 ms; scripts/mb_encoder.py).
 
 The fix is to own the stage end-to-end in Pallas so every tensor stays in
 row-major (B, H, W, C):
@@ -1167,8 +1167,8 @@ def _in_bwd_means(u, xhat):
     On single-device TPU these run as ONE layout-preserving Pallas kernel
     over the packed row-major view: a plain XLA cross-(H,W) reduce of a
     conv-adjacent tensor forces full-tensor blocked<->row-major relayouts,
-    and NO XLA-side formulation escapes that (measured exhaustively,
-    docs/perf_notes_r03.md) — the exact storm that motivated this module.
+    and NO XLA-side formulation escapes that (measured exhaustively) — the
+    exact storm that motivated this module.
     Under an active mesh the XLA form stays: the backward runs on GLOBAL
     arrays that GSPMD partitions, where a bare pallas_call cannot."""
     from ..parallel.context import active_corr_mesh

@@ -12,8 +12,7 @@ features and the loop invariants — roughly a 4x reduction on the loop's
 memory traffic at flagship shapes (docs/perf_notes_r06.md).
 
 Design, built on the data-stationary 3x3-conv formulation validated by
-scripts/mb_gru_kernel.py (90.8 TF/s packed vs XLA's 74.8 at GRU shapes,
-docs/perf_notes_r03.md):
+scripts/mb_gru_kernel.py (90.8 TF/s packed vs XLA's 74.8 at GRU shapes):
 
 * weights shift, not activations: dy taps are row slices on the untiled
   outer axis (free), the per-tap matmuls take contiguous operands, and
@@ -84,10 +83,12 @@ _get_override, override_fused_gru = make_override_scope(
 def use_fused_gru(backend: str, test_mode: bool) -> bool:
     """Gate for the fused GRU step.
 
-    ``backend`` is config.gru_backend: "auto" resolves to the fused
-    kernel on a single-device TPU backend and to the XLA reference step
-    everywhere else; "fused"/"xla" force one path (tests force "fused"
-    on CPU to exercise the interpret-mode kernel).  The kernel covers
+    ``backend`` is config.gru_backend: "auto" resolves to the XLA
+    reference step on every backend — Mosaic does not lower the kernel
+    at flagship size in a time a server start can bear (PR 24,
+    ROADMAP.md Speed 2); "fused"/"xla" force one path (tests force
+    "fused" on CPU to exercise the interpret-mode kernel; on a TPU an
+    explicit "fused" raises whatever the compiler raises).  The kernel covers
     the test-mode step only (no per-iteration mask head), so train-mode
     tracing always takes the XLA step.  A bare pallas_call cannot be
     SPMD-partitioned, so any active corr mesh (parallel/context.py)
@@ -112,7 +113,7 @@ def use_fused_gru(backend: str, test_mode: bool) -> bool:
         return False
     if ov is not None:
         return ov
-    return jax.default_backend() == "tpu" and len(jax.devices()) == 1
+    return False
 
 
 def resolve_gru_backend(config) -> str:
@@ -252,7 +253,7 @@ def _conv7x1(d_win, w49, bias, wd):
         rows = d_win[dyi:dyi + rows_out]
         for dxi in range(7):
             taps.append(_roll_w(rows, dxi - 3, wd))
-    z = jnp.concatenate(taps, axis=-1)           # (rows_out, wd, 49)
+    z = jnp.concatenate(taps, axis=-1).astype(w49.dtype)  # (rows_out, wd, 49)
     y = jax.lax.dot_general(z, w49, (((2,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     return y + bias.astype(jnp.float32)
@@ -304,6 +305,7 @@ def _gru_update_kernel(*refs, hgt, wd, rr, starts, has_ext, hd):
         return jnp.where((i >= 0) & (i < hgt), t, jnp.zeros_like(t))
 
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 128), 2)
+    onehot126 = (lane == 126).astype(jnp.float32)
 
     for s in starts:
         # ---- motion encoder (fixed 64/128-channel geometry)
@@ -314,9 +316,10 @@ def _gru_update_kernel(*refs, hgt, wd, rr, starts, has_ext, hd):
              + w["bc1"].astype(jnp.float32)).astype(ct), 0), s, _D_CORR)
         cor = mask(jnp.maximum(
             _conv3([(c1, w["wc2"])], w["bc2"], wd).astype(ct), 0), s, 5)
-        d9 = win(disp, s, _D_DISP).astype(ct)
+        d9f = win(disp, s, _D_DISP)
+        d9 = d9f.astype(ct)
         f1 = mask(jnp.maximum(
-            _conv7x1(d9, w["wf1"], w["bf1"], wd).astype(ct), 0), s, 6)
+            _conv7x1(d9f, w["wf1"], w["bf1"], wd).astype(ct), 0), s, 6)
         flo = mask(jnp.maximum(
             _conv3([(f1, w["wf2"])], w["bf2"], wd).astype(ct), 0), s, 5)
         me = mask(jnp.maximum(
@@ -324,8 +327,7 @@ def _gru_update_kernel(*refs, hgt, wd, rr, starts, has_ext, hd):
                    w["bme"], wd).astype(ct), 0), s, _D_X)
         # motion features = [me(126, zero-padded to 128), d, 0]: the
         # disparity rides on lane 126 (lane 127 stays the zero y-flow).
-        d4 = d9[5:-5]
-        mf = me + jnp.where(lane == 126, d4, jnp.zeros_like(d4)).astype(ct)
+        mf = me + (d9f[5:-5] * onehot126).astype(ct)
 
         # ---- gru0 gates: one dot per (tap, operand), no concats
         h4 = win(h, s, _D_H)
@@ -334,14 +336,19 @@ def _gru_update_kernel(*refs, hgt, wd, rr, starts, has_ext, hd):
             e4 = win(ext, s, _D_X)
             zr_ops.append((e4, w["wzr_e"]))
         zr = _conv3(zr_ops, w["bzr"], wd).astype(ct)
-        z = jax.nn.sigmoid(zr[..., :hd] + win(cz, s, 3))
-        r = jax.nn.sigmoid(zr[..., hd:] + win(cr, s, 3))
+        f32 = jnp.float32
+        # Transcendentals in f32 and cast back: Mosaic verifies neither
+        # sigmoid nor tanh on bf16 operands (PR 24).
+        z = jax.nn.sigmoid(
+            (zr[..., :hd] + win(cz, s, 3)).astype(f32)).astype(ct)
+        r = jax.nn.sigmoid(
+            (zr[..., hd:] + win(cr, s, 3)).astype(f32)).astype(ct)
         rh = r * h4[1:-1]
         q_ops = [(rh, w["wq_h"]), (mf[1:-1], w["wq_m"])]
         if has_ext:
             q_ops.append((e4[1:-1], w["wq_e"]))
-        q = jnp.tanh(_conv3(q_ops, w["bq"], wd).astype(ct)
-                     + win(cq, s, 2))
+        q = jnp.tanh((_conv3(q_ops, w["bq"], wd).astype(ct)
+                      + win(cq, s, 2)).astype(f32)).astype(ct)
         z2 = z[1:-1]
         hn = mask((1 - z2) * h4[2:-2] + z2 * q, s, 2)
 

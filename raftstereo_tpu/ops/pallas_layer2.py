@@ -3,7 +3,7 @@
 Extends the stem..layer1 pipeline (ops/pallas_encoder.py) one stage
 deeper: round-5 profiling puts ~15 ms of the 23.6 ms flagship fixed stage
 in XLA's layer2/layer3 convs and the blocked-layout relayouts around them
-(docs/perf_notes_r05.md) — the same storm the stem pipeline removed.
+— the same storm the stem pipeline removed.
 
 Semantics are exactly BasicEncoder's layer2 (two ResidualBlocks, first
 stride 2 with a 1x1 projection shortcut; reference:

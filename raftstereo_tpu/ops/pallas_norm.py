@@ -1,7 +1,7 @@
 """Pallas TPU instance-norm: layout-preserving stats + apply kernels.
 
-Why this exists (measured, scripts/mb_encoder.py + the device trace in
-docs/perf_notes_r03.md): at the feature encoder's hot shape
+Why this exists (measured, scripts/mb_encoder.py + a device trace): at
+the feature encoder's hot shape
 (272x480x64 bf16) EVERY XLA formulation of the cross-(H,W) reduction —
 lane-packed view, direct reduce, fp32 reduce, even MXU ones-vector
 matmuls — costs 4-11 ms per norm, 50-100x its ~80 us bandwidth floor,

@@ -1,8 +1,7 @@
 """Int8 quantized correlation + the serving accuracy-tier vocabulary.
 
-The round-5 perf work left the GRU/head convs at the measured MXU ceiling
-(docs/perf_notes_r05.md), so the remaining arithmetic-intensity lever is
-precision.  This module supplies the numeric core of the quantized serving
+The round-5 perf work left the GRU/head convs at the measured MXU ceiling,
+so the remaining arithmetic-intensity lever is precision.  This module supplies the numeric core of the quantized serving
 fast path (docs/perf_notes_r07.md):
 
 * **symmetric int8 row quantization** of the left/right feature maps.  One

@@ -77,7 +77,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..config import RAFTStereoConfig
@@ -603,9 +602,9 @@ def build_spatial_forward(model, mesh: Mesh, iters: int):
                               flow_init)
 
     spec = P(None, SPACE_AXIS)
-    return shard_map(local_fn, mesh,
-                     in_specs=(P(), spec, spec, spec),
-                     out_specs=(spec, spec), check_rep=False)
+    return jax.shard_map(local_fn, mesh=mesh,
+                         in_specs=(P(), spec, spec, spec),
+                         out_specs=(spec, spec), check_vma=False)
 
 
 def jitted_spatial_infer(model, mesh: Mesh, iters: int = 32):

@@ -1500,6 +1500,14 @@ def build_server(model, variables, config: ServeConfig,
     metrics.spatial_shards.set(
         engine.spatial_shards
         if getattr(engine, "spatial_shards", 1) > 1 else 0)
+    if model is not None:
+        from ..utils.platform import describe_runtime
+
+        # Which device, and what every backend-keyed kernel gate resolved
+        # to in THIS process (chip_smoke.py holds a chip run to this line).
+        logger.info("runtime: %s", json.dumps(describe_runtime(
+            model.config, config.max_batch_size,
+            engine.bucket_of((*config.buckets[0], engine.input_channels)))))
     server = StereoServer(config, engine, batcher, metrics, stream=stream,
                           tracer=tracer, scheduler=scheduler,
                           cluster=cluster, start_ready=False,

@@ -2,8 +2,8 @@
 
 from .convert import convert_checkpoint, load_state_dict, torch_to_variables
 from .faults import FaultPlan, InjectedCrash, InjectedFault, InjectedSampleError
-from .platform import apply_env_platform
+from .platform import describe_runtime, setup_compile_cache
 
-__all__ = ["apply_env_platform", "convert_checkpoint", "load_state_dict",
+__all__ = ["describe_runtime", "setup_compile_cache", "convert_checkpoint", "load_state_dict",
            "torch_to_variables", "FaultPlan", "InjectedFault",
            "InjectedCrash", "InjectedSampleError"]

@@ -1,7 +1,7 @@
 """A/B the conv1 kernels' dot structure (VERDICT r4 item 1, conv1 part):
 7 per-dy-tap dots (K=30/36, 23-28% MXU K-fill) vs ONE dy-folded big-K dot
 (K=210/252, 2 nearly-full K-passes).  Alternating same-process pairs —
-the chip drifts within a process (docs/perf_notes_r04.md), so the valid
+the chip drifts within a process, so the valid
 readout is the per-pair delta, not single shots.
 
 Usage: python scripts/ab_conv1_bigk.py [--realtime] [--reps 10] [--pairs 2]
@@ -29,8 +29,6 @@ def main():
     p.add_argument("--realtime", action="store_true")
     args = p.parse_args()
 
-    from raftstereo_tpu.utils import apply_env_platform
-    apply_env_platform()
 
     import jax
     import jax.numpy as jnp

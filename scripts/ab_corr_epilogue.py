@@ -27,8 +27,6 @@ def main():
     p.add_argument("--realtime", action="store_true")
     args = p.parse_args()
 
-    from raftstereo_tpu.utils import apply_env_platform
-    apply_env_platform()
 
     import jax
     import jax.numpy as jnp

@@ -25,8 +25,6 @@ def main():
     p.add_argument("--reps", type=int, default=10)
     args = p.parse_args()
 
-    from raftstereo_tpu.utils import apply_env_platform
-    apply_env_platform()
 
     import jax
     import jax.numpy as jnp

@@ -25,8 +25,6 @@ def main():
     p.add_argument("--pairs", type=int, default=2)
     args = p.parse_args()
 
-    from raftstereo_tpu.utils import apply_env_platform
-    apply_env_platform()
 
     import jax
     import jax.numpy as jnp

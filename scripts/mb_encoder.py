@@ -1,6 +1,6 @@
 """Microbenchmark: the fixed-stage encoders at flagship resolution.
 
-The device trace (docs/perf_notes_r03.md) shows the ~50 ms fixed stage is
+The device trace shows the ~50 ms fixed stage is
 ~90% data movement around the half-resolution 64-channel convs.  This
 harness times the encoder subgraphs in isolation so layout/packing
 experiments get a fast measured verdict (the round-2 lesson: microbenches
@@ -32,8 +32,6 @@ def main():
     p.add_argument("--stem_only", action="store_true")
     args = p.parse_args()
 
-    from raftstereo_tpu.utils import apply_env_platform
-    apply_env_platform()
 
     import jax
     import jax.numpy as jnp

@@ -23,10 +23,9 @@ sys.path.insert(0, REPO)
 
 def run(steps=300, batch=4, hw=(64, 96), lr=4e-4, seed=0, log_every=10,
         platform=None, out=None, train_iters=6):
-    from raftstereo_tpu.utils.platform import apply_env_platform
-    apply_env_platform(platform)
-
     import jax
+    if platform:
+        jax.config.update("jax_platforms", platform)
     import numpy as np
 
     from raftstereo_tpu.config import RAFTStereoConfig, TrainConfig

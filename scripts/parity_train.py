@@ -94,8 +94,6 @@ def run_ours(args, ckpt, ws):
 
 def _run_ours_impl(args, ckpt):
     os.environ["JAX_PLATFORMS"] = "cpu"
-    from raftstereo_tpu.utils import apply_env_platform
-    apply_env_platform()
 
     import jax
     import jax.numpy as jnp

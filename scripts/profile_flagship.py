@@ -109,8 +109,6 @@ def main():
     args = p.parse_args()
 
     if not args.reuse:
-        from raftstereo_tpu.utils import apply_env_platform
-        apply_env_platform()
         fwd, variables, img1, img2 = build_forward(args)
 
         def run():

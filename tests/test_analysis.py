@@ -13,8 +13,7 @@ Two halves:
 * the runtime retrace guard — a seeded Python-float jit closure (the
   classic silent-retrace hazard) must blow its declared compile budget,
   a cached jit must pass under budget, and the guard must refuse to run
-  under a persistent JAX compile cache (known broken on this container,
-  CHANGES.md PR 2).
+  under a persistent JAX compile cache.
 """
 
 import os
@@ -333,9 +332,8 @@ class TestRetraceGuard:
 
     def test_refuses_persistent_compile_cache(self, retrace_guard,
                                               monkeypatch):
-        """Deserialized executables skip the backend-compile event (and
-        are broken on this container anyway) — the guard must refuse
-        rather than silently under-count."""
+        """Deserialized executables skip the backend-compile event — the
+        guard must refuse rather than silently under-count."""
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/never-used")
         with pytest.raises(RuntimeError, match="persistent"):
             with retrace_guard(0):

@@ -29,39 +29,6 @@ def _bench_module():
     return bench
 
 
-def test_ledger_collates_committed_artifacts(tmp_path, capsys):
-    """bench.py --ledger (tier-1, no accelerator): the committed
-    BENCH_*/MULTICHIP_* records collate into one schema-stable
-    PERF_LEDGER.json — the trajectory table's (docs/perf_notes_r08.md)
-    machine-readable source."""
-    bench = _bench_module()
-
-    out = tmp_path / "ledger.json"
-    bench.main(["--ledger", "--ledger_out", str(out)])
-    printed = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    ledger = json.loads(out.read_text())
-    assert printed["n_entries"] == ledger["n_entries"]
-    assert ledger["ledger_format"] == 1
-    assert ledger["n_entries"] == len(ledger["entries"]) > 0
-    for e in ledger["entries"]:
-        assert {"source", "round", "mode", "metric",
-                "value", "unit"} <= set(e), e
-        assert e["mode"] in {"headline", "session", "slo", "cascade",
-                             "multichip", "baseline"}, e
-        assert isinstance(e["value"], (int, float)), e
-        assert e["round"] is None or isinstance(e["round"], int), e
-    # Every committed per-round artifact class is represented.
-    modes = {e["mode"] for e in ledger["entries"]}
-    assert {"headline", "multichip", "baseline"} <= modes
-    # Deterministic: a second collation is byte-identical.
-    out2 = tmp_path / "ledger2.json"
-    bench.main(["--ledger", "--ledger_out", str(out2)])
-    assert out2.read_text() == out.read_text()
-    # The checked-in ledger matches what --ledger produces today.
-    committed = os.path.join(REPO, "PERF_LEDGER.json")
-    assert json.loads(open(committed).read()) == ledger
-
-
 @pytest.mark.slow
 def test_quick_inference_contract():
     r = _run(["--quick", "--reps", "1"])

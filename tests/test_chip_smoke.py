@@ -1,0 +1,54 @@
+"""chip_smoke.py has no CPU fallback: off the chip it exits non-zero, says
+why, and prints no result line (the no-fallback rule of PR 24)."""
+
+import ast
+import os
+import subprocess
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_chip_smoke_refuses_the_cpu(tmp_path):
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py"),
+         "--out", str(tmp_path)],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True,
+        text=True, timeout=300)
+    assert r.returncode != 0, r.stdout
+    assert "platform is 'cpu', not 'tpu'" in r.stdout, r.stdout + r.stderr
+    assert '"ok"' not in r.stdout
+
+
+_CACHE_PROBE = (
+    "import jax\n"
+    "from jax._src import xla_bridge\n"
+    "from raftstereo_tpu.utils.platform import setup_compile_cache\n"
+    "print(repr(setup_compile_cache()))\n"
+    "print(repr(jax.config.jax_compilation_cache_dir))\n"
+    "assert not xla_bridge._backends, 'initialised a backend'\n")
+
+
+def _cache_probe(**env):
+    base = {k: v for k, v in os.environ.items()
+            if k not in ("JAX_PLATFORMS", "JAX_COMPILATION_CACHE_DIR")}
+    r = subprocess.run([sys.executable, "-c", _CACHE_PROBE], cwd=REPO,
+                       env={**base, "PYTHONPATH": REPO, **env},
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    returned, configured = r.stdout.strip().splitlines()[-2:]
+    return ast.literal_eval(returned), ast.literal_eval(configured)
+
+
+def test_compile_cache_is_placed_from_outside_or_under_the_checkout():
+    """One helper, three cases (utils/platform.setup_compile_cache): a
+    directory given from outside is left alone, a process pinned to the CPU
+    gets none, anything else caches under <checkout>/.jax_cache — and the
+    helper never initialises a backend (chip_smoke's parent calls it)."""
+    assert _cache_probe(JAX_PLATFORMS="tpu",
+                        JAX_COMPILATION_CACHE_DIR="/given/dir") == (
+        "/given/dir", "/given/dir")
+    assert _cache_probe(JAX_PLATFORMS="cpu") == (None, None)
+    want = os.path.join(REPO, ".jax_cache")
+    assert _cache_probe(JAX_PLATFORMS="tpu") == (want, want)
+    assert _cache_probe() == (want, want)

@@ -110,7 +110,7 @@ def test_precision_policies_agree(fmaps, rng):
     backend.  On CPU the XLA einsum ignores precision (native fp32), but the
     pallas_alt kernel's manual hi/lo decomposition (ops/pallas_alt._dot) is
     real arithmetic in interpret mode, so the 3-pass construction itself is
-    exercised.  Perf decision (measured on v5e, docs/perf_notes_r03.md):
+    exercised.  Perf decision (measured on v5e):
     neither is faster on the default path, so "highest" stays the default."""
     f1, f2 = fmaps
     x = rng.uniform(0, 20, (2, 6, 20)).astype(np.float32)[..., None]

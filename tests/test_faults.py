@@ -7,7 +7,8 @@ Everything runs on synthetic data on CPU and is part of the tier-1
 selection (marker ``chaos``).
 
 NOTE: these tests deliberately do NOT use jax's persistent compilation
-cache.  On this container, a cache-DESERIALIZED executable is both
+cache (utils/platform.setup_compile_cache sets none on the CPU).  When
+PR 2 tried it, a cache-DESERIALIZED CPU executable was both
 crash-prone (SIGSEGV/SIGABRT in ``_check_if_deleted`` when fed an
 orbax-restored donated state) and numerically different from the
 freshly-compiled one (bitwise train-state divergence after 4 steps), so

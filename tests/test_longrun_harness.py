@@ -1,6 +1,6 @@
 """Unit coverage for the long-horizon harness's health-gate helpers
 (scripts/longrun_tpu.py) — the gates that certify the committed chip
-curve (docs/longrun_r05.md) must themselves be trustworthy: a parser
+curve (docs/longrun_r05_curve.jsonl) must themselves be trustworthy: a parser
 that silently drops records would turn a broken run into a PASS.
 """
 

@@ -8,14 +8,6 @@ import pytest
 
 from raftstereo_tpu.ops import pallas_encoder as pe
 
-# Known sharded-Pallas parity failures on this container (tracking: PR3
-# fault-tolerance note in CHANGES.md): its jax build removed the
-# `jax.shard_map` alias the partitioned paths call, so every shard_map'd
-# case fails at attribute lookup, not at parity.  strict=False so the tests
-# pass unchanged on stacks where the alias (or a fixed call site) exists.
-shard_map_xfail = pytest.mark.xfail(
-    strict=False,
-    reason="jax.shard_map alias removed in this container's jax build")
 
 
 @pytest.fixture
@@ -122,7 +114,6 @@ class TestEncoderIntegration:
 
     @pytest.mark.skipif(jax.device_count() < 2,
                         reason="needs a multi-device mesh")
-    @shard_map_xfail
     def test_sharded_equals_unsharded(self, stage):
         """shard_map'd fused stage (data x space mesh: stats psum +
         ppermute'd halo rows) must match the single-device fused stage."""
@@ -146,7 +137,6 @@ class TestEncoderIntegration:
 
     @pytest.mark.skipif(jax.device_count() < 4,
                         reason="needs a data x space mesh")
-    @shard_map_xfail
     def test_sharded_gradients(self, stage):
         """Backward under the mesh: the XLA-reference VJP runs on global
         arrays (GSPMD partitions it), so grads match the unsharded ones."""
@@ -217,7 +207,6 @@ class TestFusedConv1:
 
     @pytest.mark.skipif(jax.device_count() < 4,
                         reason="needs a data x space mesh")
-    @shard_map_xfail
     def test_conv1_stage_sharded(self, rng):
         """Space sharding exchanges 3 image halo rows per boundary; the
         result must match the single-device pipeline."""

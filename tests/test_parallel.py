@@ -20,14 +20,6 @@ from raftstereo_tpu.parallel import (DATA_AXIS, SPACE_AXIS, batch_sharded,
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# Known sharded-Pallas parity failures on this container (tracking: PR3
-# fault-tolerance note in CHANGES.md): its jax build removed the
-# `jax.shard_map` alias the partitioned corr paths call, so these fail at
-# attribute lookup, not at parity.  strict=False so they pass unchanged on
-# stacks where the alias exists.
-shard_map_xfail = pytest.mark.xfail(
-    strict=False,
-    reason="jax.shard_map alias removed in this container's jax build")
 
 
 class TestMesh:
@@ -71,9 +63,8 @@ class TestMeshSubprocessDeviceCounts:
     SCRIPT = textwrap.dedent("""
         import json
         import numpy as np
-        from raftstereo_tpu.utils.platform import apply_env_platform
-        assert apply_env_platform("cpu") == "cpu"
         import jax
+        jax.config.update("jax_platforms", "cpu")
         from raftstereo_tpu.parallel import (DATA_AXIS, SPACE_AXIS,
             batch_sharded, make_mesh, replica_devices, shard_batch)
 
@@ -187,7 +178,6 @@ class TestShardedPallasCorr:
 
     @pytest.mark.parametrize("impl", ["pallas_alt", "pallas"])
     @pytest.mark.parametrize("data,space", [(4, 1), (2, 2), (1, 4)])
-    @shard_map_xfail
     def test_sharded_matches_unsharded(self, rng, impl, data, space):
         import jax.numpy as jnp
 
@@ -246,7 +236,6 @@ class TestShardedPallasCorr:
 
 
 class TestSpatialEvaluatorPallas:
-    @shard_map_xfail
     def test_evaluator_space_mesh_with_pallas_alt(self, rng):
         """The spatial evaluator runs the Pallas on-demand backend sharded
         over the space axis (shard_map; interpret mode on CPU) and matches
@@ -368,7 +357,6 @@ class TestHaloExchange:
     @pytest.mark.parametrize("shards", [2, 4])
     def test_matches_zero_padded_reference_rows(self, rng, pad, shards):
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from raftstereo_tpu.parallel.spatial import (halo_exchange,
@@ -378,9 +366,9 @@ class TestHaloExchange:
         x = jnp.asarray(rng.standard_normal((1, shards * h_loc, 5, 3)),
                         jnp.float32)
         spec = P(None, SPACE_AXIS)
-        f = shard_map(lambda a: halo_exchange(a, pad, shards),
-                      spatial_mesh(shards), in_specs=(spec,),
-                      out_specs=spec, check_rep=False)
+        f = jax.shard_map(lambda a: halo_exchange(a, pad, shards),
+                          mesh=spatial_mesh(shards), in_specs=(spec,),
+                          out_specs=spec, check_vma=False)
         # Sharded out axis 1 concatenates the extended slabs in order.
         out = np.asarray(jax.jit(f)(x)).reshape(
             1, shards, h_loc + 2 * pad, 5, 3)
@@ -407,7 +395,6 @@ class TestHaloExchange:
         each data-row's halo is exchanged within its own mesh row —
         batch entries never mix."""
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from raftstereo_tpu.parallel.spatial import halo_exchange
@@ -417,8 +404,8 @@ class TestHaloExchange:
                         jnp.float32)
         mesh = make_mesh(data=2, space=2)
         spec = P(DATA_AXIS, SPACE_AXIS)
-        f = shard_map(lambda a: halo_exchange(a, pad, shards), mesh,
-                      in_specs=(spec,), out_specs=spec, check_rep=False)
+        f = jax.shard_map(lambda a: halo_exchange(a, pad, shards), mesh=mesh,
+                          in_specs=(spec,), out_specs=spec, check_vma=False)
         out = np.asarray(jax.jit(f)(x)).reshape(
             2, shards, h_loc + 2 * pad, 5, 3)
         ref = np.pad(np.asarray(x),
@@ -435,7 +422,6 @@ class TestHaloExchange:
         full-image conv bit-for-bit on a (1, 4) mesh."""
         import jax.numpy as jnp
         from jax import lax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from raftstereo_tpu.parallel import spatial as sp
@@ -451,9 +437,9 @@ class TestHaloExchange:
             a, k, (1, 1), ((1, 1), (1, 1)),
             dimension_numbers=("NHWC", "HWIO", "NHWC")) + b)(x)
         spec = P(None, SPACE_AXIS)
-        f = shard_map(lambda a: sp._conv(p, a, 1, 1, shards),
-                      sp.spatial_mesh(shards), in_specs=(spec,),
-                      out_specs=spec, check_rep=False)
+        f = jax.shard_map(lambda a: sp._conv(p, a, 1, 1, shards),
+                          mesh=sp.spatial_mesh(shards), in_specs=(spec,),
+                          out_specs=spec, check_vma=False)
         np.testing.assert_array_equal(np.asarray(jax.jit(f)(x)),
                                       np.asarray(ref))
 
@@ -468,11 +454,9 @@ class TestSpatialSubprocessDeviceCounts:
     SCRIPT = textwrap.dedent("""
         import json
         import numpy as np
-        from raftstereo_tpu.utils.platform import apply_env_platform
-        assert apply_env_platform("cpu") == "cpu"
         import jax
+        jax.config.update("jax_platforms", "cpu")
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from raftstereo_tpu.parallel import DATA_AXIS, SPACE_AXIS, make_mesh
         from raftstereo_tpu.parallel.spatial import (halo_exchange,
@@ -489,9 +473,9 @@ class TestSpatialSubprocessDeviceCounts:
         def halo_ok(mesh, spec, batch, shards, h_loc, pad):
             x = jnp.asarray(rng.standard_normal(
                 (batch, shards * h_loc, 5, 3)), jnp.float32)
-            f = shard_map(lambda a: halo_exchange(a, pad, shards), mesh,
-                          in_specs=(spec,), out_specs=spec,
-                          check_rep=False)
+            f = jax.shard_map(lambda a: halo_exchange(a, pad, shards),
+                              mesh=mesh, in_specs=(spec,), out_specs=spec,
+                              check_vma=False)
             got = np.asarray(jax.jit(f)(x)).reshape(
                 batch, shards, h_loc + 2 * pad, 5, 3)
             ref = np.pad(np.asarray(x),
