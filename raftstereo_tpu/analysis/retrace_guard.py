@@ -32,21 +32,33 @@ REFUSES to run when a persistent JAX compilation cache is configured —
 deserialized executables skip the backend-compile event, so the count
 would be meaningless (CPU runs, where the guard is used, set no cache:
 utils/platform.setup_compile_cache).
+
+The one ``jax.monitoring`` listener this module registers also feeds
+``subscribe``: the serving front-end counts every program the process
+builds (``compile``) or reads back from the persistent cache
+(``cache_load``) through it (``serve_xla_compiles_total``) — one listener,
+however many guards and servers a process holds.  A cache read reports as
+``/jax/compilation_cache/cache_retrieval_time_sec``, the duration event
+JAX records beside ``/jax/compilation_cache/cache_hits``.
 """
 
 from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Iterator, List
+from typing import Callable, Iterator, List
 
-__all__ = ["RetraceBudgetExceeded", "retrace_guard", "compile_events"]
+__all__ = ["RetraceBudgetExceeded", "retrace_guard", "compile_events",
+           "subscribe"]
 
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 _lock = threading.Lock()
 _installed = False
 _durations: List[float] = []  # every backend compile since install
+# fn(kind, duration_s), kind in {"compile", "cache_load"}; under _lock
+_subscribers: List[Callable[[str, float], None]] = []
 
 
 class RetraceBudgetExceeded(AssertionError):
@@ -55,8 +67,34 @@ class RetraceBudgetExceeded(AssertionError):
 
 def _listener(event: str, duration: float, **kwargs) -> None:
     if event == _BACKEND_COMPILE_EVENT:
-        with _lock:
+        kind = "compile"
+    elif event == _CACHE_LOAD_EVENT:
+        kind = "cache_load"
+    else:
+        return
+    with _lock:
+        if kind == "compile":
             _durations.append(duration)
+        subscribers = list(_subscribers)
+    for fn in subscribers:  # outside the lock: they take their own
+        fn(kind, duration)
+
+
+def subscribe(fn: Callable[[str, float], None]) -> Callable[[], None]:
+    """Call ``fn(kind, duration_s)`` for every program this process
+    builds (``"compile"``) or loads from the persistent cache
+    (``"cache_load"``) from now on, on whichever thread compiled.
+    Returns the function that ends the subscription."""
+    _ensure_installed()
+    with _lock:
+        _subscribers.append(fn)
+
+    def unsubscribe() -> None:
+        with _lock:
+            if fn in _subscribers:
+                _subscribers.remove(fn)
+
+    return unsubscribe
 
 
 def _ensure_installed() -> None:

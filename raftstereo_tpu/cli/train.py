@@ -390,14 +390,14 @@ def train(model_cfg, cfg: TrainConfig, dataset=None,
     saved_steps = set()
 
     def save_ckpt(step, state, wait=False):
-        t0 = time.perf_counter()
-        manager.save(step, state, wait=wait)
-        t1 = time.perf_counter()
         # wait=False saves measure the async dispatch; wait=True (boundary
-        # and final saves) the full write.
-        tracer.record("checkpoint", t0, t1, run_trace,
-                      attrs={"step": step, "wait": wait})
-        tmetrics.checkpoint_seconds.observe(t1 - t0)
+        # and final saves) the full write.  phase(): a ring span AND, in a
+        # --profile_steps capture, a host event over the device's steps.
+        t0 = time.perf_counter()
+        with tracer.phase("checkpoint", trace_id=run_trace, step=step,
+                          wait=wait):
+            manager.save(step, state, wait=wait)
+        tmetrics.checkpoint_seconds.observe(time.perf_counter() - t0)
         saved_steps.add(step)
 
     def save_boundary(step, state):
@@ -439,13 +439,14 @@ def train(model_cfg, cfg: TrainConfig, dataset=None,
             while True:
                 # Explicit next(): the wait for the prefetched batch IS the
                 # data-starvation signal (span + train_data_wait_seconds).
+                # (The wait that ends an epoch is recorded too.)
                 t_d0 = time.perf_counter()
-                batch = next(batches, _EPOCH_DONE)
+                with tracer.phase("data_wait", trace_id=run_trace,
+                                  step=total_steps + 1):
+                    batch = next(batches, _EPOCH_DONE)
                 t_d1 = time.perf_counter()
                 if batch is _EPOCH_DONE:
                     break
-                tracer.record("data_wait", t_d0, t_d1, run_trace,
-                              attrs={"step": total_steps + 1})
                 # The watchdog clock starts before the fault hooks so an
                 # injected slow@step is measured like a real stall.
                 t0 = time.monotonic()
@@ -469,19 +470,20 @@ def train(model_cfg, cfg: TrainConfig, dataset=None,
                 in_xla_window = (prof.enabled
                                  and prof.start <= total_steps < prof.stop)
                 t_s0 = time.perf_counter()
-                with prof.step(total_steps):
-                    state, metrics = step_fn(state, batch)
-                total_steps += 1
-                # float() blocks on the device result, so dt covers the
-                # actual step execution, not just its dispatch.
-                metrics = {k: float(v) for k, v in metrics.items()}
-                t_s1 = time.perf_counter()
+                # The capture opens (prof.step) before the phase, so the
+                # first profiled step is a host event in it too.
                 # xla_profile cross-references this span with the
-                # StepProfiler capture it overlapped, so the host-side
-                # phase trace and the XLA device trace line up in Perfetto.
-                tracer.record("step", t_s0, t_s1, run_trace,
-                              attrs={"step": total_steps,
-                                     "xla_profile": in_xla_window})
+                # StepProfiler capture it overlapped.
+                with prof.step(total_steps), \
+                        tracer.phase("step", trace_id=run_trace,
+                                     step=total_steps + 1,
+                                     xla_profile=in_xla_window):
+                    state, metrics = step_fn(state, batch)
+                    total_steps += 1
+                    # float() blocks on the device result, so the phase
+                    # covers the step's execution, not just its dispatch.
+                    metrics = {k: float(v) for k, v in metrics.items()}
+                t_s1 = time.perf_counter()
                 tmetrics.observe_step(step_s=t_s1 - t_s0,
                                       data_s=t_d1 - t_d0)
                 health = loader.health_metrics()

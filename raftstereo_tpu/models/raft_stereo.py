@@ -36,6 +36,17 @@ from .layers import ResidualBlock, conv
 from .update import BasicMultiUpdateBlock, _interp_to
 
 
+# The ``jax.named_scope`` stages of the served step, as a device trace's
+# op names carry them (the innermost one on an op's path is its stage):
+# ``encoders`` (all of ``_encode``), ``corr_build`` (what is built once per
+# pair), and per iteration ``lookup``, ``gru`` (motion encoder, GRU levels
+# ``gru/level32|16|08``, flow head) and ``upsample`` (mask head + convex
+# upsampling; inside the loop only when training).  The train step adds
+# ``loss`` (train/step.py).  Scopes are metadata: the compiled program is
+# the same with and without them (tests/test_trace_names.py).
+STAGES = ("encoders", "corr_build", "lookup", "gru", "upsample")
+
+
 class ContextZQR(nn.Module):
     """Per-level 3x3 convs producing the GRU context biases once
     (reference: core/raft_stereo.py:32).  Output channel order (cz, cr, cq)
@@ -205,7 +216,13 @@ class RAFTStereo:
                 image2: jax.Array):
         """Encoder phase shared by ``forward`` and ``forward_prologue``:
         normalization, context/feature encoders and the precomputed GRU
-        context biases (reference: core/raft_stereo.py:77-88)."""
+        context biases (reference: core/raft_stereo.py:77-88).  All of it
+        is the ``encoders`` stage of a device trace (STAGES)."""
+        with jax.named_scope("encoders"):
+            return self._encode_stage(variables, image1, image2)
+
+    def _encode_stage(self, variables: Dict, image1: jax.Array,
+                      image2: jax.Array):
         cfg = self.config
         dtype = self.dtype
         b = image1.shape[0]
@@ -323,23 +340,25 @@ class RAFTStereo:
             def fused_step(carry, _):
                 nets, d = carry
                 d = jax.lax.stop_gradient(d)
-                corr = corr_fn(grid + d)
+                with jax.named_scope("lookup"):
+                    corr = corr_fn(grid + d)
                 nets = list(nets)
-                if n == 3 and sf:
-                    nets = self.update.apply(update_vars, nets, zqr_list,
-                                             iter2=True, iter1=False,
-                                             iter0=False, update=False)
-                if n >= 2 and sf:
-                    nets = self.update.apply(update_vars, nets, zqr_list,
-                                             iter2=(n == 3), iter1=True,
-                                             iter0=False, update=False)
-                if n >= 2:
-                    nets = self.update.apply(update_vars, nets, zqr_list,
-                                             iter2=(n == 3), iter1=True,
-                                             iter0=False, update=False)
-                ext = (_interp_to(nets[1], nets[0]) if n > 1 else None)
-                hnew, delta = fused_update(nets[0], ext, corr, d,
-                                           cz0, cr0, cq0, wpack)
+                with jax.named_scope("gru"):
+                    if n == 3 and sf:
+                        nets = self.update.apply(
+                            update_vars, nets, zqr_list, iter2=True,
+                            iter1=False, iter0=False, update=False)
+                    if n >= 2 and sf:
+                        nets = self.update.apply(
+                            update_vars, nets, zqr_list, iter2=(n == 3),
+                            iter1=True, iter0=False, update=False)
+                    if n >= 2:
+                        nets = self.update.apply(
+                            update_vars, nets, zqr_list, iter2=(n == 3),
+                            iter1=True, iter0=False, update=False)
+                    ext = (_interp_to(nets[1], nets[0]) if n > 1 else None)
+                    hnew, delta = fused_update(nets[0], ext, corr, d,
+                                               cz0, cr0, cq0, wpack)
                 nets[0] = hnew
                 d = d + delta[..., :1].astype(jnp.float32)
                 return (tuple(nets), d), None
@@ -349,31 +368,36 @@ class RAFTStereo:
         def step(carry, _):
             nets, d = carry
             d = jax.lax.stop_gradient(d)
-            corr = corr_fn(grid + d)  # already emitted in model dtype
+            with jax.named_scope("lookup"):
+                corr = corr_fn(grid + d)  # already emitted in model dtype
             flow = jnp.concatenate([d, jnp.zeros_like(d)], axis=-1).astype(dtype)
 
-            if n == 3 and sf:
-                nets = self.update.apply(update_vars, nets, zqr_list,
-                                         iter2=True, iter1=False, iter0=False,
-                                         update=False)
-            if n >= 2 and sf:
-                nets = self.update.apply(update_vars, nets, zqr_list,
-                                         iter2=(n == 3), iter1=True,
-                                         iter0=False, update=False)
-            # Test mode skips the mask head inside the loop: only the final
-            # mask is consumed and it depends only on net[0], so it is
-            # computed ONCE after the scan (measured ~0.18 ms/iter saved at
-            # flagship shapes: the 128->256 conv, the 1x1 head, the f32
-            # cast, and the carry's HBM round trip).
-            nets, mask, delta = self.update.apply(
-                update_vars, nets, zqr_list, corr, flow,
-                iter2=(n == 3), iter1=(n >= 2), with_mask=not test_mode,
-                corr_preact=use_epi)
+            with jax.named_scope("gru"):
+                if n == 3 and sf:
+                    nets = self.update.apply(update_vars, nets, zqr_list,
+                                             iter2=True, iter1=False,
+                                             iter0=False, update=False)
+                if n >= 2 and sf:
+                    nets = self.update.apply(update_vars, nets, zqr_list,
+                                             iter2=(n == 3), iter1=True,
+                                             iter0=False, update=False)
+                # Test mode skips the mask head inside the loop: only the
+                # final mask is consumed and it depends only on net[0], so
+                # it is computed ONCE after the scan (measured ~0.18
+                # ms/iter saved at flagship shapes: the 128->256 conv, the
+                # 1x1 head, the f32 cast, and the carry's HBM round trip).
+                # In train mode the mask head runs in here, under its own
+                # inner ``upsample`` scope (models/update.py).
+                nets, mask, delta = self.update.apply(
+                    update_vars, nets, zqr_list, corr, flow,
+                    iter2=(n == 3), iter1=(n >= 2), with_mask=not test_mode,
+                    corr_preact=use_epi)
 
             d = d + delta[..., :1].astype(jnp.float32)
             if test_mode:
                 return (tuple(nets), d), None
-            up = convex_upsample(d, mask.astype(jnp.float32), cfg.factor)
+            with jax.named_scope("upsample"):
+                up = convex_upsample(d, mask.astype(jnp.float32), cfg.factor)
             return (tuple(nets), d), up
 
         return step
@@ -390,13 +414,14 @@ class RAFTStereo:
         fused = self._use_fused_gru(test_mode)
         corr_dtype, use_epi, epi, out_channels, quant = self._corr_setup(
             update_vars, test_mode, fused)
-        corr_fn = make_corr_fn(cfg.corr_implementation, fmap1, fmap2,
-                               cfg.corr_levels, cfg.corr_radius,
-                               dtype=corr_dtype,
-                               precision=cfg.corr_precision,
-                               out_dtype=self.dtype,
-                               out_channels=out_channels,
-                               epilogue=epi, quant=quant)
+        with jax.named_scope("corr_build"):
+            corr_fn = make_corr_fn(cfg.corr_implementation, fmap1, fmap2,
+                                   cfg.corr_levels, cfg.corr_radius,
+                                   dtype=corr_dtype,
+                                   precision=cfg.corr_precision,
+                                   out_dtype=self.dtype,
+                                   out_channels=out_channels,
+                                   epilogue=epi, quant=quant)
 
         h0, w0 = net_list[0].shape[1:3]
         grid = coords_grid_x(b, h0, w0)
@@ -418,10 +443,11 @@ class RAFTStereo:
             body, (tuple(net_list), disp), None, length=iters,
             unroll=unroll)
         if test_mode:
-            mask = self.update.apply(update_vars, nets[0],
-                                     method="upsample_mask")
-            disp_up = convex_upsample(disp, mask.astype(jnp.float32),
-                                      cfg.factor)
+            with jax.named_scope("upsample"):
+                mask = self.update.apply(update_vars, nets[0],
+                                         method="upsample_mask")
+                disp_up = convex_upsample(disp, mask.astype(jnp.float32),
+                                          cfg.factor)
             return disp, disp_up
         return ys  # (iters, B, H*f, W*f, 1)
 
@@ -459,10 +485,12 @@ class RAFTStereo:
                                                         image2)
         corr_dtype, _, _, _, quant = self._corr_setup(
             self._split_vars(variables, "update"), test_mode=True)
-        corr_state = build_corr_state(cfg.corr_implementation, fmap1, fmap2,
-                                      cfg.corr_levels, dtype=corr_dtype,
-                                      precision=cfg.corr_precision,
-                                      quant=quant)
+        with jax.named_scope("corr_build"):
+            corr_state = build_corr_state(cfg.corr_implementation, fmap1,
+                                          fmap2, cfg.corr_levels,
+                                          dtype=corr_dtype,
+                                          precision=cfg.corr_precision,
+                                          quant=quant)
         b, h0, w0 = net_list[0].shape[:3]
         disp = jnp.zeros((b, h0, w0, 1), jnp.float32)
         if flow_init is not None:
@@ -481,12 +509,14 @@ class RAFTStereo:
         fused = self._use_fused_gru(test_mode=True)
         _, use_epi, epi, out_channels, quant = self._corr_setup(
             update_vars, test_mode=True, fused=fused)
-        corr_fn = corr_fn_from_state(cfg.corr_implementation, state["corr"],
-                                     cfg.corr_levels, cfg.corr_radius,
-                                     precision=cfg.corr_precision,
-                                     out_dtype=self.dtype,
-                                     out_channels=out_channels,
-                                     epilogue=epi, quant=quant)
+        with jax.named_scope("corr_build"):
+            corr_fn = corr_fn_from_state(cfg.corr_implementation,
+                                         state["corr"], cfg.corr_levels,
+                                         cfg.corr_radius,
+                                         precision=cfg.corr_precision,
+                                         out_dtype=self.dtype,
+                                         out_channels=out_channels,
+                                         epilogue=epi, quant=quant)
         disp = state["disp"]
         b, h0, w0 = disp.shape[:3]
         grid = coords_grid_x(b, h0, w0)
@@ -501,10 +531,12 @@ class RAFTStereo:
         """Final mask head + convex upsampling: ``(disp_low, disp_up)`` —
         the same post-scan code as the monolithic test-mode ``forward``."""
         update_vars = self._split_vars(variables, "update")
-        mask = self.update.apply(update_vars, state["nets"][0],
-                                 method="upsample_mask")
-        disp_up = convex_upsample(state["disp"], mask.astype(jnp.float32),
-                                  self.config.factor)
+        with jax.named_scope("upsample"):
+            mask = self.update.apply(update_vars, state["nets"][0],
+                                     method="upsample_mask")
+            disp_up = convex_upsample(state["disp"],
+                                      mask.astype(jnp.float32),
+                                      self.config.factor)
         return state["disp"], disp_up
 
     # ------------------------------------------------------------- interface

@@ -387,21 +387,27 @@ class BasicMultiUpdateBlock(nn.Module):
         n = cfg.n_gru_layers
         net = list(net)
 
+        # One scope per level inside the caller's ``gru`` stage
+        # (models/raft_stereo.py STAGES): gru/level32, gru/level16,
+        # gru/level08 in a device trace's op names.
         if n == 3 and iter2:
-            net[2] = self.gru2(net[2], *inp[2], avg_pool2x(net[1]))
+            with jax.named_scope("level32"):
+                net[2] = self.gru2(net[2], *inp[2], avg_pool2x(net[1]))
         if n >= 2 and iter1:
-            if n > 2:
-                net[1] = self.gru1(net[1], *inp[1], avg_pool2x(net[0]),
-                                   _interp_to(net[2], net[1]))
-            else:
-                net[1] = self.gru1(net[1], *inp[1], avg_pool2x(net[0]))
+            with jax.named_scope("level16"):
+                if n > 2:
+                    net[1] = self.gru1(net[1], *inp[1], avg_pool2x(net[0]),
+                                       _interp_to(net[2], net[1]))
+                else:
+                    net[1] = self.gru1(net[1], *inp[1], avg_pool2x(net[0]))
         if iter0:
             motion_features = self.encoder(flow, corr, preact=corr_preact)
-            if n > 1:
-                net[0] = self.gru0(net[0], *inp[0], motion_features,
-                                   _interp_to(net[1], net[0]))
-            else:
-                net[0] = self.gru0(net[0], *inp[0], motion_features)
+            with jax.named_scope("level08"):
+                if n > 1:
+                    net[0] = self.gru0(net[0], *inp[0], motion_features,
+                                       _interp_to(net[1], net[0]))
+                else:
+                    net[0] = self.gru0(net[0], *inp[0], motion_features)
 
         if not update:
             return net
@@ -415,7 +421,8 @@ class BasicMultiUpdateBlock(nn.Module):
             y = self._merged_head_hidden(net[0])
             hd = self.flow_head.hidden_dim
             delta = self.flow_head.from_hidden(y[..., :hd])
-            mask = 0.25 * self.mask_conv2(y[..., hd:])
+            with jax.named_scope("upsample"):
+                mask = 0.25 * self.mask_conv2(y[..., hd:])
             return net, mask, delta
 
         delta = self.flow_head(net[0])
@@ -426,7 +433,9 @@ class BasicMultiUpdateBlock(nn.Module):
             # — measured ~0.18 ms/iter of conv + f32 cast + carry traffic
             # at flagship shapes.
             return net, None, delta
-        return net, self.upsample_mask(net[0]), delta
+        with jax.named_scope("upsample"):
+            mask = self.upsample_mask(net[0])
+        return net, mask, delta
 
     def _merged_head_hidden(self, net0: jax.Array) -> jax.Array:
         """relu of the concatenated flow/mask first-stage convs on net[0],

@@ -20,7 +20,14 @@ Two recording styles:
   thread-local stack (children inherit trace/parent ids automatically);
 * ``tracer.record("queue_wait", t0, t1, rid)`` — after-the-fact, for
   phases measured by another component (the batcher reconstructs each
-  request's queue-wait/dispatch/host-fetch from the dispatch worker).
+  request's queue-wait/dispatch/host-fetch from the dispatch worker);
+* ``with tracer.phase("launch", trace_id=btid):`` — a ``span`` that is
+  ALSO a ``jax.profiler.TraceAnnotation`` for its length, so while a
+  profile runs the same phase is an event on the host plane of the
+  ``.xplane.pb``, stamped by the profiler on the clock it stamps the
+  device with.  Components without a tracer (the engine) time a phase
+  with ``timed_phase`` and hand its ``window`` to whoever records it.
+  ``clock_annotation`` ties the two clocks (docs/observability.md).
 
 Timestamps are ``time.perf_counter`` values (monotonic, ns-resolution);
 the export converts them to epoch microseconds with one process-wide
@@ -39,7 +46,8 @@ import uuid
 from collections import deque
 from typing import Dict, Iterator, List, Optional, Tuple
 
-__all__ = ["Span", "Tracer", "to_chrome_trace"]
+__all__ = ["Span", "Tracer", "clock_annotation", "timed_phase",
+           "to_chrome_trace"]
 
 # perf_counter -> unix epoch seconds, fixed at import so every span (and
 # every thread) converts identically.
@@ -67,6 +75,76 @@ class Span:
     def wall_t0(self) -> float:
         """Start as unix epoch seconds."""
         return self.t0 + _EPOCH_OFFSET
+
+
+def _no_annotation(name: str, **attrs):
+    """Stands in for ``TraceAnnotation`` where JAX is not installed."""
+    return contextlib.nullcontext()
+
+
+_annotation_cls = None
+
+
+def _annotation(name: str, attrs: Dict):
+    """``jax.profiler.TraceAnnotation(name, **attrs)``.  JAX is imported
+    on first use, so ``obs`` stays importable without it.  With no
+    capture running the constructor returns before it looks at
+    ``attrs`` (``TraceMe`` checks the profiler's level first), so pass
+    values as they are and format nothing for it."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except ImportError:
+            cls = _no_annotation
+        _annotation_cls = cls
+    return _annotation_cls(name, **attrs)
+
+
+class timed_phase:
+    """``with timed_phase("launch", batch_size=8) as ph:`` holds a
+    ``TraceAnnotation`` open and reads ``perf_counter`` just inside it
+    at both ends: ``ph.window`` is the ``(t0, t1)`` a ring span of the
+    same phase is recorded from (``Tracer.record(name, *ph.window,
+    ...)``), so the annotation and the span bracket the same code."""
+
+    __slots__ = ("name", "attrs", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, **attrs):
+        self.name = name
+        self.attrs = attrs
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "timed_phase":
+        self._ann = _annotation(self.name, self.attrs)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        return False
+
+    @property
+    def window(self) -> Tuple[float, float]:
+        return self.t0, self.t1
+
+
+def clock_annotation():
+    """``TraceAnnotation("obs.clock", unix_ns=, perf_counter_ns=)``: one
+    instant on both of the program's clocks, stamped by the profiler on
+    its own.  ``perf_counter_ns`` is the clock of ``Span.t0``;
+    ``unix_ns`` is the same instant as the Chrome export writes it
+    (``ts`` = perf_counter + the process's fixed offset).  The
+    profilers (utils/profiling.py) emit one when a capture starts and
+    one when it stops; a reader lays the ring's spans onto the trace
+    from the pair: ``trace_ns = event.start_ns + (ts_us * 1e3 -
+    unix_ns)``."""
+    now = time.perf_counter()
+    return _annotation("obs.clock",
+                       {"unix_ns": int((now + _EPOCH_OFFSET) * 1e9),
+                        "perf_counter_ns": int(now * 1e9)})
 
 
 class _Live:
@@ -109,9 +187,6 @@ class Tracer:
         outbound ``X-Trace-Context`` header, then records the span after
         the forward returns) mint here and pass it to ``record``."""
         return uuid.uuid4().hex[:16]
-
-    # internal alias kept for the pre-PR 20 private callers
-    _new_span_id = new_span_id
 
     def current(self) -> Optional[Tuple[str, str]]:
         """(trace_id, span_id) of this thread's innermost open span."""
@@ -167,7 +242,7 @@ class Tracer:
                 trace_id = self.new_trace_id()
         elif parent_id is None and cur is not None and cur[0] == trace_id:
             parent_id = cur[1]
-        sid = self._new_span_id()
+        sid = self.new_span_id()
         live = _Live(trace_id, sid, dict(attrs))
         stack = getattr(self._tls, "stack", None)
         if stack is None:
@@ -186,6 +261,18 @@ class Tracer:
             with self._lock:
                 self._recorded += 1
                 self._spans.append(span)
+
+    @contextlib.contextmanager
+    def phase(self, name: str, trace_id: Optional[str] = None,
+              parent_id: Optional[str] = None, **attrs) -> Iterator[_Live]:
+        """``span`` plus a ``jax.profiler.TraceAnnotation(name, **attrs)``
+        held open for its length: one ring span, and while a profile
+        runs one event of the same name on the trace's host plane.  The
+        annotation sees the attrs given here; attrs set on the yielded
+        handle later go to the ring only."""
+        with _annotation(name, attrs), \
+                self.span(name, trace_id, parent_id, **attrs) as live:
+            yield live
 
     # -------------------------------------------------------------- reading
 

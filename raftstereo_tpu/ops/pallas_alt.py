@@ -433,6 +433,7 @@ def _alt_pyr_radial_fwd_impl(f1flat, f2cat, x, bounds, radius,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((r, blk, lk), lambda i, j: (i, j, 0),
                                memory_space=pltpu.VMEM),
+        name="alt_lookup_fwd",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(*operands)
@@ -492,6 +493,7 @@ def _alt_pyr_fwd_impl(f1flat, f2cat, taps, bounds, prec="highest",
         ],
         out_specs=pl.BlockSpec((r, blk, lk), lambda i, j: (i, j, 0),
                                memory_space=pltpu.VMEM),
+        name="alt_lookup_taps_fwd",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(f1flat, f2cat, t)
@@ -531,6 +533,7 @@ def _alt_pyr_bwd_impl(f1flat, f2cat, taps, g, bounds, prec="highest"):
             pl.BlockSpec((r, w2cat, c), lambda i, j: (i, 0, 0),
                          memory_space=pltpu.VMEM),
         ),
+        name="alt_lookup_bwd",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(f1flat, f2cat, t, gg)

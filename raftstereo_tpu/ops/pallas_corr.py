@@ -268,6 +268,7 @@ def _lookup_fwd_impl(vflat, taps, bounds):
         ],
         out_specs=pl.BlockSpec((r, blk, kk), lambda i, j: (i, j, 0),
                                memory_space=pltpu.VMEM),
+        name="corr_lookup_fwd",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(vflat, t)
@@ -294,6 +295,7 @@ def _lookup_bwd_impl(taps, g, vflat_shape, vol_dtype_name, bounds):
         ],
         out_specs=pl.BlockSpec((r, blk, w2), lambda i, j: (i, j, 0),
                                memory_space=pltpu.VMEM),
+        name="corr_lookup_bwd",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(t, gg)

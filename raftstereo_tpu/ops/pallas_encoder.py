@@ -458,6 +458,7 @@ def _enc_conv(x, stats, w9, bias, res=None, res_stats=None,
         grid=grid,
         in_specs=in_specs,
         out_specs=tuple(out_specs),
+        name="encoder_conv",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(*operands)
@@ -484,6 +485,7 @@ def _packed_stats(x):
                                 memory_space=pltpu.VMEM),
                    pl.BlockSpec((1, 1, c2), lambda i, j: (i, 0, 0),
                                 memory_space=pltpu.VMEM)),
+        name="encoder_stats",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(x)
@@ -617,6 +619,7 @@ def _stage_on_packed(xp, st1, params, n, space_axis=None, space_size=1,
                   row_spec(), stat_spec(), stat_spec(),
                   row_spec(), stat_spec(), stat_spec()],
         out_specs=row_spec(),
+        name="encoder_finish",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(xp, *st1, c11, *st11, c21, *st21)
@@ -831,6 +834,7 @@ def _stem_conv1_s2(img, c1_params, dt, boundary=None, want_stats=True):
                           memory_space=pltpu.VMEM)]
             + [pl.BlockSpec((1, 1, co2), lambda i, j: (i, 0, 0),
                             memory_space=pltpu.VMEM)] * (2 * want_stats)),
+        name="encoder_stem_s2",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(xq, xh, w7, bias)
@@ -891,6 +895,7 @@ def _stem_conv1(img, c1_params, dt, boundary=None, want_stats=True):
                           memory_space=pltpu.VMEM)]
             + [pl.BlockSpec((1, 1, co2), lambda i, j: (i, 0, 0),
                             memory_space=pltpu.VMEM)] * (2 * want_stats)),
+        name="encoder_stem",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(xp, xh, w7, bias)
@@ -1201,6 +1206,7 @@ def _in_bwd_means(u, xhat):
                                 memory_space=pltpu.VMEM),
                    pl.BlockSpec((1, 1, c2), lambda i, j: (i, 0, 0),
                                 memory_space=pltpu.VMEM)),
+        name="encoder_norm_bwd_sums",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(up, vp)

@@ -395,6 +395,7 @@ def _fused_forward(h, ext, corr, disp, cz, cr, cq, wpack):
         out_specs=(full(h), pl.BlockSpec(
             (1, hgt, wd, 2), lambda i: (i, 0, 0, 0),
             memory_space=pltpu.VMEM)),
+        name="gru_fused_step",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(*operands)

@@ -309,6 +309,7 @@ def _l2_entry(xp, w3, b3, wp1, bp1, dt):
                          memory_space=pltpu.VMEM),
         ],
         out_specs=(row, row, stat, stat, stat, stat),
+        name="layer2_entry",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(xp, xh, w3, b3[None, None, :], wp1, bp1[None, None, :])
@@ -349,6 +350,7 @@ def _l2_conv(x, aff, w, bias, dt, res=None, res_aff=None):
         grid=grid,
         in_specs=in_specs,
         out_specs=(row, stat, stat),
+        name="layer2_conv",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(*operands)
@@ -364,6 +366,7 @@ def _l2_finish(p, ap, c2, a2, c4, a4, dt):
         grid=(b, h2 // r),
         in_specs=[row, stat, stat, row, stat, stat, row, stat, stat],
         out_specs=row,
+        name="layer2_finish",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(p, *ap, c2, *a2, c4, *a4)

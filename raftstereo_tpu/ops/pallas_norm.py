@@ -98,6 +98,7 @@ def _pallas_forward(x, relu):
                                 memory_space=pltpu.VMEM),
                    pl.BlockSpec((1, 1, c), lambda i, j: (i, 0, 0),
                                 memory_space=pltpu.VMEM)),
+        name="norm_stats",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(x)
@@ -126,6 +127,7 @@ def _pallas_forward(x, relu):
         ],
         out_specs=pl.BlockSpec((1, r, w, c), lambda i, j: (i, j, 0, 0),
                                memory_space=pltpu.VMEM),
+        name="norm_apply",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(x, mean, rstd)

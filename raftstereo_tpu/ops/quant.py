@@ -253,6 +253,7 @@ def pallas_int8_corr_volume(q1: jax.Array, s1: jax.Array, q2: jax.Array,
         ],
         out_specs=pl.BlockSpec((r, w1p, w2p), lambda i: (i, 0, 0),
                                memory_space=pltpu.VMEM),
+        name="quant_corr_volume_fwd",
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
     )(q1f, q2f, s1f, s2f)

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import threading
 import time
 from typing import Deque, Dict, Optional, Tuple
@@ -33,10 +34,16 @@ from typing import Deque, Dict, Optional, Tuple
 import numpy as np
 
 from ..config import ServeConfig
+from ..obs.trace import timed_phase
 from .metrics import ServeMetrics
 
 __all__ = ["DynamicBatcher", "Future", "Overloaded", "RequestTimedOut",
            "ServeResult", "ShuttingDown"]
+
+
+# Batch trace ids ``batch:<n>``: one counter for the process, so the
+# replicas of a cluster (one batcher each, one shared tracer) never collide.
+_BATCH_SEQ = itertools.count(1)
 
 
 class Overloaded(RuntimeError):
@@ -244,48 +251,89 @@ class DynamicBatcher:
         """Key whose head request has waited longest (caller holds lock)."""
         return min(self._queues, key=lambda k: self._queues[k][0].seq)
 
+    def _phase(self, name: str, btid: Optional[str], **attrs):
+        """One phase of the worker's cycle: a ring span under the batch's
+        trace plus a profiler annotation (``Tracer.phase``); without a
+        tracer the annotation alone."""
+        if self.tracer is None:
+            return timed_phase(name, **attrs)
+        return self.tracer.phase(name, trace_id=btid, **attrs)
+
     def _loop(self) -> None:
+        """The worker's cycle, in phases that leave no hole, each recorded
+        ONCE per dispatch under the batch's own trace ``batch:<seq>``:
+        ``queue_empty`` (nothing queued) -> ``batch_form`` (first request
+        seen -> batch closed) -> ``pad_bucket`` -> ``launch`` ->
+        ``device_wait`` -> ``host_fetch`` (the engine's, handed over in
+        ``last_segments``) -> ``reply_handoff`` (docs/observability.md)."""
         max_wait_s = self.cfg.max_wait_ms / 1000.0
         while True:
+            btid = f"batch:{next(_BATCH_SEQ)}"
             with self._cv:
-                while not self._closed and self._depth == 0:
-                    self._cv.wait()
+                if not self._closed and self._depth == 0:
+                    with self._phase("queue_empty", btid):
+                        while not self._closed and self._depth == 0:
+                            self._cv.wait()
                 if self._depth == 0:  # closed and drained
                     return
-                key = self._oldest_key()
-                deadline = self._queues[key][0].t_enqueue + max_wait_s
-                # Hold the batch open until it fills or the oldest member's
-                # deadline passes; new arrivals notify the condition.
-                while (len(self._queues.get(key, ()))
-                       < self.cfg.max_batch_size and not self._closed):
-                    remaining = deadline - time.perf_counter()
-                    if remaining <= 0:
-                        break
-                    self._cv.wait(remaining)
-                q = self._queues.get(key)
-                if not q:  # drained by a non-drain stop
-                    continue
-                batch = [q.popleft() for _ in
-                         range(min(len(q), self.cfg.max_batch_size))]
-                if not q:
-                    del self._queues[key]
-                self._depth -= len(batch)
-                # Backlog measured at batch close, including this batch:
-                # the signal that decides graceful degradation.
-                backlog = self._depth + len(batch)
-                self.metrics.queue_depth.set(self._depth)
-            self._dispatch(key, batch, backlog)
+                with self._phase("batch_form", btid) as form:
+                    key = self._oldest_key()
+                    deadline = self._queues[key][0].t_enqueue + max_wait_s
+                    # Hold the batch open until it fills or the oldest
+                    # member's deadline passes; new arrivals notify the
+                    # condition.
+                    closed_by = "full"
+                    while (len(self._queues.get(key, ()))
+                           < self.cfg.max_batch_size):
+                        if self._closed:
+                            closed_by = "shutdown"
+                            break
+                        remaining = deadline - time.perf_counter()
+                        if remaining <= 0:
+                            closed_by = "deadline"
+                            break
+                        self._cv.wait(remaining)
+                    q = self._queues.get(key)
+                    if not q:  # drained by a non-drain stop
+                        continue
+                    batch = [q.popleft() for _ in
+                             range(min(len(q), self.cfg.max_batch_size))]
+                    if not q:
+                        del self._queues[key]
+                    self._depth -= len(batch)
+                    # Backlog measured at batch close, including this
+                    # batch: the signal that decides graceful degradation.
+                    backlog = self._depth + len(batch)
+                    self.metrics.queue_depth.set(self._depth)
+                    form.attrs.update(closed_by=closed_by,
+                                      batch_size=len(batch),
+                                      bucket=f"{key[0]}x{key[1]}")
+            self._dispatch(key, batch, backlog, btid)
 
     def _trace_batch(self, key: _Key, batch, iters: int, degraded: bool,
-                     t_run0: float, t_done: float, error=None) -> None:
+                     t_run0: float, t_done: float, error=None,
+                     btid: Optional[str] = None) -> None:
         """Reconstruct each request's phase spans from the dispatch the
         worker just ran: queue wait (enqueue -> batch close), dispatch
         (engine call through device compute) and host fetch — siblings
         under the request's trace id, so their durations sum to the
-        server-side latency (asserted in tests/test_obs.py)."""
+        server-side latency (asserted in tests/test_obs.py).  The
+        engine's phases of the dispatch itself go ONCE under the batch's
+        trace ``btid``, from the same windows."""
         seg = getattr(self.engine, "last_segments", None) if error is None \
             else None
         bucket = f"{key[0]}x{key[1]}"
+        if seg is not None and btid is not None:
+            battrs = {"batch_size": len(batch), "bucket": bucket,
+                      "iters": iters,
+                      "request_ids": [r.trace_id for r in batch
+                                      if r.trace_id is not None]}
+            for name, window in (("pad_bucket", seg.get("pad")),
+                                 ("launch", seg.get("launch")),
+                                 ("device_wait", seg.get("device_wait")),
+                                 ("host_fetch", seg.get("host_fetch"))):
+                if window:
+                    self.tracer.record(name, *window, btid, attrs=battrs)
         for r in batch:
             if r.trace_id is None:
                 continue
@@ -311,7 +359,8 @@ class DynamicBatcher:
                                r.trace_id, parent_id=parent)
             self.tracer.record("host_fetch", *seg["host_fetch"], r.trace_id)
 
-    def _dispatch(self, key: _Key, batch, backlog: int) -> None:
+    def _dispatch(self, key: _Key, batch, backlog: int,
+                  btid: Optional[str] = None) -> None:
         now = time.perf_counter()
         timeout_s = self.cfg.request_timeout_ms / 1000.0
         alive = []
@@ -351,14 +400,18 @@ class DynamicBatcher:
             for r in alive:
                 r.future._resolve(exc=e)
             return
-        done = time.perf_counter()
-        if self.tracer is not None:
-            self._trace_batch(key, alive, iters, degraded, t_run0, done)
-        self.metrics.batch_size.observe(len(alive))
-        for r, d in zip(alive, disps):
-            latency = done - r.t_enqueue
-            self.metrics.latency.observe(latency)
-            self.metrics.responses.inc()
-            r.future._resolve(value=ServeResult(
-                disparity=d, iters=iters, degraded=degraded,
-                batch_size=len(alive), latency_s=latency))
+        # reply_handoff: from the engine's return (disparities un-padded)
+        # until every future of the batch is resolved.
+        with self._phase("reply_handoff", btid, batch_size=len(alive)):
+            done = time.perf_counter()
+            if self.tracer is not None:
+                self._trace_batch(key, alive, iters, degraded, t_run0, done,
+                                  btid=btid)
+            self.metrics.batch_size.observe(len(alive))
+            for r, d in zip(alive, disps):
+                latency = done - r.t_enqueue
+                self.metrics.latency.observe(latency)
+                self.metrics.responses.inc()
+                r.future._resolve(value=ServeResult(
+                    disparity=d, iters=iters, degraded=degraded,
+                    batch_size=len(alive), latency_s=latency))

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import ServeConfig
+from ..obs.trace import timed_phase
 from ..ops.image import BucketPadder
 from ..ops.pallas_gru import resolve_gru_backend
 from ..ops.quant import MODES, config_for_mode, default_mode
@@ -501,9 +502,14 @@ class BatchEngine:
     @property
     def last_segments(self) -> Optional[Dict[str, object]]:
         """Phase timing of the last dispatch on THIS thread:
-        ``{"pad", "dispatch", "host_fetch"}`` as (perf_counter t0, t1)
-        windows plus ``"compile"`` — the raw material the batcher and
-        stream runner turn into per-request trace spans (obs/trace.py)."""
+        ``{"pad", "launch", "device_wait", "dispatch", "host_fetch"}`` as
+        (perf_counter t0, t1) windows plus ``"compile"`` — the raw
+        material the batcher and stream runner turn into trace spans
+        (obs/trace.py).  ``dispatch`` (the ``device_compute`` span) is
+        ``launch`` (the jitted call until it returns) followed by
+        ``device_wait`` (``block_until_ready``).  Each window is read
+        inside a ``timed_phase`` of the same name, so a profile capture
+        shows the same phases as host events."""
         return getattr(self._seg, "last", None)
 
     def _pad_pairs(self, pairs):
@@ -512,7 +518,12 @@ class BatchEngine:
         keyed by bucket alone.  All pairs must map to one bucket (the
         batcher groups by bucket before dispatching)."""
         assert pairs, "empty batch"
-        t_pad0 = time.perf_counter()
+        with timed_phase("pad_bucket", batch_size=len(pairs)) as ph:
+            staged = self._stage_pairs(pairs)
+        self._seg.pad = ph.window
+        return staged
+
+    def _stage_pairs(self, pairs):
         assert len(pairs) <= self.cfg.max_batch_size, (
             f"batch {len(pairs)} exceeds max_batch_size "
             f"{self.cfg.max_batch_size}")
@@ -538,7 +549,6 @@ class BatchEngine:
             if pad_rows:
                 i1 = jnp.pad(i1, ((0, pad_rows), (0, 0), (0, 0), (0, 0)))
                 i2 = jnp.pad(i2, ((0, pad_rows), (0, 0), (0, 0), (0, 0)))
-        self._seg.pad = (t_pad0, time.perf_counter())
         return padders, hw, i1, i2, pad_rows
 
     def _dispatch(self, key, call):
@@ -568,17 +578,21 @@ class BatchEngine:
             if self.metrics is not None:
                 (self.metrics.compile_misses if miss
                  else self.metrics.compile_hits).labels(**labels).inc()
-            start = time.perf_counter()
-            with self._device_ctx():
-                out_dev = call()
-            # Two measured phases: device compute (dispatch until the
-            # result exists on device) and the device->host copy.  Both
-            # still happen under the engine lock — fetch-before-release is
-            # the engine's completion contract.
-            jax.block_until_ready(out_dev)
-            t_compute = time.perf_counter()
-            out = [np.asarray(o, np.float32) for o in out_dev]
-            t_fetch = time.perf_counter()
+            # Three measured phases: launch (the asynchronous call of the
+            # jitted function until it returns — a slow launch is host
+            # time, not device time), device_wait (until the result
+            # exists on device) and the device->host copy.  All under the
+            # engine lock — fetch-before-release is the engine's
+            # completion contract.
+            with timed_phase("launch", bucket=labels["bucket"],
+                             iters=key[2]) as ph_launch:
+                with self._device_ctx():
+                    out_dev = call()
+            with timed_phase("device_wait") as ph_wait:
+                jax.block_until_ready(out_dev)
+            with timed_phase("host_fetch") as ph_fetch:
+                out = [np.asarray(o, np.float32) for o in out_dev]
+            start, t_compute, t_fetch = ph_launch.t0, ph_wait.t1, ph_fetch.t1
             runtime = t_fetch - start
             self.last_batch_runtime = runtime
             self.last_included_compile = miss
@@ -586,6 +600,8 @@ class BatchEngine:
                 self._compiled.add(key)
         self._seg.last = {
             "pad": getattr(self._seg, "pad", None),
+            "launch": ph_launch.window,
+            "device_wait": ph_wait.window,
             "dispatch": (start, t_compute),
             "host_fetch": (t_compute, t_fetch),
             "compile": miss,

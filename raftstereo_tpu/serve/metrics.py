@@ -27,13 +27,15 @@ render call.
 from __future__ import annotations
 
 import math
+import sys
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from ..utils.profiling import LatencyHistogram
 
-__all__ = ["ClusterMetrics", "Counter", "Gauge", "LabelFamily",
-           "MetricsRegistry", "ServeMetrics"]
+__all__ = ["CallbackGauge", "ClusterMetrics", "Counter", "Gauge",
+           "LabelFamily", "MetricsRegistry", "ServeMetrics",
+           "device_memory"]
 
 
 class Counter:
@@ -77,6 +79,37 @@ class Gauge:
     def value(self) -> float:
         with self._lock:
             return self._value
+
+
+class CallbackGauge:
+    """A gauge read at scrape time: ``value`` is ``fn()`` when the
+    registry renders (device memory, which only the runtime knows)."""
+
+    def __init__(self, fn):
+        self._fn = fn
+
+    @property
+    def value(self) -> float:
+        return float(self._fn())
+
+
+# device.memory_stats() keys -> what the gauges and /debug/vars call them.
+# ``peak_bytes_reserved`` is the running program's temporaries on this
+# runtime, which ``peak_bytes_in_use`` leaves out (PERF.md section 3).
+DEVICE_MEMORY_KEYS = ("bytes_in_use", "peak_bytes_in_use",
+                      "peak_bytes_reserved")
+
+
+def device_memory() -> Dict[str, int]:
+    """``DEVICE_MEMORY_KEYS`` of the fullest local device, each taken as
+    the largest over the devices; zeros where the backend reports none
+    (CPU) or where this process never imported JAX (no device to ask:
+    the router, the lint)."""
+    jax = sys.modules.get("jax")
+    stats = ([d.memory_stats() or {} for d in jax.local_devices()]
+             if jax is not None else [])
+    return {k: max((int(s.get(k, 0)) for s in stats), default=0)
+            for k in DEVICE_MEMORY_KEYS}
 
 
 class LabelFamily:
@@ -153,9 +186,23 @@ class MetricsRegistry:
         obj = LabelFamily(Counter, labels) if labels else Counter()
         return self._register("counter", name, help_, obj)
 
-    def gauge(self, name: str, help_: str, labels: Sequence[str] = ()):
-        obj = LabelFamily(Gauge, labels) if labels else Gauge()
+    def gauge(self, name: str, help_: str, labels: Sequence[str] = (),
+              fn=None):
+        """``fn`` makes it a ``CallbackGauge`` read at render time."""
+        obj = (CallbackGauge(fn) if fn is not None
+               else LabelFamily(Gauge, labels) if labels else Gauge())
         return self._register("gauge", name, help_, obj)
+
+    def device_memory_gauges(self, prefix: str) -> None:
+        """``<prefix>_device_<key>`` for each of ``DEVICE_MEMORY_KEYS``,
+        read from the runtime at scrape time."""
+        for key in DEVICE_MEMORY_KEYS:
+            self.gauge(
+                f"{prefix}_device_{key}",
+                f"device.memory_stats()[{key!r}] of the fullest local "
+                "device at scrape time (reserved = the running program's "
+                "temporaries, outside in_use)",
+                fn=lambda key=key: device_memory()[key])
 
     def histogram(self, name: str, help_: str,
                   bounds=None, lo: float = 1e-4,
@@ -238,6 +285,15 @@ class ServeMetrics:
             "XLA compile — tier= is the resolved precision mode, so a "
             "per-tier compile under traffic is attributable",
             labels=("bucket", "iters", "mode", "tier"))
+        self.xla_compiles = r.counter(
+            "serve_xla_compiles_total",
+            "programs this process built (kind=compile) or read back from "
+            "the persistent compile cache (kind=cache_load), counted by "
+            "jax.monitoring: EVERY program, the eager per-occupancy "
+            "staging ones included, which the engine's own hit/miss "
+            "counters cannot see",
+            labels=("kind",))
+        r.device_memory_gauges("serve")
         self.queue_depth = r.gauge(
             "serve_queue_depth", "requests currently waiting in the queue")
         self.batch_size = r.histogram(

@@ -73,12 +73,13 @@ import numpy as np
 from .. import wire
 from ..config import ServeConfig
 from ..obs import Tracer, build_info, dump_threads, trace_response
+from ..obs.trace import timed_phase
 from ..utils.faults import FaultPlan
 from ..utils.profiling import OnDemandProfiler, ProfilerBusy
 from .batcher import DynamicBatcher, Overloaded, RequestTimedOut, ShuttingDown
 from .engine import BatchEngine
 from .httpbase import JsonRequestHandler
-from .metrics import ServeMetrics
+from .metrics import ServeMetrics, device_memory
 from .sched import IterationScheduler
 from .spatial import (SPATIAL_ENDPOINT, admit_spatial, route_spatial,
                       capability as spatial_capability)
@@ -281,6 +282,10 @@ class _Handler(JsonRequestHandler):
     # the same keep-alive reuse reason as _wire_ctx.
     _trace: Optional[Tuple[Optional[str], Optional[str]]] = None
 
+    # Pre-minted id of the CURRENT /predict request's ``admission`` span
+    # (its ``wire_decode`` child names it before it is recorded).
+    _adm_span: Optional[str] = None
+
     # ------------------------------------------------------------- plumbing
     # (_send/_json/_reject_body come from JsonRequestHandler, shared
     # byte-for-byte with the cluster router's handler.)
@@ -317,7 +322,25 @@ class _Handler(JsonRequestHandler):
                    meta: Dict, endpoint: str, rid: str,
                    t0: float) -> None:
         """Terminal 200 for /predict: encode the disparity in whichever
-        response format this request negotiated (``_wire_ctx``)."""
+        response format this request negotiated (``_wire_ctx``).  The
+        whole of it — encode and write — is the request's ``reply``
+        phase; it closes in a ``finally``, so a client that hangs up
+        mid-write still leaves the span."""
+        with self._req_phase(srv, "reply", rid):
+            self._reply_ok(srv, disparity, meta, endpoint, rid, t0)
+
+    def _req_phase(self, srv: "StereoServer", name: str, rid: str,
+                   parent_id: Optional[str] = None):
+        """``Tracer.phase`` under this request's (continued) trace id;
+        an unsampled request (trace id None) keeps the profiler
+        annotation and records no span."""
+        tid = (self._trace or (rid, None))[0]
+        if tid is None:
+            return timed_phase(name)
+        return srv.tracer.phase(name, trace_id=tid, parent_id=parent_id)
+
+    def _reply_ok(self, srv: "StereoServer", disparity: np.ndarray,
+                  meta: Dict, endpoint: str, rid: str, t0: float) -> None:
         ctx = self._wire_ctx
         if ctx is None:
             self._finish(200, {"disparity": encode_array(disparity),
@@ -465,6 +488,7 @@ class _Handler(JsonRequestHandler):
                           "recorded": srv.tracer.recorded,
                           "dropped": srv.tracer.dropped},
                 "profile_running": srv.profiler.running,
+                "memory": device_memory(),
             })
         else:
             self._json(404, {"error": f"no such path {self.path!r}"})
@@ -609,7 +633,34 @@ class _Handler(JsonRequestHandler):
         # bounds concurrent decoded pairs.  Without this, a handful of
         # parallel near-limit POSTs OOM the host before queue_limit
         # ever engages.
-        wire_fields = None
+        # Body read + decode is the request's ``wire_decode`` phase, a
+        # child of the ``admission`` span (whose id is minted here so
+        # the child can name it before the parent is recorded).
+        self._adm_span = srv.tracer.new_span_id()
+        with self._req_phase(srv, "wire_decode", rid,
+                             parent_id=self._adm_span):
+            decoded = self._read_pair(srv, length, binary_in, binary_out,
+                                      endpoint, rid, t_req0)
+        if decoded is None:  # already answered
+            return
+        (left, right, iters, session_id, seq_no, deadline_ms, priority,
+         accuracy, spatial) = decoded
+        del decoded
+        try:
+            self._predict_admitted(srv, endpoint, rid, t_req0, left, right,
+                                   iters, session_id, seq_no, deadline_ms,
+                                   priority, accuracy, spatial)
+        finally:
+            srv.end_predict()
+
+    def _read_pair(self, srv: "StereoServer", length: int, binary_in: bool,
+                   binary_out: bool, endpoint: str, rid: str,
+                   t_req0: float):
+        """Read and decode one /predict body under a decode slot.
+        Returns ``(left, right, iters, session_id, seq_no, deadline_ms,
+        priority, accuracy, spatial)`` with the in-flight count taken
+        (the caller owes ``end_predict``), or None when the request was
+        refused and already answered."""
         with srv.decode_slots:
             if binary_in:
                 # Decoded planes may legitimately exceed the body byte
@@ -630,7 +681,7 @@ class _Handler(JsonRequestHandler):
                               else "bad wire frame: ")
                     self._finish(400, {"error": f"{prefix}{e}"},
                                  endpoint, rid, t_req0)
-                    return
+                    return None
                 raw = b""
             else:
                 # Drain the body BEFORE any reply: under HTTP/1.1
@@ -644,11 +695,11 @@ class _Handler(JsonRequestHandler):
                 self._finish(400, {"error": "body shorter than "
                                             "Content-Length"},
                              endpoint, rid, t_req0)
-                return
+                return None
             if self.path != "/predict":
                 self._finish(404, {"error": f"no such path {self.path!r}"},
                              "other", rid, t_req0)
-                return
+                return None
             # Readiness gate + in-flight count, atomically: a warming
             # server must not accept traffic (the request would stall
             # behind the warmup compiles), a draining one must not
@@ -663,7 +714,7 @@ class _Handler(JsonRequestHandler):
                 self._finish(503, {"error": "unavailable",
                                    "detail": detail},
                              endpoint, rid, t_req0, {"Retry-After": "1"})
-                return
+                return None
             try:
                 if binary_in:
                     req = dec.request()
@@ -711,14 +762,10 @@ class _Handler(JsonRequestHandler):
                 srv.end_predict()
                 self._finish(400, {"error": f"bad request: {e}"},
                              endpoint, rid, t_req0)
-                return
+                return None
             del raw, payload
-        try:
-            self._predict_admitted(srv, endpoint, rid, t_req0, left, right,
-                                   iters, session_id, seq_no, deadline_ms,
-                                   priority, accuracy, spatial)
-        finally:
-            srv.end_predict()
+        return (left, right, iters, session_id, seq_no, deadline_ms,
+                priority, accuracy, spatial)
 
     def _predict_admitted(self, srv: "StereoServer", endpoint, rid, t_req0,
                           left, right, iters, session_id, seq_no,
@@ -917,7 +964,8 @@ class _Handler(JsonRequestHandler):
         # request either enters the batcher queue or the session path.
         srv.tracer.record("admission", t_req0, time.perf_counter(), tid,
                           attrs={"endpoint": endpoint,
-                                 "shape": list(left.shape)})
+                                 "shape": list(left.shape)},
+                          span_id=self._adm_span)
         if use_spatial:
             self._spatial_dispatch(srv, endpoint, rid, t_req0,
                                    left, right, iters)
@@ -1189,6 +1237,9 @@ class StereoServer(ThreadingHTTPServer):
         # ``config.stream.tier`` is set.  None = local-pin-only.
         self.tier_publisher = None
         self.profiler = OnDemandProfiler(log_dir="runs/serve/profile")
+        # Ends build_server's subscription to the process's compile
+        # events (``watch_xla_compiles``); None when built by hand.
+        self.unwatch_compiles = None
         # Readiness (live vs ready on /healthz): set once warmup
         # finishes.  build_server passes start_ready=False and owns the
         # gate — it warms either before returning (blocking) or in a
@@ -1357,6 +1408,25 @@ class StereoServer(ThreadingHTTPServer):
             self.batcher.stop(drain=True)
         if self.scheduler is not None:
             self.scheduler.stop(drain=True)
+        if self.unwatch_compiles is not None:
+            self.unwatch_compiles()
+
+
+def watch_xla_compiles(metrics: ServeMetrics, tracer: Tracer):
+    """Count every program this process builds or loads from the
+    persistent cache from now on (``serve_xla_compiles_total{kind=}``) and
+    record each as a ``compile`` span under the trace ``xla`` — through
+    the one ``jax.monitoring`` listener the retrace guard owns.  Returns
+    the function that stops it."""
+    from ..analysis.retrace_guard import subscribe
+
+    def on_compile(kind: str, duration_s: float) -> None:
+        metrics.xla_compiles.labels(kind=kind).inc()
+        t1 = time.perf_counter()
+        tracer.record("compile", t1 - duration_s, t1, "xla",
+                      attrs={"kind": kind})
+
+    return subscribe(on_compile)
 
 
 def build_server(model, variables, config: ServeConfig,
@@ -1381,6 +1451,8 @@ def build_server(model, variables, config: ServeConfig,
     """
     metrics = metrics or ServeMetrics()
     tracer = tracer or Tracer(capacity=config.trace_buffer)
+    # Before anything compiles: warm-up programs count too.
+    unwatch_compiles = watch_xla_compiles(metrics, tracer)
     # ONE fault plan for the whole process (server + every engine): a
     # single POST /debug/faults arms every hook, and a count budget is
     # consumed once process-wide (utils/faults.py).
@@ -1515,6 +1587,7 @@ def build_server(model, variables, config: ServeConfig,
                           cascades=cascades,
                           cascade_reasons=cascade_reasons,
                           fault_plan=fault_plan)
+    server.unwatch_compiles = unwatch_compiles
     if config.stream is not None and config.stream.tier is not None:
         from ..stream.tier import TierClient, TierPublisher
 

@@ -79,8 +79,13 @@ def make_train_step(model, tx, cfg: TrainConfig, lr_schedule=None,
         with override_fused_layer2(False):
             preds = model.forward(variables, img1, img2,
                                   iters=cfg.train_iters)
-        return sequence_loss(preds, disp_gt, valid,
-                             loss_gamma=cfg.loss_gamma, max_flow=cfg.max_flow)
+        # ``loss`` joins the model's stage scopes (models/raft_stereo.py
+        # STAGES) in a device trace; its backward inherits the name under
+        # transpose(jvp(loss)).
+        with jax.named_scope("loss"):
+            return sequence_loss(preds, disp_gt, valid,
+                                 loss_gamma=cfg.loss_gamma,
+                                 max_flow=cfg.max_flow)
 
     if cfg.device_photometric:
         from ..data.device_aug import DevicePhotometric

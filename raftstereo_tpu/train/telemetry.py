@@ -71,6 +71,8 @@ class TrainMetrics:
             lo=1e-3, hi=600.0)
         self.health = {name: r.gauge(name, help_)
                        for name, help_ in _HEALTH_GAUGES}
+        # train_device_{bytes_in_use,peak_bytes_in_use,peak_bytes_reserved}
+        r.device_memory_gauges("train")
         self._data_total = 0.0
         self._step_total = 0.0
 

@@ -2,12 +2,16 @@
 
 The reference's only observability is wall-clock FPS in the KITTI evaluator
 (reference: evaluate_stereo.py:77-81,105-107).  The TPU-native equivalent is
-the XLA profiler: device traces viewable in TensorBoard / Perfetto, plus
-host-side step annotations that bracket each training step so device work
-lines up with program phases.  This module wraps ``jax.profiler`` so the train
-CLI (``--profile_steps``) and ad-hoc scripts never import it directly, and
-adds a lightweight wall-clock ``Timer`` for the places where a full trace is
-overkill.
+the XLA profiler: device traces viewable in TensorBoard / Perfetto, over
+which the program's own phases (``obs.Tracer.phase``) appear as host events.
+This module wraps ``jax.profiler`` so the train CLI (``--profile_steps``) and
+the serving front-end (``POST /debug/profile``) never import it directly, and
+holds the fixed-bucket ``LatencyHistogram`` the metrics share.
+
+Every capture is a LIGHT one (``_start_capture``): the Python tracer off and
+the host tracer at the level that keeps ``TraceAnnotation``s and drops the
+runtime's per-tile events, so the process that is profiled runs as it does
+unprofiled (PERF.md section 3 has the measurement).
 """
 
 from __future__ import annotations
@@ -22,41 +26,50 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 logger = logging.getLogger(__name__)
 
-__all__ = ["trace", "step_annotation", "StepProfiler", "Timer",
-           "LatencyHistogram"]
+__all__ = ["StepProfiler", "LatencyHistogram", "OnDemandProfiler",
+           "ProfilerBusy"]
+
+# TraceMe levels: 1 = annotations the program (and the runtime's coarse
+# phases) emit, 2 adds the runtime's per-tile / per-transfer events — on a
+# serving TPU host millions of them, which stretched the host phase
+# between two dispatches from 0.017 to 0.3 s (PERF.md, PR 25).
+HOST_TRACER_LEVEL = 1
+PYTHON_TRACER_LEVEL = 0
 
 
-@contextlib.contextmanager
-def trace(log_dir: str) -> Iterator[None]:
-    """Capture an XLA device+host trace into ``log_dir``.
-
-    View with ``tensorboard --logdir <log_dir>`` (Profile tab) or open the
-    generated ``.trace.json.gz`` in Perfetto.  Works on TPU and CPU backends.
-    """
+def _start_capture(log_dir: str) -> None:
+    """``jax.profiler.start_trace`` with the light options, then the
+    ``obs.clock`` annotation that ties the program's clocks to the
+    profiler's (obs/trace.py ``clock_annotation``)."""
     import jax
 
-    jax.profiler.start_trace(log_dir)
-    logger.info("Profiler trace started -> %s", log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-        logger.info("Profiler trace written to %s", log_dir)
+    from ..obs.trace import clock_annotation
+
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = HOST_TRACER_LEVEL
+    options.python_tracer_level = PYTHON_TRACER_LEVEL
+    jax.profiler.start_trace(log_dir, profiler_options=options)
+    with clock_annotation():
+        pass
 
 
-def step_annotation(name: str, step: int):
-    """Named host annotation that the trace viewer correlates with device ops
-    launched inside it (use around one training step)."""
+def _stop_capture() -> None:
     import jax
 
-    return jax.profiler.StepTraceAnnotation(name, step_num=step)
+    from ..obs.trace import clock_annotation
+
+    with clock_annotation():
+        pass
+    jax.profiler.stop_trace()
 
 
 class StepProfiler:
     """Trace a window of training steps [start, stop).
 
-    Drives ``trace`` + ``step_annotation`` from a plain per-step ``step()``
-    call so the train loop stays branch-free:
+    Driven from a plain per-step ``step()`` call so the train loop stays
+    branch-free; inside the window every step is bracketed by a
+    ``StepTraceAnnotation`` the trace viewer correlates with the device
+    operations launched inside it:
 
         prof = StepProfiler(log_dir, start=100, stop=105)
         for i in range(num_steps):
@@ -75,21 +88,21 @@ class StepProfiler:
 
     @contextlib.contextmanager
     def step(self, i: int) -> Iterator[None]:
-        import jax
-
         if not self.enabled:
             yield
             return
+        import jax
+
         # >= not ==: a resumed run whose restored step is already inside (or
         # past the start of) the window must still trace the remainder.
         if self.start <= i < self.stop and not self._active:
-            jax.profiler.start_trace(self.log_dir)
+            _start_capture(self.log_dir)
             self._active = True
             logger.info("Profiling steps [%d, %d) -> %s",
                         self.start, self.stop, self.log_dir)
         try:
             if self._active:
-                with step_annotation("train", i):
+                with jax.profiler.StepTraceAnnotation("train", step_num=i):
                     yield
             else:
                 yield
@@ -99,15 +112,12 @@ class StepProfiler:
             self.close()
             raise
         if self._active and i >= self.stop - 1:
-            jax.profiler.stop_trace()
-            self._active = False
+            self.close()
             logger.info("Profiler trace written to %s", self.log_dir)
 
     def close(self) -> None:
         if self._active:
-            import jax
-
-            jax.profiler.stop_trace()
+            _stop_capture()
             self._active = False
 
 
@@ -246,55 +256,6 @@ class LatencyHistogram:
             self._max = -math.inf
 
 
-class Timer:
-    """Wall-clock segment timer with named accumulators.
-
-        t = Timer()
-        with t("data"): batch = next(it)
-        with t("step"): state, m = train_step(state, batch)
-        t.summary()  # {'data': {'total': ..., 'mean': ..., 'count': N}, ...}
-
-    O(1) memory per segment name: each accumulator is (count, total, min,
-    max), never a list of observations — a Timer left running in a serving
-    or long-train process must not grow without bound.
-    """
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        # name -> [count, total, min, max]  # guarded_by: _lock
-        self._acc: Dict[str, List[float]] = {}
-
-    @contextlib.contextmanager
-    def __call__(self, name: str) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            with self._lock:
-                acc = self._acc.get(name)
-                if acc is None:
-                    self._acc[name] = [1, dt, dt, dt]
-                else:
-                    acc[0] += 1
-                    acc[1] += dt
-                    acc[2] = min(acc[2], dt)
-                    acc[3] = max(acc[3], dt)
-
-    def summary(self) -> Dict[str, Dict[str, float]]:
-        with self._lock:
-            snap = {k: list(v) for k, v in self._acc.items()}
-        return {
-            k: {"total": total, "mean": total / n, "count": n,
-                "min": lo, "max": hi}
-            for k, (n, total, lo, hi) in snap.items() if n
-        }
-
-    def reset(self) -> None:
-        with self._lock:
-            self._acc.clear()
-
-
 class ProfilerBusy(RuntimeError):
     """An on-demand capture was requested while one is already running."""
 
@@ -303,10 +264,10 @@ class OnDemandProfiler:
     """Bounded on-demand ``jax.profiler`` windows (``POST /debug/profile``).
 
     One capture at a time, started from any thread, stopped by a timer
-    thread after ``seconds`` — profiling is heavyweight (device trace +
-    host callstacks), so two overlapping windows would corrupt each other
-    and uncapped duration would let a debug endpoint degrade serving
-    indefinitely.
+    thread after ``seconds`` — two overlapping windows would corrupt each
+    other, and an uncapped one would grow a file without bound.  The
+    capture is the light one of ``_start_capture`` (no Python tracer, no
+    per-tile host events): the heavy trace has no documented user.
     """
 
     def __init__(self, log_dir: str = "profile",
@@ -327,8 +288,6 @@ class OnDemandProfiler:
         """Begin a capture of ``seconds``; raises ``ProfilerBusy`` when one
         is already running (the mutual exclusion the endpoint maps to HTTP
         409).  Returns ``{"log_dir", "seconds", "capture"}``."""
-        import jax
-
         seconds = float(seconds)
         if not 0 < seconds <= self.max_seconds:
             raise ValueError(
@@ -343,7 +302,7 @@ class OnDemandProfiler:
             self._captures += 1
             capture = self._captures
         try:
-            jax.profiler.start_trace(target)
+            _start_capture(target)
         except BaseException:
             with self._lock:
                 self._until = None
@@ -354,7 +313,7 @@ class OnDemandProfiler:
         def _stop():
             time.sleep(seconds)
             try:
-                jax.profiler.stop_trace()
+                _stop_capture()
                 logger.info("on-demand profile #%d written to %s",
                             capture, target)
             finally:

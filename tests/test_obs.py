@@ -1,7 +1,7 @@
 """Observability subsystem (raftstereo_tpu/obs, docs/observability.md).
 
 Unit coverage for the span tracer, the Prometheus format validator, the
-labeled metric families and the bounded Timer, plus the subsystem's
+labeled metric families and the locked Gauge, plus the subsystem's
 acceptance gate: an HTTP e2e that drives ``/predict`` and asserts the
 response carries an ``X-Request-Id`` whose queue-wait / dispatch /
 host-fetch spans appear in ``/debug/trace`` as valid Chrome trace-event
@@ -27,7 +27,6 @@ from raftstereo_tpu.obs import (TelemetryServer, Tracer, dump_threads,
 from raftstereo_tpu.serve import ServeClient, ServeError, ServeMetrics, \
     build_server
 from raftstereo_tpu.serve.metrics import MetricsRegistry
-from raftstereo_tpu.utils.profiling import Timer
 
 from test_bench import REPO
 
@@ -268,21 +267,9 @@ class TestParseText:
             parse_text("# TYPE x_total counter\nx_total oops\n")
 
 
-# --------------------------------------------------- bounded Timer + Gauge
+# --------------------------------------------------------- bounded Gauge
 
 class TestBoundedInstruments:
-    def test_timer_accumulators_are_o1(self):
-        t = Timer()
-        for _ in range(10000):
-            with t("seg"):
-                pass
-        s = t.summary()["seg"]
-        assert s["count"] == 10000
-        assert s["min"] <= s["mean"] <= s["max"]
-        assert s["total"] >= s["mean"]
-        # The accumulator is 4 scalars, not a 10000-observation list.
-        assert len(t._acc["seg"]) == 4
-
     def test_gauge_concurrent_add_loses_nothing(self):
         m = ServeMetrics()
 
@@ -567,3 +554,320 @@ class TestEndToEnd:
         spans = obs_server.tracer.spans(last=5)
         doc = to_chrome_trace(spans)
         assert len([e for e in doc["traceEvents"] if e["ph"] == "X"]) == 5
+
+
+# ------------------------------------------- phases, clocks, light captures
+
+def _profile_events(log_dir):
+    """{name: [(start_ns, duration_ns, stats dict)]} of a capture's host
+    plane, read with what JAX brings."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = glob.glob(f"{log_dir}/plugins/profile/*/*.xplane.pb")[0]
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                out.setdefault(e.name, []).append(
+                    (e.start_ns, e.duration_ns, dict(e.stats)))
+    return out
+
+
+class TestPhase:
+    def test_phase_is_one_ring_span_and_one_profiler_event(self, tmp_path):
+        """Inside a capture ``phase()`` leaves one span in the ring and one
+        host event of the same name in the profiler's own trace, and the
+        ``obs.clock`` pair maps the span's start onto the event's within
+        1 ms."""
+        from raftstereo_tpu.utils.profiling import (_start_capture,
+                                                    _stop_capture)
+
+        tracer = Tracer(capacity=8)
+        _start_capture(str(tmp_path))
+        try:
+            with tracer.phase("unit_phase", trace_id="batch:7", rows=3):
+                time.sleep(0.01)
+        finally:
+            _stop_capture()
+        (span,) = tracer.spans()
+        assert span.name == "unit_phase" and span.trace_id == "batch:7"
+        assert span.attrs == {"rows": 3}
+        events = _profile_events(str(tmp_path))
+        ((start_ns, dur_ns, stats),) = events["unit_phase"]
+        assert stats["rows"] == 3
+        assert dur_ns * 1e-9 == pytest.approx(span.duration_s, abs=1e-3)
+        clocks = sorted(events["obs.clock"])
+        assert len(clocks) == 2     # capture start and capture stop
+        for c_start, _, c in clocks:
+            on_trace = c_start + (span.t0 * 1e9 - c["perf_counter_ns"])
+            assert abs(on_trace - start_ns) < 1e6
+        # unix_ns is the same instant as the ring's Chrome export writes it
+        ts_us = to_chrome_trace([span])["traceEvents"][0]["ts"]
+        c_start, _, c = clocks[0]
+        assert abs(c_start + (ts_us * 1e3 - c["unix_ns"]) - start_ns) < 1e6
+
+    def test_phase_outside_a_capture_formats_nothing_and_is_cheap(self):
+        class Hostile:
+            def __repr__(self):
+                raise AssertionError("formatted with no capture running")
+            __str__ = __repr__
+
+        from raftstereo_tpu.obs.trace import timed_phase
+
+        tracer = Tracer(capacity=64)
+        h = Hostile()
+        with tracer.phase("p", trace_id="t", obj=h) as live:
+            live.attrs["late"] = 1
+        with timed_phase("q", obj=h) as ph:
+            pass
+        assert ph.t1 >= ph.t0 > 0 and ph.window == (ph.t0, ph.t1)
+        (span,) = tracer.spans()
+        assert span.attrs == {"obj": h, "late": 1}
+        n = 5000
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with tracer.phase("bench", trace_id="t", batch_size=8,
+                              bucket="576x960"):
+                pass
+        per_phase = (time.perf_counter() - t0) / n
+        assert per_phase < 200e-6
+        # A dispatch adds eleven phases (three live in the batcher, four
+        # recorded, four timed in the engine) and each of its requests
+        # two: under the contract's 2 % of even a 10 ms dispatch.
+        assert (11 + 2 * 8) * per_phase < 0.02 * 0.25
+
+    def test_unsampled_span_guard_still_holds_for_record(self):
+        tracer = Tracer(capacity=4)
+        assert tracer.record("x", 0.0, 1.0, None) == ""
+        assert not hasattr(Tracer, "_new_span_id")   # the alias is gone
+        assert len(Tracer.new_span_id()) == 16
+
+
+class _StageEngine:
+    """The engine's side of the batcher contract, phases and all, with no
+    model behind it."""
+
+    def __init__(self):
+        self.last_segments = None
+
+    def bucket_of(self, shape):
+        return (64, 96)
+
+    def infer_batch(self, pairs, iters, mode=None):
+        from raftstereo_tpu.obs.trace import timed_phase
+
+        with timed_phase("pad_bucket") as pad:
+            time.sleep(0.002)
+        with timed_phase("launch") as launch:
+            time.sleep(0.001)
+        with timed_phase("device_wait") as wait:
+            time.sleep(0.005)
+        with timed_phase("host_fetch") as fetch:
+            time.sleep(0.001)
+        self.last_segments = {
+            "pad": pad.window, "launch": launch.window,
+            "device_wait": wait.window,
+            "dispatch": (launch.t0, wait.t1),
+            "host_fetch": (wait.t1, fetch.t1), "compile": False}
+        return [np.zeros((4, 4), np.float32) for _ in pairs]
+
+
+class TestWorkerPhases:
+    def _serve(self, n_requests, max_wait_ms):
+        from raftstereo_tpu.serve.batcher import DynamicBatcher
+
+        tracer = Tracer(capacity=256)
+        cfg = ServeConfig(max_batch_size=2, max_wait_ms=max_wait_ms,
+                          queue_limit=8)
+        img = np.zeros((60, 90, 3), np.float32)
+        with DynamicBatcher(_StageEngine(), cfg, tracer=tracer) as b:
+            futs = [b.submit(img, img, trace_id=f"req{i}")
+                    for i in range(n_requests)]
+            for f in futs:
+                f.result(10)
+        return tracer.spans()
+
+    def test_phases_tile_the_cycle_once_per_dispatch(self):
+        spans = self._serve(2, max_wait_ms=2000.0)
+        batch = [s for s in spans if s.trace_id.startswith("batch:")
+                 and s.name != "queue_empty"]
+        btid = {s.trace_id for s in batch if s.name == "launch"}
+        assert len(btid) == 1                   # one dispatch of two rows
+        mine = sorted((s for s in batch if s.trace_id in btid),
+                      key=lambda s: s.t0)
+        assert [s.name for s in mine] == [
+            "batch_form", "pad_bucket", "launch", "device_wait",
+            "host_fetch", "reply_handoff"]      # each ONCE, in order
+        holes = sum(max(b.t0 - a.t1, 0.0) for a, b in zip(mine, mine[1:]))
+        assert holes < 1e-3, holes
+        assert all(b.t0 >= a.t0 for a, b in zip(mine, mine[1:]))
+        by = {s.name: s for s in mine}
+        assert by["batch_form"].attrs["closed_by"] == "full"
+        assert by["batch_form"].attrs["batch_size"] == 2
+        assert by["launch"].attrs["request_ids"] == ["req0", "req1"]
+        assert by["launch"].attrs["bucket"] == "64x96"
+        # the per-request copies stay as they were: one set a request,
+        # and launch + device_wait are what device_compute spans
+        for rid in ("req0", "req1"):
+            names = sorted(s.name for s in spans if s.trace_id == rid)
+            assert names == ["device_compute", "dispatch", "host_fetch",
+                             "pad_bucket", "queue_wait"]
+        dc = next(s for s in spans if s.name == "device_compute")
+        assert dc.t0 == by["launch"].t0 and dc.t1 == by["device_wait"].t1
+
+    def test_deadline_closes_a_short_batch_and_the_idle_wait_is_named(self):
+        spans = self._serve(1, max_wait_ms=20.0)
+        form = next(s for s in spans if s.name == "batch_form")
+        assert form.attrs["closed_by"] == "deadline"
+        assert form.attrs["batch_size"] == 1
+        assert form.duration_s >= 0.015
+        # before the first request the worker waited with nothing queued
+        empty = [s for s in spans if s.name == "queue_empty"]
+        assert empty and empty[0].trace_id == form.trace_id
+        assert empty[0].t1 <= form.t0 + 1e-4
+
+
+class TestRequestPhases:
+    def test_request_contains_admission_contains_wire_decode(self,
+                                                             obs_server):
+        client = ServeClient("127.0.0.1", obs_server.port, timeout=120)
+        _, meta = client.predict(_img(), _img(seed=1))
+        client.close()
+        rid = meta["request_id"]
+        deadline = time.time() + 5      # `reply` closes after the write
+        while time.time() < deadline:
+            by = {s.name: s for s in obs_server.tracer.spans(trace_id=rid)}
+            if "reply" in by:
+                break
+            time.sleep(0.01)
+        req, adm, dec, reply = (by[n] for n in (
+            "request", "admission", "wire_decode", "reply"))
+        assert req.t0 <= adm.t0 <= dec.t0 and dec.t1 <= adm.t1 <= req.t1
+        assert dec.parent_id == adm.span_id
+        # `reply` starts inside the request (encode) and outlasts it (write)
+        assert req.t0 < reply.t0 <= req.t1 <= reply.t1
+        # the batch's own trace names this request
+        launches = [s for s in obs_server.tracer.spans()
+                    if s.name == "launch"
+                    and rid in s.attrs.get("request_ids", ())]
+        assert len(launches) == 1
+        assert launches[0].trace_id.startswith("batch:")
+
+    def test_reply_closes_when_the_client_hangs_up(self, obs_server):
+        from raftstereo_tpu.serve.server import _Handler
+
+        handler = _Handler.__new__(_Handler)    # no socket: _send is ours
+        handler.server = obs_server
+        handler._wire_ctx = None
+        handler._trace = ("hangup-rid", None)
+
+        def hang_up(*a, **k):
+            raise BrokenPipeError("client went away")
+
+        handler._send = hang_up
+        with pytest.raises(BrokenPipeError):
+            handler._finish_ok(obs_server, np.zeros((4, 4), np.float32),
+                               {"iters": 3}, "predict", "hangup-rid",
+                               time.perf_counter())
+        names = [s.name for s in
+                 obs_server.tracer.spans(trace_id="hangup-rid")]
+        assert sorted(names) == ["reply", "request"]
+
+
+class TestLightCapture:
+    @pytest.mark.parametrize("which", ["on_demand", "step"])
+    def test_captures_pass_the_light_options(self, which, tmp_path,
+                                             monkeypatch):
+        from raftstereo_tpu.utils import profiling
+
+        seen = []
+        monkeypatch.setattr(
+            jax.profiler, "start_trace",
+            lambda log_dir, **kw: seen.append((log_dir, kw)))
+        monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+        if which == "on_demand":
+            prof = profiling.OnDemandProfiler(log_dir=str(tmp_path))
+            prof.start(0.05)
+            with pytest.raises(profiling.ProfilerBusy):   # the 409
+                prof.start(0.05)
+            deadline = time.time() + 5
+            while prof.running and time.time() < deadline:
+                time.sleep(0.01)
+            assert not prof.running
+        else:
+            prof = profiling.StepProfiler(str(tmp_path), start=1, stop=2)
+            for i in range(3):
+                with prof.step(i):
+                    pass
+        ((log_dir, kw),) = seen
+        options = kw["profiler_options"]
+        assert options.host_tracer_level == 1
+        assert options.python_tracer_level == 0
+
+    def test_every_start_trace_in_the_package_is_the_light_one(self):
+        import subprocess
+
+        out = subprocess.run(
+            ["grep", "-rn", "jax.profiler.start_trace(", "raftstereo_tpu"],
+            capture_output=True, text=True, cwd=REPO).stdout
+        calls = [ln for ln in out.splitlines() if '"""' not in ln
+                 and "``" not in ln]
+        assert len(calls) == 1 and "profiler_options=options" in calls[0]
+
+
+class TestCompileAndMemoryInstruments:
+    def test_compile_counter_sees_the_staging_programs(self):
+        """The engine stages a batch with eager ops whose shapes depend on
+        the number of real rows: a new occupancy is a new program, which
+        ``serve_xla_compiles_total`` counts (once) and the engine's own
+        hit/miss counters never saw."""
+        from raftstereo_tpu.serve.engine import BatchEngine
+        from raftstereo_tpu.serve.server import watch_xla_compiles
+
+        metrics, tracer = ServeMetrics(), Tracer(capacity=64)
+        # a bucket no other test of this process stages at
+        cfg = ServeConfig(max_batch_size=2, bucket_multiple=32,
+                          buckets=((40, 72),))
+        engine = BatchEngine(None, {}, cfg, metrics)
+        pair = (np.ones((40, 72, 3), np.float32),) * 2
+        unwatch = watch_xla_compiles(metrics, tracer)
+        try:
+            engine._pad_pairs([pair])           # occupancy 1: several
+            before = metrics.xla_compiles.value
+            assert before >= 1
+            engine._pad_pairs([pair, pair])     # occupancy 2: concatenate
+            assert metrics.xla_compiles.value == before + 1
+            engine._pad_pairs([pair, pair])     # staged before: none
+            assert metrics.xla_compiles.value == before + 1
+        finally:
+            unwatch()
+        engine._pad_pairs([(np.ones((33, 70, 3), np.float32),) * 2])
+        assert metrics.xla_compiles.value == before + 1     # unsubscribed
+        compiles = [s for s in tracer.spans() if s.name == "compile"]
+        assert len(compiles) == before + 1
+        assert {s.attrs["kind"] for s in compiles} == {"compile"}
+        assert 'serve_xla_compiles_total{kind="compile"}' in metrics.render()
+
+    def test_memory_gauges_render_lint_and_show_in_debug_vars(self,
+                                                              obs_server):
+        from raftstereo_tpu.serve.metrics import (DEVICE_MEMORY_KEYS,
+                                                  device_memory)
+        from raftstereo_tpu.train.telemetry import TrainMetrics
+
+        registry = MetricsRegistry()
+        ServeMetrics(registry)
+        TrainMetrics(registry)
+        assert lint_registry(registry.entries()) == []
+        scrape = parse_text(registry.render())      # validates as well
+        for prefix in ("serve", "train"):
+            for key in DEVICE_MEMORY_KEYS:
+                assert scrape.value(f"{prefix}_device_{key}") >= 0
+        assert set(device_memory()) == set(DEVICE_MEMORY_KEYS)
+        client = ServeClient("127.0.0.1", obs_server.port, timeout=120)
+        dvars = client.debug_vars()
+        client.close()
+        assert set(dvars["memory"]) == set(DEVICE_MEMORY_KEYS)

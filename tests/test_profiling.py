@@ -8,22 +8,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from raftstereo_tpu.utils.profiling import (LatencyHistogram, StepProfiler,
-                                            Timer, trace)
+from raftstereo_tpu.utils.profiling import LatencyHistogram, StepProfiler
 
 
 def _work():
     x = jnp.ones((64, 64))
     return float(jax.jit(lambda a: (a @ a).sum())(x))
-
-
-class TestTrace:
-    def test_trace_writes_artifacts(self, tmp_path):
-        d = str(tmp_path / "tr")
-        with trace(d):
-            _work()
-        files = glob.glob(os.path.join(d, "**", "*"), recursive=True)
-        assert any(os.path.isfile(f) for f in files)
 
 
 class TestStepProfiler:
@@ -139,18 +129,3 @@ class TestLatencyHistogram:
             t.join()
         assert h.count == 4000
         assert dict(h.cumulative())[0.5] == 4000
-
-
-class TestTimer:
-    def test_accumulates_named_segments(self):
-        t = Timer()
-        for _ in range(3):
-            with t("a"):
-                np.ones(10).sum()
-        with t("b"):
-            pass
-        s = t.summary()
-        assert s["a"]["count"] == 3 and s["b"]["count"] == 1
-        assert s["a"]["total"] >= s["a"]["mean"] > 0
-        t.reset()
-        assert t.summary() == {}
