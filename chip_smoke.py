@@ -97,6 +97,7 @@ def check_runtime(phase: str, rt: dict, count: int) -> None:
     say(f"[{phase}] device: platform={rt['platform']} "
         f"kind={rt['device_kind']} count={rt['device_count']}")
     say(f"[{phase}] gates: corr={rt['corr']} auto->{rt['corr_auto']} "
+        f"corr_matmul={rt['corr_matmul']} "
         f"gru_backend={rt['gru_backend']} "
         f"fused_stem(cnet)={rt['fused_stem_cnet']} "
         f"fused_stem(fnet)={rt['fused_stem_fnet']} "
@@ -112,6 +113,10 @@ def check_runtime(phase: str, rt: dict, count: int) -> None:
     if rt["corr_auto"] != "pallas_alt" or rt["corr"] != "pallas_alt":
         device_check_failed(f"{phase}: corr 'auto' resolved to a CPU branch "
                             f"({rt['corr']!r})")
+    if rt["corr_matmul"] != "bf16_exact_1+3":
+        # both phases run --mixed_precision with float32 corr operands
+        device_check_failed(f"{phase}: the lookup's matmul resolved to "
+                            f"{rt['corr_matmul']!r}, not the exact bf16 form")
     if rt["device_count"] != count:
         device_check_failed(f"{phase}: {rt['device_count']} devices, "
                             f"expected {count}")

@@ -516,7 +516,8 @@ class RAFTStereo:
                                          precision=cfg.corr_precision,
                                          out_dtype=self.dtype,
                                          out_channels=out_channels,
-                                         epilogue=epi, quant=quant)
+                                         epilogue=epi, quant=quant,
+                                         feature_dtype=self.dtype)
         disp = state["disp"]
         b, h0, w0 = disp.shape[:3]
         grid = coords_grid_x(b, h0, w0)

@@ -331,6 +331,20 @@ def make_pallas_alt_corr_fn(fmap1: jax.Array, fmap2: jax.Array,
     # DMA and takes the MXU's native bf16 path (fp32 accumulation). The
     # pyramid is always POOLED in fp32 first; only the kernel inputs are
     # rounded.
+    #
+    # With float32 operands the widening below loses what the kernel can
+    # use: a bf16 model hands over bf16 features, so fmap1 and level 0 of
+    # the pyramid are float32 values with an all-zero low half, and five
+    # of the six bf16 passes that emulate their ``highest`` product
+    # multiply zeros (three of six for the pooled levels, whose left
+    # operand is still exact).  ``feature_dtype`` carries the dtype the
+    # features were born in to the kernel wrapper, which then runs only
+    # the passes that can be non-zero (pallas_alt.resolve_corr_matmul):
+    # the same float32-accumulated result up to the order of float32
+    # sums, no operand rounded that is not already exact.  Float32-born
+    # features keep ``precision`` as it is.
+    feature_dtype = jnp.result_type(fmap1.dtype, fmap2.dtype)
+
     def construct(f1, f2):
         f1flat = preflatten_fmap1(f1.astype(jnp.float32)).astype(dtype)
         f2p = [pad_w2_lane(preflatten_fmap2(x)).astype(dtype) for x in
@@ -355,12 +369,13 @@ def make_pallas_alt_corr_fn(fmap1: jax.Array, fmap2: jax.Array,
                 return pallas_alt_pyramid_radial_epi_flat(
                     f1, f2, xl, w2s, radius, epi[0], epi[1],
                     precision=precision, out_dtype=out_dtype,
-                    level_scales=scales)
+                    level_scales=scales, feature_dtype=feature_dtype)
             return pallas_alt_pyramid_radial_flat(f1, f2, xl, w2s, radius,
                                                   precision=precision,
                                                   out_dtype=out_dtype,
                                                   out_channels=out_channels,
-                                                  level_scales=scales)
+                                                  level_scales=scales,
+                                                  feature_dtype=feature_dtype)
     else:
         # Partition over the mesh (see _corr_shard_mesh): construction and
         # every lookup run per-shard inside shard_map; no collectives.
@@ -377,7 +392,8 @@ def make_pallas_alt_corr_fn(fmap1: jax.Array, fmap2: jax.Array,
                 return jax.shard_map(
                     lambda a, b, t, w, bi: pallas_alt_pyramid_radial_epi_flat(
                         a, b, t, w2s, radius, w, bi, precision=precision,
-                        out_dtype=out_dtype, level_scales=scales),
+                        out_dtype=out_dtype, level_scales=scales,
+                        feature_dtype=feature_dtype),
                     mesh=mesh,
                     in_specs=(flat_spec, flat_spec, row_spec, P(), P()),
                     out_specs=row_spec, check_vma=False)(f1, f2, xl, *epi)
@@ -385,7 +401,7 @@ def make_pallas_alt_corr_fn(fmap1: jax.Array, fmap2: jax.Array,
                 lambda a, b, t: pallas_alt_pyramid_radial_flat(
                     a, b, t, w2s, radius, precision=precision,
                     out_dtype=out_dtype, out_channels=out_channels,
-                    level_scales=scales),
+                    level_scales=scales, feature_dtype=feature_dtype),
                 mesh=mesh, in_specs=(flat_spec, flat_spec, row_spec),
                 out_specs=row_spec, check_vma=False)(f1, f2, xl)
 
@@ -536,7 +552,7 @@ def corr_fn_from_state(implementation: str, state: Sequence[jax.Array],
                        num_levels: int, radius: int,
                        precision: str = "highest", out_dtype=jnp.float32,
                        out_channels: int = 0, epilogue=None,
-                       quant: bool = False) -> CorrFn:
+                       quant: bool = False, feature_dtype=None) -> CorrFn:
     """Rebuild a lookup function over ``build_corr_state`` output.
 
     Static parameters (radius/precision/out_*/epilogue/quant) are passed
@@ -546,6 +562,10 @@ def corr_fn_from_state(implementation: str, state: Sequence[jax.Array],
     exactly where that function honors them: pallas_alt only).  ``quant``
     only steers implementation resolution — the state arrays are already
     the DEQUANTIZED volume pyramid, so the lookups are the stock ones.
+    ``feature_dtype`` is the dtype the encoder handed the features over in
+    (the state arrays are already float32, so the caller has to say): it
+    selects the pallas_alt kernel's matmul form exactly as
+    ``make_pallas_alt_corr_fn`` does from ``fmap1.dtype``.
     """
     implementation = resolve_implementation(implementation, quant)
     if implementation == "reg":
@@ -601,12 +621,12 @@ def corr_fn_from_state(implementation: str, state: Sequence[jax.Array],
                 out = pallas_alt_pyramid_radial_epi_flat(
                     f1flat, f2cat, xl, w2s, radius, epi[0], epi[1],
                     precision=precision, out_dtype=out_dtype,
-                    level_scales=scales)
+                    level_scales=scales, feature_dtype=feature_dtype)
             else:
                 out = pallas_alt_pyramid_radial_flat(
                     f1flat, f2cat, xl, w2s, radius, precision=precision,
                     out_dtype=out_dtype, out_channels=out_channels,
-                    level_scales=scales)
+                    level_scales=scales, feature_dtype=feature_dtype)
             return out[:, :h] if hp != h else out
         return fn
     else:

@@ -17,6 +17,27 @@ and throws the rows away: HBM never holds more than the O(H*W) feature
 pyramids, yet the inner loop is MXU matmul + VPU reduction instead of the
 XLA gather chain the ``alt`` backend lowers to.
 
+The served form (the radial kernels, ``alt_lookup_fwd``; _radial_cols) does
+only the arithmetic that can change its answer (PR 27, measured on the v5e
+at the served shape, PERF.md §5):
+
+- *The windows are read, not summed.*  A pixel's 2r+2 integer windows are
+  adjacent columns of its own row of M, so one per-row lane gather of each
+  128-column chunk lands them on adjacent lanes.  The masked window sums
+  this replaced (ten compare-select-reduce sweeps a level) were the whole
+  kernel: 6.9 ms a call with or without any matmul behind them.  The
+  centres come in pixels-on-lanes and are broadcast once, which leaves
+  the sweep (1.6 ms alone) hidden behind the DMA of the two float32
+  feature operands.
+- *The matmul runs the passes that can be non-zero.*  A bf16 model hands
+  over bf16 features which ops/corr.py widens to float32; a ``highest``
+  float32 product is six bf16 passes, and five of them (level 0) or three
+  (the pooled levels, float32 on the right only) multiply an all-zero low
+  half.  For bf16-born features one native pass and three exact ones give
+  the same float32-accumulated result (resolve_corr_matmul, _dot): 1.8 ms
+  a call against 3.2 with all six.  Float32-born features keep the
+  configured policy; nothing is rounded that is not already exact.
+
 Backward (for completeness/training) fuses the volume-gradient expansion with
 the feature-gradient matmuls per block:
 
@@ -40,9 +61,54 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_corr import (_BLOCK_ROWS, _COMPILER_PARAMS, _block_w1,
+from .pallas_corr import (_BLOCK_ROWS, _COMPILER_PARAMS, LANE, _block_w1,
                           _interpret, _pad_rows, _pad_taps, _pad_w1,
                           bounds_from_widths, pad_lane)
+
+
+EXACT_BF16 = "bf16_exact_1+3"
+_LANE_BITS = LANE.bit_length() - 1
+
+
+def resolve_corr_matmul(feature_dtype, operand_dtype, precision: str) -> str:
+    """The form of the radial lookup's on-demand matmul — the ONE resolver:
+    the kernel wrapper (_alt_pyr_radial_fwd_impl) asks it which passes to
+    run and ``utils/platform.describe_runtime`` prints its answer as the
+    ``runtime:`` line's ``corr_matmul``.
+
+    ``feature_dtype`` is the dtype the features were BORN in (what the
+    encoder handed over, before ops/corr.py widened them; None = the
+    operands' own), ``operand_dtype`` the dtype the kernel's operands are
+    stored in, ``precision`` the configured float32 policy.
+
+    - bf16 operands: one native pass (``bf16_native``).
+    - float32 operands that are bf16-born, policy ``highest``: fmap1 and
+      level 0 of the fmap2 pyramid hold bf16 values exactly, so of the six
+      bf16 passes that emulate a float32 product only one (level 0) or
+      three (the pooled levels, whose RIGHT operand is a true float32) can
+      be non-zero.  Only those run (``bf16_exact_1+3``, see _dot); the
+      result is the ``highest`` one up to the order of float32 sums.
+    - anything else: the configured policy on float32 (``f32_<policy>``).
+    """
+    if jnp.dtype(operand_dtype) == jnp.bfloat16:
+        return "bf16_native"
+    if (precision == "highest" and feature_dtype is not None
+            and jnp.dtype(feature_dtype) == jnp.bfloat16):
+        return EXACT_BF16
+    return f"f32_{precision}"
+
+
+def _split3(x):
+    """float32 -> three bf16 pieces with hi + mid + lo == x exactly (8+8+8
+    significand bits; each remainder is representable, so the subtractions
+    do not round).  Holds for every float32 whose head does not round up
+    to infinity and whose last bit is a normal bf16, 2**-102 <= |x| <
+    3.39e38 or x == 0: correlation features are O(1)."""
+    hi = x.astype(jnp.bfloat16)
+    r = x - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    lo = (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
 
 
 def _dot(a, b, dims, prec: str):
@@ -52,11 +118,26 @@ def _dot(a, b, dims, prec: str):
     fp32 operand into a bf16 head + bf16 residual and sum the three
     significant cross products (hi*hi + hi*lo + lo*hi), which is exactly
     XLA's bf16x3 emulation.  bf16 operands always take the native single
-    pass regardless of the policy."""
-    if a.dtype != jnp.float32 or prec == "default":
-        return jax.lax.dot_general(a, b, dims,
+    pass regardless of the policy.
+
+    MIXED operands (bf16 against float32) are the EXACT form, whatever the
+    policy: the float32 side is split three ways (_split3) and each piece
+    takes one native pass against the bf16 side.  bf16 x bf16 products are
+    exact in float32 and the MXU accumulates in float32, so this is the
+    six-pass ``highest`` product of the same values with the three passes
+    that would multiply the bf16 side's all-zero low half left out — equal
+    to it up to the order of float32 sums."""
+    def d(x, y):
+        return jax.lax.dot_general(x, y, dims,
                                    preferred_element_type=jnp.float32,
                                    precision=jax.lax.Precision.DEFAULT)
+
+    if a.dtype != b.dtype:
+        assert (a.dtype, b.dtype) == (jnp.bfloat16, jnp.float32), (a, b)
+        hi, mid, lo = (d(a, p) for p in _split3(b))
+        return (hi + mid) + lo
+    if a.dtype != jnp.float32 or prec == "default":
+        return d(a, b)
     if prec == "highest":
         return jax.lax.dot_general(a, b, dims,
                                    preferred_element_type=jnp.float32,
@@ -65,12 +146,6 @@ def _dot(a, b, dims, prec: str):
     a_lo = (a - a_hi.astype(jnp.float32)).astype(jnp.bfloat16)
     b_hi = b.astype(jnp.bfloat16)
     b_lo = (b - b_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-
-    def d(x, y):
-        return jax.lax.dot_general(x, y, dims,
-                                   preferred_element_type=jnp.float32,
-                                   precision=jax.lax.Precision.DEFAULT)
-
     return d(a_hi, b_hi) + d(a_hi, b_lo) + d(a_lo, b_hi)
 
 
@@ -116,62 +191,116 @@ def _alt_pyr_fwd_kernel(f1_ref, f2_ref, taps_ref, out_ref, *, scale, bounds,
 
 
 def _radial_cols(f1_ref, f2_ref, x_ref, *, scale, bounds, radius, prec,
-                 level_scales):
-    """Shared core of the radial kernels: the per-tap column list.
+                 level_scales, exact_bf16=False):
+    """Shared core of the radial kernels: the (R*blk, LANE) slab whose lane
+    l*K + k holds level l's tap k (K = 2*radius + 1), every other lane 0.
 
     Taps are x + k for k in [-radius, radius], so every tap of a level
-    shares floor(x)/frac(x).  Instead of K dense hat sweeps (~6 VPU ops
-    per column-visit), sweep K+1 integer WINDOWS
-    win[d] = M[x1, floor(x)+d-radius] (~3 ops per visit: one shared integer
-    offset, then compare + masked-accumulate per window) and lerp
-    per-pixel:  out_k = (1-f)*win[k] + f*win[k+1].  Algebraically identical
-    to the hat form — hat(j - (b0+f+k-r)) is nonzero exactly at
+    shares floor(x)/frac(x): out_k = (1-f)*win[k] + f*win[k+1] with the K+1
+    integer WINDOWS win[d] = M[x1, floor(x)+d-radius].  Algebraically
+    identical to the hat form — hat(j - (b0+f+k-r)) is nonzero exactly at
     j = b0+k-r (weight 1-f) and j+1 (weight f) — including zero-outside
-    edges (out-of-range windows sum nothing) and NaN coords (f = NaN
-    poisons the lerp).  ~1.7x fewer VPU ops on the kernel's dominant
-    cost."""
+    edges (an out-of-range window is 0) and NaN coords (f = NaN poisons
+    the lerp).
+
+    The windows are READ, not summed: a pixel's K+1 windows are adjacent
+    columns of its own row of M, so one per-row lane gather of each
+    128-column chunk (take_along_axis -> Mosaic's dynamic_gather) lands
+    them on K+1 adjacent lanes, already at the level's place in the output
+    (lane l*K + d reads column b0 - r + d).  That is ~10 VPU operations and
+    one gather per (8 pixels x 128 columns) of M where the masked window
+    sums this replaces spent ~31 and forty cross-lane reductions per
+    8 pixels — all of the kernel's time (PERF.md §5, PR 27).  A window
+    sum has one non-zero term, so reading it is the same float32 value.
+
+    The matmul (``exact_bf16``; resolve_corr_matmul): when the features
+    are bf16-born, fmap1 and the level-0 columns of fmap2 are cast back to
+    bf16 (exact) and take ONE native pass; the pooled levels, true
+    float32, take the exact three (_dot's mixed form)."""
     f1 = f1_ref[...]                              # (R, blk, C)
     f2 = f2_ref[...]                              # (R, W2cat, C)
-    x = x_ref[...].astype(jnp.float32)            # (R, blk, L)
-    m = _dot(f1, f2, (((2,), (2,)), ((0,), (0,))),
-             prec) * scale                        # (R, blk, W2cat)
+    r, blk = f1.shape[:2]
+    p = r * blk
+
+    def centre(li):
+        # The centres arrive pixels-on-lanes, (R, blk) a level: a block
+        # with the pixels on sublanes and ONE lane is 1,920 four-byte DMA
+        # rows a grid step, 0.63 ms of a 2.04-ms call (PERF.md §5).  One
+        # small transpose puts them where the lane gather wants them, and
+        # ONE lane broadcast serves everything derived from them below
+        # (floor, fraction, columns, lerp weights: all full-width
+        # elementwise; a broadcast per use kept the cross-lane unit busier
+        # than the gathers did).
+        xt = x_ref[li].astype(jnp.float32).T      # (blk, R)
+        col = jnp.concatenate([xt[:, i:i + 1] for i in range(r)], axis=0)
+        return jnp.broadcast_to(col, (p, LANE))
+
+    dims = (((2,), (2,)), ((0,), (0,)))
+    if exact_bf16:
+        w0 = bounds[0][1]
+        f1 = f1.astype(jnp.bfloat16)
+        parts = [_dot(f1, f2[:, :w0].astype(jnp.bfloat16), dims, prec)]
+        if len(bounds) > 1:
+            parts.append(_dot(f1, f2[:, w0:], dims, prec))
+    else:
+        parts = [_dot(f1, f2, dims, prec)]        # (R, blk, W2cat)
+    # M as LANE-column chunks, pixels flattened onto sublanes (blk is a
+    # multiple of 8, so merging the leading axes moves nothing).
+    chunks = [part[:, :, i:i + LANE].reshape(p, LANE)
+              for part in parts for i in range(0, part.shape[-1], LANE)]
     kk = 2 * radius + 1
-    cols = []
+    assert len(bounds) * kk < LANE, (len(bounds), radius)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANE), 1)
+    out = jnp.zeros((p, LANE), jnp.float32)
     for li, (off, w2p) in enumerate(bounds):
-        ml = m[:, :, off:off + w2p]
+        assert off % LANE == 0 and w2p % LANE == 0, bounds
         # level_scales (static): x carries only the LEVEL-0 center and the
         # per-level locals are derived in-register — the (B, H, W1, L)
         # center tensor cost 28 us/iter of 24 GB/s loop fusion outside.
-        xl = (x[:, :, li] if level_scales is None
-              else x[:, :, 0] * level_scales[li])
+        if level_scales is None:
+            xl = centre(li)                       # (P, LANE), lanes equal
+        else:
+            if li == 0:
+                x0 = centre(0)
+            xl = x0 * level_scales[li]
         b0 = jnp.floor(xl)
-        f = xl - b0                               # (R, blk)
-        j = jax.lax.broadcasted_iota(jnp.int32, (1, 1, w2p), 2)
-        z = j - b0.astype(jnp.int32)[..., None] + radius   # (R, blk, w2p)
-        wins = [jnp.sum(jnp.where(z == d, ml, 0.0), axis=-1)
-                for d in range(kk + 1)]           # each (R, blk)
-        for ki in range(kk):
-            cols.append(wins[ki] * (1.0 - f) + wins[ki + 1] * f)
-    return cols
+        f = xl - b0
+        # Column each lane reads.  A column outside [0, w2p) matches no
+        # chunk below and stays 0; padded columns inside it hold m == 0.
+        col = lane + (b0.astype(jnp.int32) - (radius + li * kk))
+        src = col & (LANE - 1)
+        win = jnp.zeros((p, LANE), jnp.float32)
+        for c in range(w2p // LANE):
+            g = jnp.take_along_axis(chunks[off // LANE + c], src, axis=1,
+                                    mode="promise_in_bounds")
+            win = jnp.where((col >> _LANE_BITS) == c, g, win)
+        win = win * scale
+        nxt = pltpu.roll(win, LANE - 1, 1)        # lane j <- win[j + 1]
+        val = win * (1.0 - f) + nxt * f
+        out = jnp.where((lane >= li * kk) & (lane < (li + 1) * kk), val, out)
+    return out
 
 
 def _alt_pyr_radial_kernel(f1_ref, f2_ref, x_ref, out_ref, *, scale, bounds,
-                           radius, prec="highest", level_scales=None):
+                           radius, prec="highest", level_scales=None,
+                           exact_bf16=False):
     """Radial lookup emitting the raw correlation features."""
     cols = _radial_cols(f1_ref, f2_ref, x_ref, scale=scale, bounds=bounds,
-                        radius=radius, prec=prec, level_scales=level_scales)
+                        radius=radius, prec=prec, level_scales=level_scales,
+                        exact_bf16=exact_bf16)
     # Zero channel padding up to the declared output width: a 36-lane
     # tensor makes the consuming 1x1 conv's fusion read at ~39 GB/s
     # (measured 60 us/iter); emitting a lane-friendly channel count is
-    # free here and the consumer zero-pads its weights to match.
-    while len(cols) < out_ref.shape[-1]:
-        cols.append(jnp.zeros_like(cols[0]))
-    out_ref[...] = jnp.stack(cols, axis=-1).astype(out_ref.dtype)
+    # free here (the slab's spare lanes are zeros) and the consumer
+    # zero-pads its weights to match.
+    out_ref[...] = cols[:, :out_ref.shape[-1]].reshape(
+        out_ref.shape).astype(out_ref.dtype)
 
 
 def _alt_pyr_radial_epi_kernel(f1_ref, f2_ref, x_ref, ew_ref, eb_ref,
                                out_ref, *, scale, bounds, radius,
-                               prec="highest", level_scales=None):
+                               prec="highest", level_scales=None,
+                               exact_bf16=False):
     """Radial lookup with the motion encoder's convc1 fused as an
     epilogue: out = relu(cols @ W + b), the 1x1 (L*K -> 64) conv that
     otherwise re-reads the correlation features from HBM at 75 GB/s
@@ -181,9 +310,11 @@ def _alt_pyr_radial_epi_kernel(f1_ref, f2_ref, x_ref, ew_ref, eb_ref,
     the fused numerics mirror the unfused ones; inference-only (the
     backward keeps the module conv — see make_pallas_alt_corr_fn)."""
     cols = _radial_cols(f1_ref, f2_ref, x_ref, scale=scale, bounds=bounds,
-                        radius=radius, prec=prec, level_scales=level_scales)
+                        radius=radius, prec=prec, level_scales=level_scales,
+                        exact_bf16=exact_bf16)
     ew = ew_ref[...]                               # (L*K, Co) compute dtype
-    z = jnp.stack(cols, axis=-1).astype(ew.dtype)  # (R, blk, L*K)
+    r, blk = out_ref.shape[:2]
+    z = cols[:, :ew.shape[0]].astype(ew.dtype).reshape(r, blk, ew.shape[0])
     pp = (jax.lax.Precision.HIGHEST if ew.dtype == jnp.float32 else None)
     y = jax.lax.dot_general(z, ew, (((2,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32,
@@ -300,7 +431,8 @@ def pallas_alt_pyramid_radial_flat(f1flat: jax.Array, f2cat: jax.Array,
                                    precision: str = "highest",
                                    out_dtype=jnp.float32,
                                    out_channels: int = 0,
-                                   level_scales: tuple = None) -> jax.Array:
+                                   level_scales: tuple = None,
+                                   feature_dtype=None) -> jax.Array:
     """Model-pattern variant of :func:`pallas_alt_pyramid_flat`: instead of
     explicit per-tap coordinates it takes the per-level LOCAL center
     ``x_levels`` (B, H, W1, L) and the static ``radius``, and resolves the
@@ -316,21 +448,32 @@ def pallas_alt_pyramid_radial_flat(f1flat: jax.Array, f2cat: jax.Array,
     carries a SINGLE channel — the level-0 center — and each level's
     local center is derived in-kernel as x * level_scales[l], removing
     the per-level center tensor from HBM entirely (the model's pattern:
-    scales 2**-l)."""
+    scales 2**-l).
+
+    ``feature_dtype``: the dtype the features were born in, when the
+    caller widened them to the operands' float32 (ops/corr.py).  bfloat16
+    promises that ``f1flat`` and the level-0 columns of ``f2cat`` hold
+    bf16 values exactly, and the forward matmul then runs only the passes
+    that can be non-zero (resolve_corr_matmul); the backward is the
+    configured ``precision`` either way."""
     return _make_alt_pyr_radial(f1flat.shape, f2cat.shape, tuple(w2s),
                                 radius, f1flat.dtype.name, f2cat.dtype.name,
                                 precision, jnp.dtype(out_dtype).name,
                                 out_channels,
                                 tuple(level_scales)
                                 if level_scales is not None
-                                else None)(f1flat, f2cat, x_levels)
+                                else None,
+                                feature_dtype and jnp.dtype(
+                                    feature_dtype).name)(
+                                    f1flat, f2cat, x_levels)
 
 
 def pallas_alt_pyramid_radial_epi_flat(f1flat, f2cat, x_levels, w2s, radius,
                                        ew, eb,
                                        precision: str = "highest",
                                        out_dtype=jnp.float32,
-                                       level_scales: tuple = None):
+                                       level_scales: tuple = None,
+                                       feature_dtype=None):
     """Radial pyramid lookup with a fused 1x1-conv + relu epilogue
     (the motion encoder's convc1): returns relu(corr @ ew + eb) directly,
     (B, H, W1, Co).  ``ew`` is (L*K, Co) in the compute dtype, ``eb``
@@ -341,13 +484,14 @@ def pallas_alt_pyramid_radial_epi_flat(f1flat, f2cat, x_levels, w2s, radius,
         f1flat, f2cat, x_levels, bounds, radius, precision,
         jnp.dtype(out_dtype), 0,
         tuple(level_scales) if level_scales is not None else None,
-        epilogue=(ew, eb))
+        epilogue=(ew, eb), feature_dtype=feature_dtype)
 
 
 @functools.lru_cache(maxsize=None)
 def _make_alt_pyr_radial(f1flat_shape, f2cat_shape, w2s, radius, f1_dtype,
                          f2_dtype, precision="highest", out_dtype="float32",
-                         out_channels=0, level_scales=None):
+                         out_channels=0, level_scales=None,
+                         feature_dtype=None):
     bounds = bounds_from_widths(w2s)
     odt = jnp.dtype(out_dtype)
 
@@ -355,12 +499,14 @@ def _make_alt_pyr_radial(f1flat_shape, f2cat_shape, w2s, radius, f1_dtype,
     def f(f1flat, f2cat, x):
         return _alt_pyr_radial_fwd_impl(f1flat, f2cat, x, bounds, radius,
                                         precision, odt, out_channels,
-                                        level_scales)
+                                        level_scales,
+                                        feature_dtype=feature_dtype)
 
     def fwd(f1flat, f2cat, x):
         return _alt_pyr_radial_fwd_impl(
             f1flat, f2cat, x, bounds, radius, precision, odt,
-            out_channels, level_scales), (f1flat, f2cat, x)
+            out_channels, level_scales,
+            feature_dtype=feature_dtype), (f1flat, f2cat, x)
 
     def bwd(res, g):
         f1flat, f2cat, x = res
@@ -389,36 +535,42 @@ def _make_alt_pyr_radial(f1flat_shape, f2cat_shape, w2s, radius, f1_dtype,
 def _alt_pyr_radial_fwd_impl(f1flat, f2cat, x, bounds, radius,
                              prec="highest", out_dtype=jnp.float32,
                              out_channels=0, level_scales=None,
-                             epilogue=None):
+                             epilogue=None, feature_dtype=None):
     f1flat = _pad_rows(f1flat)  # no-ops for preflatten_* outputs
     f2cat = _pad_rows(f2cat)
     n, w1p, c = f1flat.shape
     b, h, w1, nl = x.shape
     t, blk = _pad_taps(x, n)
+    t = jnp.moveaxis(t, 2, 0)   # (L, rows, W1p): free for the one-channel form
     scale = 1.0 / float(c) ** 0.5
     w2cat = f2cat.shape[1]
     n_lvl = len(bounds) if level_scales is not None else nl
     lk = max(n_lvl * (2 * radius + 1), out_channels)
     r = _BLOCK_ROWS
+    exact_bf16 = resolve_corr_matmul(feature_dtype, f1flat.dtype,
+                                     prec) == EXACT_BF16
     operands = [f1flat, f2cat, t]
     in_specs = [
         pl.BlockSpec((r, blk, c), lambda i, j: (i, j, 0),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((r, w2cat, c), lambda i, j: (i, 0, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((r, blk, nl), lambda i, j: (i, j, 0),
+        pl.BlockSpec((nl, r, blk), lambda i, j: (0, i, j),
                      memory_space=pltpu.VMEM),
     ]
     if epilogue is None:
+        assert lk <= LANE, lk
         kernel = functools.partial(
             _alt_pyr_radial_kernel, scale=scale, bounds=bounds,
-            radius=radius, prec=prec, level_scales=level_scales)
+            radius=radius, prec=prec, level_scales=level_scales,
+            exact_bf16=exact_bf16)
     else:
         ew, eb = epilogue                         # (L*K, Co), (1, 1, Co)
         lk = ew.shape[-1]
         kernel = functools.partial(
             _alt_pyr_radial_epi_kernel, scale=scale, bounds=bounds,
-            radius=radius, prec=prec, level_scales=level_scales)
+            radius=radius, prec=prec, level_scales=level_scales,
+            exact_bf16=exact_bf16)
         operands += [ew, eb]
         in_specs += [
             pl.BlockSpec(ew.shape, lambda i, j: (0, 0),

@@ -62,6 +62,7 @@ def describe_runtime(config, batch: int, hw: Sequence[int]) -> Dict:
     import jax
 
     from ..ops.corr import resolve_implementation
+    from ..ops.pallas_alt import resolve_corr_matmul
     from ..ops.pallas_corr import _interpret
     from ..ops.pallas_encoder import use_fused_stem
     from ..ops.pallas_gru import resolve_gru_backend
@@ -69,13 +70,21 @@ def describe_runtime(config, batch: int, hw: Sequence[int]) -> Dict:
     devices = jax.devices()
     stride = 1 + (config.n_downsample > 2)
     h, w = -(-hw[0] // stride), -(-hw[1] // stride)
+    corr = resolve_implementation(config.corr_implementation,
+                                  config.corr_quant)
     return {
         "platform": devices[0].platform,
         "device_kind": devices[0].device_kind,
         "device_count": len(devices),
-        "corr": resolve_implementation(config.corr_implementation,
-                                       config.corr_quant),
+        "corr": corr,
         "corr_auto": resolve_implementation("auto"),
+        # the pallas_alt lookup's matmul form, from the dtype the encoder
+        # hands the features over in (the kernel wrapper asks the same
+        # resolver); the other backends build their volume otherwise
+        "corr_matmul": (resolve_corr_matmul(config.compute_dtype,
+                                            config.corr_dtype,
+                                            config.corr_precision)
+                        if corr == "pallas_alt" else None),
         "gru_backend": resolve_gru_backend(config),
         # the stem gate as models/encoders.py asks it: the context encoder
         # sees the batch, the feature encoder both images of every pair

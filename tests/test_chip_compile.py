@@ -91,6 +91,55 @@ def test_pallas_alt_lookup_with_epilogue(one_chip, compiled_kernels):
     assert compiled.output_shardings == one_chip
 
 
+# What the benchmark's cells serve (PERF.md §4): the 576x960 bucket at batch 8,
+# float32 correlation operands under --mixed_precision.
+SERVED_BATCH, SERVED_H4 = 8, 576 // 4
+SERVED_COMPILE_LIMIT_S = 90.0    # 4-7 s alone; the old body took 25-30 s
+
+
+@pytest.mark.parametrize("born", [BF16, jnp.float32],
+                         ids=["bf16_born", "f32_born"])
+def test_pallas_alt_lookup_as_served(one_chip, compiled_kernels, born):
+    """The served form of the lookup, for both feature origins: float32
+    operands, ``highest``, the convc1 epilogue, bf16 out, 1,152 rows x 240
+    x 256 against the 640 lane-padded pyramid columns.  bf16-born features
+    take the exact one- and three-pass matmul, float32-born the six-pass
+    one (ops/pallas_alt.resolve_corr_matmul); both read their windows by
+    lane gather.  Held to a time limit of its own — three dots in place of
+    one must not turn the server's start into minutes — and to the operand
+    signature the benchmark's roofline reader finds the call by."""
+    import time
+
+    from raftstereo_tpu.ops.corr import make_corr_fn
+
+    planes = LEVELS * (2 * RADIUS + 1)
+
+    def lookup(f1, f2, coords, kernel, bias):
+        fn = make_corr_fn("pallas_alt", f1, f2, LEVELS, RADIUS,
+                          dtype=jnp.float32, precision="highest",
+                          out_dtype=BF16, out_channels=64,
+                          epilogue={"kernel": kernel, "bias": bias})
+        return fn(coords)
+
+    f = _sds(one_chip, (SERVED_BATCH, SERVED_H4, W4, C), born)
+    coords = _sds(one_chip, (SERVED_BATCH, SERVED_H4, W4, 1))
+    t0 = time.monotonic()
+    compiled, kernels = _compile(
+        lookup, f, f, coords, _sds(one_chip, (1, 1, planes, 64), BF16),
+        _sds(one_chip, (64,), BF16))
+    took = time.monotonic() - t0
+    assert kernels == 1
+    assert took < SERVED_COMPILE_LIMIT_S, took
+    # benchmark/metrics/kernel.corr_lookup_roofline.serve.json finds the
+    # call in a trace by its result and its FIRST operand.
+    (call,) = [ln for ln in compiled.as_text().splitlines()
+               if "tpu_custom_call" in ln]
+    rows = SERVED_BATCH * SERVED_H4
+    assert f"= bf16[{rows},{W4},64]" in call
+    assert f"operand_layout_constraints={{f32[{rows},{W4},{C}]" in call
+    assert "alt_lookup_fwd" in call
+
+
 def test_pallas_alt_lookup_train_forward_and_backward(one_chip,
                                                       compiled_kernels):
     """What ``auto`` trains with: the raw lookup and its backward kernel

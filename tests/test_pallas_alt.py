@@ -193,21 +193,25 @@ class TestModelIntegration:
                                    rtol=1e-4, atol=1e-4)
 
 
+def _flats(f1, f2, levels):
+    """The radial kernels' operands as ops/corr.py's construct() lays them
+    out: (f1flat, f2cat, per-level lane-padded widths)."""
+    from raftstereo_tpu.ops.corr import build_fmap2_pyramid
+    from raftstereo_tpu.ops.pallas_alt import (pad_w2_lane, preflatten_fmap1,
+                                               preflatten_fmap2)
+    f1flat = preflatten_fmap1(jnp.asarray(f1))
+    pyr = [pad_w2_lane(preflatten_fmap2(x))
+           for x in build_fmap2_pyramid(jnp.asarray(f2), levels)]
+    w2s = tuple(p.shape[1] for p in pyr)
+    return f1flat, jnp.concatenate(pyr, axis=1), w2s
+
+
 class TestRadialKernel:
     """The model-pattern radial entry (shared-fraction windows) must be
     numerically interchangeable with the general-taps kernel — it is the
     same lookup, resolved with ~1.7x fewer VPU ops."""
 
-    def _flats(self, f1, f2, levels=3):
-        from raftstereo_tpu.ops.corr import build_fmap2_pyramid
-        from raftstereo_tpu.ops.pallas_alt import (pad_w2_lane,
-                                                   preflatten_fmap1,
-                                                   preflatten_fmap2)
-        f1flat = preflatten_fmap1(jnp.asarray(f1))
-        pyr = [pad_w2_lane(preflatten_fmap2(x))
-               for x in build_fmap2_pyramid(jnp.asarray(f2), levels)]
-        w2s = tuple(p.shape[1] for p in pyr)
-        return f1flat, jnp.concatenate(pyr, axis=1), w2s
+    _flats = staticmethod(lambda f1, f2, levels=3: _flats(f1, f2, levels))
 
     def test_matches_general_taps(self, fmaps, coords):
         from raftstereo_tpu.ops.pallas_alt import (
@@ -375,3 +379,273 @@ class TestEpilogue:
             corr_mod.corr_epilogue_enabled = prev
         np.testing.assert_allclose(np.asarray(up_on), np.asarray(up_off),
                                    rtol=1e-4, atol=1e-4)
+
+
+def _kernel_dots(fn, *args):
+    """(lhs dtype, rhs dtype, precision) of every dot inside the
+    ``alt_lookup_fwd`` kernels that ``fn(*args)`` traces."""
+    dots = []
+
+    def walk(jaxpr, inside):
+        for eqn in jaxpr.eqns:
+            if inside and eqn.primitive.name == "dot_general":
+                prec = eqn.params["precision"]
+                prec = prec[0] if isinstance(prec, tuple) else prec
+                dots.append((eqn.invars[0].aval.dtype.name,
+                             eqn.invars[1].aval.dtype.name,
+                             None if prec is None else prec.name))
+            here = inside or (
+                eqn.primitive.name == "pallas_call"
+                and eqn.params["name"] == "alt_lookup_fwd")
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub, here)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, False)
+    return dots
+
+
+def _window_sum_body(f1flat, f2cat, x, w2s, radius, scales):
+    """The radial lookup as it stood before PR 27, in plain jnp: the
+    float32 ``highest`` product, K+1 masked window SUMS a level, the lerp.
+    The oracle for 'bit-equal to the parent's'."""
+    c = f1flat.shape[-1]
+    m = jax.lax.dot_general(
+        f1flat, f2cat, (((2,), (2,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32) * (1.0 / float(c) ** 0.5)
+    x0 = x.reshape(f1flat.shape[0], -1)
+    kk, cols, off = 2 * radius + 1, [], 0
+    for li, w2p in enumerate(w2s):
+        ml = m[:, :, off:off + w2p]
+        off += w2p
+        xl = x0 * scales[li]
+        b0 = jnp.floor(xl)
+        f = xl - b0
+        z = (jnp.arange(w2p, dtype=jnp.int32)
+             - b0.astype(jnp.int32)[..., None] + radius)
+        wins = [jnp.sum(jnp.where(z == d, ml, 0.0), axis=-1)
+                for d in range(kk + 1)]
+        cols += [wins[k] * (1.0 - f) + wins[k + 1] * f for k in range(kk)]
+    return jnp.stack(cols, axis=-1).reshape(x.shape[:3] + (len(cols),))
+
+
+class TestMatmulForm:
+    """PR 27: the radial body reads its windows (one lane gather per
+    128-column chunk) and, for bf16-BORN features, runs only the bf16
+    passes of the float32 product that can be non-zero — one for level 0,
+    three for the pooled levels.  What decides is the dtype the encoder
+    handed the features over in, nothing else."""
+
+    LEVELS, RADIUS = 3, 3
+    SCALES = (1.0, 0.5, 0.25)
+
+    def _operands(self, rng, born, c=64):
+        """Float32 operands as ops/corr.py's construct() builds them from
+        features born in ``born`` (C = 64: 1/sqrt(C) is a power of two)."""
+        f1, f2 = (jnp.asarray(rng.standard_normal((2, 4, 40, c))
+                              .astype(np.float32)).astype(born)
+                  for _ in range(2))
+        return _flats(f1.astype(jnp.float32), f2.astype(jnp.float32),
+                      self.LEVELS)
+
+    def _centres(self, rng, kind):
+        grid = coords_grid_x(2, 4, 40)
+        if kind == "frac":
+            return grid - jnp.asarray(
+                rng.uniform(0, 12, (2, 4, 40, 1)).astype(np.float32))
+        if kind == "halves":   # f in {0, .5} at every level: an exact lerp
+            x = grid - 2.0 * jnp.asarray(
+                rng.integers(0, 8, (2, 4, 40, 1)).astype(np.float32))
+            return x - x % 2.0
+        # integers, the level edges, far out of range, NaN
+        vals = np.array([0.0, 7.0, -50.0, 200.0, 39.0, 39.5, -0.5, np.nan,
+                         1e6, 19.75], np.float32)
+        return jnp.asarray(np.tile(vals, (2, 4, 4))[..., None])
+
+    def _lookup(self, ops, x, feature_dtype, epi=None, **kw):
+        from raftstereo_tpu.ops.pallas_alt import (
+            pallas_alt_pyramid_radial_epi_flat,
+            pallas_alt_pyramid_radial_flat)
+        f1flat, f2cat, w2s = ops
+        if epi is not None:
+            return pallas_alt_pyramid_radial_epi_flat(
+                f1flat, f2cat, x, w2s, self.RADIUS, *epi,
+                level_scales=self.SCALES, feature_dtype=feature_dtype, **kw)
+        return pallas_alt_pyramid_radial_flat(
+            f1flat, f2cat, x, w2s, self.RADIUS, level_scales=self.SCALES,
+            feature_dtype=feature_dtype, **kw)
+
+    def _epi(self):
+        r = np.random.default_rng(7)
+        lk = self.LEVELS * (2 * self.RADIUS + 1)
+        return (jnp.asarray(r.normal(size=(lk, 64)).astype(np.float32)) * 0.2,
+                jnp.asarray(r.normal(size=(1, 1, 64)).astype(np.float32))
+                * 0.1)
+
+    @pytest.mark.parametrize("kind", ["normal", "tiny", "huge", "special"])
+    def test_split3_reconstructs_float32_exactly(self, rng, kind):
+        from raftstereo_tpu.ops.pallas_alt import _split3
+        x = rng.standard_normal(4096).astype(np.float32)
+        if kind == "tiny":     # down to where the low piece leaves bf16
+            x = np.copysign(1 + np.abs(x), x) * np.float32(2.0 ** -100)
+        elif kind == "huge":
+            x = x * np.float32(2.0 ** 120)
+        elif kind == "special":
+            x = np.array([0.0, -0.0, 1.0, -1.0, 1.0 + 2.0 ** -23,
+                          1.0 - 2.0 ** -24, 2.0 - 2.0 ** -23, 255.5,
+                          1.00390625, 0.99609375, 3.0e38,
+                          2.0 ** -102, 16777215.0, 1 / 3], np.float32)
+        hi, mid, lo = _split3(jnp.asarray(x))
+        assert hi.dtype == mid.dtype == lo.dtype == jnp.bfloat16
+        back = (hi.astype(jnp.float32) + mid.astype(jnp.float32)) \
+            + lo.astype(jnp.float32)
+        np.testing.assert_array_equal(np.asarray(back), x)
+
+    @pytest.mark.parametrize("feature,operand,precision,want", [
+        ("bfloat16", "float32", "highest", "bf16_exact_1+3"),
+        ("float32", "float32", "highest", "f32_highest"),
+        (None, "float32", "highest", "f32_highest"),
+        ("bfloat16", "float32", "high", "f32_high"),
+        ("bfloat16", "float32", "default", "f32_default"),
+        ("bfloat16", "bfloat16", "highest", "bf16_native"),
+        ("float32", "bfloat16", "highest", "bf16_native"),
+    ])
+    def test_resolver(self, feature, operand, precision, want):
+        from raftstereo_tpu.ops.pallas_alt import resolve_corr_matmul
+        assert resolve_corr_matmul(feature, operand, precision) == want
+
+    @pytest.mark.parametrize("born,corr_dtype,want", [
+        # one native pass over level 0, three over the pooled levels
+        (jnp.bfloat16, jnp.float32,
+         [("bfloat16", "bfloat16", "DEFAULT")] * 4),
+        (jnp.float32, jnp.float32, [("float32", "float32", "HIGHEST")]),
+        (jnp.bfloat16, jnp.bfloat16, [("bfloat16", "bfloat16", "DEFAULT")]),
+    ])
+    def test_path_follows_the_feature_dtype_and_no_flag(self, fmaps, coords,
+                                                        born, corr_dtype,
+                                                        want):
+        """make_corr_fn is called the same way every time; only the dtype
+        of the features it is handed differs."""
+        f1, f2 = (f.astype(born) for f in fmaps)
+
+        def lookup(a, b, c):
+            return make_corr_fn("pallas_alt", a, b, 4, 4, dtype=corr_dtype,
+                                out_channels=64)(c)
+
+        assert _kernel_dots(lookup, f1, f2, coords) == want
+
+    def test_phase_split_state_takes_the_dtype_it_is_told(self, fmaps,
+                                                          coords):
+        """The state arrays are float32 whatever the features were born
+        as, so corr_fn_from_state is told (the model passes self.dtype)."""
+        from raftstereo_tpu.ops.corr import (build_corr_state,
+                                             corr_fn_from_state)
+        f1, f2 = (f.astype(jnp.bfloat16) for f in fmaps)
+        state = build_corr_state("pallas_alt", f1, f2, 4)
+        assert all(s.dtype == jnp.float32 for s in state)
+
+        def lookup(fd):
+            return lambda s, c: corr_fn_from_state(
+                "pallas_alt", s, 4, 4, feature_dtype=fd)(c)
+
+        assert len(_kernel_dots(lookup(jnp.bfloat16), state, coords)) == 4
+        assert _kernel_dots(lookup(None), state, coords) == [
+            ("float32", "float32", "HIGHEST")]
+        want = make_corr_fn("pallas_alt", f1, f2, 4, 4)(coords)
+        got = lookup(jnp.bfloat16)(state, coords)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("epilogue", [False, True],
+                             ids=["raw", "epilogue"])
+    @pytest.mark.parametrize("kind", ["frac", "edges"])
+    def test_bf16_born_equals_highest_body(self, rng, kind, epilogue):
+        """Same operands, both bodies: the exact passes against the six-pass
+        ``highest`` product.  Level 0 (one pass) and the pooled levels
+        (three passes) are asserted separately; float32 rounding only."""
+        ops = self._operands(rng, jnp.bfloat16)
+        x = self._centres(rng, kind)
+        epi = self._epi() if epilogue else None
+        assert len(_kernel_dots(
+            lambda c: self._lookup(ops, c, jnp.bfloat16, epi), x)) == 4 + epilogue
+        got = np.asarray(self._lookup(ops, x, jnp.bfloat16, epi))
+        want = np.asarray(self._lookup(ops, x, None, epi))
+        assert np.array_equal(np.isnan(got), np.isnan(want))
+        if kind == "edges":
+            assert np.isnan(want).any() and (want == 0).any()
+        if epilogue:
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+            return
+        kk = 2 * self.RADIUS + 1
+        np.testing.assert_allclose(got[..., :kk], want[..., :kk],
+                                   rtol=2e-6, atol=2e-6)
+        np.testing.assert_allclose(got[..., kk:], want[..., kk:],
+                                   rtol=2e-6, atol=2e-6)
+        # and the general-taps kernel (hat form), an independent body
+        from raftstereo_tpu.ops.pallas_alt import pallas_alt_pyramid_flat
+        xl = x[..., 0:1] * jnp.asarray(self.SCALES)
+        taps = (xl[..., None] + jnp.arange(-self.RADIUS, self.RADIUS + 1.0)
+                ).reshape(*xl.shape[:-1], -1)
+        hat = np.asarray(pallas_alt_pyramid_flat(ops[0], ops[1], taps,
+                                                 ops[2]))
+        np.testing.assert_allclose(got, hat, rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("kw", [{}, {"out_dtype": jnp.bfloat16,
+                                         "out_channels": 64}],
+                             ids=["f32_out", "bf16_out_padded"])
+    @pytest.mark.parametrize("kind", ["halves", "frac", "edges"])
+    def test_f32_born_is_the_parents_result(self, rng, kind, kw):
+        """Float32-born features keep the ``highest`` dot, and a window
+        that is read is the window that was summed: bit-equal to the
+        parent's body wherever the lerp is exact (the CPU compiler is free
+        to fuse a*b + c*d either way, so fractional centres are held to an
+        ulp instead)."""
+        ops = self._operands(rng, jnp.float32)
+        x = self._centres(rng, kind)
+        assert _kernel_dots(lambda c: self._lookup(ops, c, jnp.float32, **kw),
+                            x) == [("float32", "float32", "HIGHEST")]
+        got = self._lookup(ops, x, jnp.float32, **kw)
+        lk = self.LEVELS * (2 * self.RADIUS + 1)
+        want = _window_sum_body(ops[0], ops[1], x, ops[2], self.RADIUS,
+                                self.SCALES).astype(got.dtype)
+        assert got.dtype == kw.get("out_dtype", jnp.float32)
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        assert not got[..., lk:].any()           # the channel padding
+        if kind == "halves":
+            np.testing.assert_array_equal(got[..., :lk], want)
+        else:
+            tol = 1e-2 if kw else 1e-6
+            np.testing.assert_allclose(got[..., :lk], want, rtol=tol,
+                                       atol=tol)
+
+    def test_gradients_unchanged_for_bf16_born(self, rng):
+        """The backward kernel is not this PR's: same cotangents whatever
+        the forward's matmul form (custom_vjp; residuals are the operands)."""
+        ops = self._operands(rng, jnp.bfloat16)
+        x = self._centres(rng, "frac")
+
+        def loss(fd):
+            return lambda a, b: (self._lookup((a, b, ops[2]), x, fd)
+                                 ** 2).sum()
+
+        g_exact = jax.grad(loss(jnp.bfloat16), argnums=(0, 1))(*ops[:2])
+        g_high = jax.grad(loss(None), argnums=(0, 1))(*ops[:2])
+        for a, b in zip(g_exact, g_high):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-5)
+
+    def test_runtime_line_names_the_form(self):
+        from raftstereo_tpu.config import RAFTStereoConfig
+        from raftstereo_tpu.utils.platform import describe_runtime
+        want = {("bfloat16", "float32"): "bf16_exact_1+3",
+                ("float32", "float32"): "f32_highest",
+                ("bfloat16", "bfloat16"): "bf16_native"}
+        for (compute, corr), form in want.items():
+            cfg = RAFTStereoConfig(corr_implementation="pallas_alt",
+                                   compute_dtype=compute, corr_dtype=corr)
+            assert describe_runtime(cfg, 1, (64, 96))["corr_matmul"] == form
+        # off the TPU ``auto`` is the XLA gather path: no such matmul
+        assert describe_runtime(RAFTStereoConfig(), 1,
+                                (64, 96))["corr_matmul"] is None
