@@ -576,8 +576,16 @@ class ServeConfig:
     # (413) and images with a side above max_image_dim (400) before any
     # decode/allocation.  The body default is sized to what max_image_dim
     # actually needs (a 2048^2 fp32 pair is ~134 MB base64), not beyond
-    # it.  cold_buckets=False additionally rejects shapes whose bucket
-    # was not warmed at startup (400) — the production setting; True
+    # it.  max_image_dim bounds what a server will COMPILE on demand and
+    # stays what the operator set.  A shape the operator lists in
+    # ``buckets`` is warmed at start-up and is admissible whatever the
+    # constant says: above the constant a pair passes only if it fits
+    # inside a listed bucket, both sides (``admits``), and the body cap
+    # is raised to what the largest such bucket needs (__post_init__,
+    # ``bucket_body_mb``).  So a 1988x2964 bucket is served with no other
+    # flag, and a 3000-wide or a 2900x2900 pair is still a 400.
+    # cold_buckets=False additionally rejects shapes whose bucket was
+    # not warmed at startup (400) — the production setting; True
     # compiles on demand (development, tests).
     max_body_mb: float = 160.0
     max_image_dim: int = 2048
@@ -619,7 +627,7 @@ class ServeConfig:
     # other shapes — or with an ``accuracy`` tier / ``session_id``, both
     # unsupported under sharding in v1 — are 400s at admission, never a
     # compile.  When spatial buckets are configured, ``max_body_mb`` is
-    # auto-raised to fit the largest one (see ``spatial_body_mb``), so a
+    # auto-raised to fit the largest one (see ``bucket_body_mb``), so a
     # 4K pair is not 413'd before admission ever sees it.
     spatial_shards: int = 0
     spatial_buckets: Tuple[Tuple[int, int], ...] = ()
@@ -664,14 +672,19 @@ class ServeConfig:
                 self, "spatial_buckets",
                 tuple(tuple(b) for b in self.spatial_buckets))
         assert self.spatial_shards >= 0, self.spatial_shards
+        # A configured bucket above the side ceiling is admissible
+        # (``admits``): the body cap follows from the largest such one
+        # (the operator's own cap stands for everything under it).
+        over = tuple(b for b in self.buckets if max(b) > self.max_image_dim)
+        need = bucket_body_mb(over)
         if self.spatial_shards > 1 and self.spatial_buckets:
             # The whole point of the spatial path is payloads above the
             # single-chip cap — refusing them at the body cap would make
             # the capability unreachable (serve/httpbase.py 413s before
             # admission ever sees the request).
-            need = spatial_body_mb(self.spatial_buckets)
-            if need > self.max_body_mb:
-                object.__setattr__(self, "max_body_mb", need)
+            need = max(need, bucket_body_mb(self.spatial_buckets))
+        if need > self.max_body_mb:
+            object.__setattr__(self, "max_body_mb", need)
         _known_tiers = ("certified", "fast", "turbo")  # ops/quant.TIERS
         bad_tiers = [t for t in self.tiers if t not in _known_tiers]
         assert not bad_tiers, (
@@ -739,13 +752,24 @@ class ServeConfig:
                     f"max_iters {self.sched.max_iters})")
 
 
-def spatial_body_mb(buckets: Tuple[Tuple[int, int], ...],
-                    channels: int = 3) -> float:
-    """Request-body cap (MB) the largest spatial bucket needs: two fp32
+    def admits(self, h: int, w: int) -> bool:
+        """Whether an (h, w) image passes the side ceiling: no side above
+        ``max_image_dim``, or both sides inside a bucket the operator
+        listed.  ``max_image_dim`` stays the bound on what is compiled
+        cold: a pair above it runs the listed bucket's program or a
+        smaller one, never a larger."""
+        if max(h, w) <= self.max_image_dim:
+            return True
+        return any(h <= bh and w <= bw for bh, bw in self.buckets)
+
+
+def bucket_body_mb(buckets: Tuple[Tuple[int, int], ...],
+                   channels: int = 3) -> float:
+    """Request-body cap (MB) the largest of ``buckets`` needs: two fp32
     images base64-encoded (4/3 expansion) plus 25% JSON/meta headroom.
-    ``ServeConfig`` raises ``max_body_mb`` to this when spatial buckets
-    are configured — a 4K pair is ~265 MB on the wire, well above the
-    single-chip default cap."""
+    ``ServeConfig`` raises ``max_body_mb`` to this for spatial buckets
+    and for plain buckets above ``max_image_dim`` — a 4K pair is ~265 MB
+    on the wire, a 1988x2964 one 235 MB, both above the default cap."""
     if not buckets:
         return 0.0
     h, w = max(buckets, key=lambda b: b[0] * b[1])
@@ -796,9 +820,13 @@ def add_serve_args(parser: argparse.ArgumentParser) -> None:
     g.add_argument("--degrade_queue_depth", type=int,
                    default=d.degrade_queue_depth)
     g.add_argument("--max_body_mb", type=float, default=d.max_body_mb,
-                   help="reject request bodies above this size (HTTP 413)")
+                   help="reject request bodies above this size (HTTP 413); "
+                        "raised to fit a --buckets shape above "
+                        "--max_image_dim")
     g.add_argument("--max_image_dim", type=int, default=d.max_image_dim,
-                   help="reject images with a side above this (HTTP 400)")
+                   help="reject images with a side above this (HTTP 400) "
+                        "unless they fit inside a shape listed in "
+                        "--buckets")
     g.add_argument("--no_cold_buckets", action="store_true",
                    help="reject shapes whose bucket was not warmed at "
                         "startup instead of compiling on demand (recommended "

@@ -407,7 +407,7 @@ def _enc_conv(x, stats, w9, bias, res=None, res_stats=None,
     ``want_stats=False`` (affine pipelines) skips the output-stats
     accumulation entirely.  Returns (y_raw fp-of-x, (s1, s2) or None)."""
     b, h, wp, c2 = x.shape
-    r = _row_block(h)
+    r = _row_block(h, row_elems=wp * c2)
     grid = (b, h // r)
     xh = _halo_rows(x, r, boundary)
     if hv is None:
@@ -473,7 +473,7 @@ def _packed_stats(x):
     from .pallas_norm import _in_stats_kernel
 
     b, h, wp, c2 = x.shape
-    r = _row_block(h)
+    r = _row_block(h, row_elems=wp * c2)
     return pl.pallas_call(
         _in_stats_kernel,
         out_shape=(jax.ShapeDtypeStruct((b, 1, c2), jnp.float32),
@@ -572,7 +572,7 @@ def _stage_on_packed(xp, st1, params, n, space_axis=None, space_size=1,
     never re-runs a forward (see _stage_bwd_xla)."""
     dt = xp.dtype
     b, h, wp, c2 = xp.shape
-    r = _row_block(h)
+    r = _row_block(h, row_elems=wp * c2)
     nblk = h // r
     hv, exch = _shard_ctx(nblk, space_axis, space_size)
 
@@ -803,7 +803,7 @@ def _stem_conv1_s2(img, c1_params, dt, boundary=None, want_stats=True):
     H % 2 == 0 and W % 4 == 0."""
     b, h, w, ci = img.shape
     xq = img.astype(dt).reshape(b, h, w // 4, 4 * ci)
-    r = _row_block(h // 2)
+    r = _row_block(h // 2, row_elems=(w // 4) * 128)
     grid = (b, (h // 2) // r)
     xh = _halo_rows_s2(xq, r, boundary)
     w7 = pack_weights7s2(c1_params["kernel"]).astype(dt)
@@ -866,7 +866,7 @@ def _stem_conv1(img, c1_params, dt, boundary=None, want_stats=True):
     Requires stride 1 (downsample <= 2) and W % 2 == 0."""
     xp = pack_view(img.astype(dt))                 # (B, H, W/2, 6)
     b, h, wp, c2 = xp.shape
-    r = _row_block(h)
+    r = _row_block(h, row_elems=wp * 128)          # the output row: 128 packed
     grid = (b, h // r)
     xh = _halo_rows3(xp, r, boundary)
     w7 = pack_weights7(c1_params["kernel"]).astype(dt)
@@ -1194,7 +1194,7 @@ def _in_bwd_means(u, xhat):
     up = pack_view(u)
     vp = pack_view(xhat)
     b, h, wp, c2 = up.shape
-    r = _row_block(h)
+    r = _row_block(h, row_elems=wp * c2)
     s1, s2 = pl.pallas_call(
         _dual_sum_kernel,
         out_shape=(jax.ShapeDtypeStruct((b, 1, c2), jnp.float32),

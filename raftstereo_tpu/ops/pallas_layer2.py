@@ -279,7 +279,7 @@ def _specs(r, w2, c):
 def _l2_entry(xp, w3, b3, wp1, bp1, dt):
     b, h, wpk, c2 = xp.shape
     h2 = h // 2
-    r = _row_block(h2)
+    r = _row_block(h2, row_elems=wpk * c2)
     grid = (b, h2 // r)
     xh = _halo1_above_s2(xp, r)
     co = w3.shape[-1]
@@ -318,7 +318,7 @@ def _l2_entry(xp, w3, b3, wp1, bp1, dt):
 
 def _l2_conv(x, aff, w, bias, dt, res=None, res_aff=None):
     b, h2, w2, c = x.shape
-    r = _row_block(h2)
+    r = _row_block(h2, row_elems=w2 * c)
     grid = (b, h2 // r)
     hv = _default_hv2(h2 // r)
     row, halo, stat = _specs(r, w2, c)
@@ -358,7 +358,7 @@ def _l2_conv(x, aff, w, bias, dt, res=None, res_aff=None):
 
 def _l2_finish(p, ap, c2, a2, c4, a4, dt):
     b, h2, w2, c = p.shape
-    r = _row_block(h2)
+    r = _row_block(h2, row_elems=w2 * c)
     row, _, stat = _specs(r, w2, c)
     return pl.pallas_call(
         _l2_finish_kernel,

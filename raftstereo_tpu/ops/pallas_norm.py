@@ -35,11 +35,23 @@ from jax.experimental.pallas import tpu as pltpu
 from .pallas_corr import _COMPILER_PARAMS, _interpret
 
 
-def _row_block(h: int, cap: int = 32) -> int:
+# Elements of one activation row block the encoder kernels are sized for:
+# 32 rows of a 1,536-wide 64-channel image (768 packed pixels x 128).  A
+# block's VMEM grows with rows x width, and 32 rows of a 3,008-wide image
+# (``encoder_conv``: 107.9 MB against the 100 MB limit, PR 28) no longer fit.
+_BLOCK_ELEMS = 32 * 768 * 128
+
+
+def _row_block(h: int, cap: int = 32, row_elems: int = 0) -> int:
     """Largest power-of-two divisor of ``h`` up to ``cap`` (encoder heights
-    are multiples of 16 at flagship shapes; odd heights degrade gracefully)."""
+    are multiples of 16 at flagship shapes; odd heights degrade gracefully).
+    ``row_elems`` (elements in one row of the widest block) lowers the cap
+    so that a block holds at most ``_BLOCK_ELEMS``: widths up to 1,536 keep
+    all 32 rows, a 3,008-wide image gets 16."""
+    if row_elems:
+        cap = min(cap, max(1, _BLOCK_ELEMS // row_elems))
     r = 1
-    while r < cap and h % (r * 2) == 0:
+    while r * 2 <= cap and h % (r * 2) == 0:
         r *= 2
     return r
 
@@ -85,7 +97,7 @@ def _xla_instance_norm(x, relu):
 
 def _pallas_forward(x, relu):
     b, h, w, c = x.shape
-    r = _row_block(h)
+    r = _row_block(h, row_elems=w * c)
     grid = (b, h // r)
     s1, s2 = pl.pallas_call(
         _in_stats_kernel,

@@ -333,7 +333,12 @@ class DynamicBatcher:
                                  ("device_wait", seg.get("device_wait")),
                                  ("host_fetch", seg.get("host_fetch"))):
                 if window:
-                    self.tracer.record(name, *window, btid, attrs=battrs)
+                    # pad_bucket also says how much of the batch is real
+                    # (real_px / bucket_px, engine._pad_pairs)
+                    extra = (seg.get("pad_px") or {}
+                             if name == "pad_bucket" else {})
+                    self.tracer.record(name, *window, btid,
+                                       attrs={**battrs, **extra})
         for r in batch:
             if r.trace_id is None:
                 continue
@@ -354,7 +359,8 @@ class DynamicBatcher:
                 attrs=attrs)
             if seg.get("pad"):
                 self.tracer.record("pad_bucket", *seg["pad"], r.trace_id,
-                                   parent_id=parent)
+                                   parent_id=parent,
+                                   attrs=seg.get("pad_px"))
             self.tracer.record("device_compute", *seg["dispatch"],
                                r.trace_id, parent_id=parent)
             self.tracer.record("host_fetch", *seg["host_fetch"], r.trace_id)

@@ -57,7 +57,7 @@ class ServeError(RuntimeError):
         msg = f"HTTP {status}: {payload.get('error', payload)}"
         if status == 413 and "limit_mb" in payload:
             # Actionable refusal, not a mystery drop: the cap auto-sizes
-            # to --spatial_buckets (config.spatial_body_mb), so the fix
+            # to --spatial_buckets (config.bucket_body_mb), so the fix
             # is a server configured for the resolution, not a retry.
             msg += (f" (server body cap {payload['limit_mb']} MB; an "
                     f"oversized pair needs --spatial_buckets covering it)")

@@ -52,8 +52,12 @@ class BatchEngine:
 
     def __init__(self, model, variables, config: ServeConfig,
                  metrics: Optional[ServeMetrics] = None, device=None,
-                 fault_plan=None):
+                 fault_plan=None, tracer=None):
         self.model = model
+        # Where a bucket program's ``compile`` span goes (obs/trace.py
+        # Tracer; None records none): the first call of every compiled
+        # key, with what its shapes resolved to (``_program_facts``).
+        self.tracer = tracer
         # Serving-plane chaos seam (utils/faults.py FaultPlan or None):
         # ``slow_replica@request=N:SECS`` injects dispatch latency at
         # the top of ``_dispatch`` — a replica that is alive but slow,
@@ -194,6 +198,26 @@ class BatchEngine:
     def compiled_keys(self) -> Set[Tuple]:
         with self._stats_lock:
             return set(self._compiled)
+
+    def _program_facts(self, key: Tuple) -> Dict:
+        """What the shapes of a plain batch program resolved to
+        (utils/platform.describe_program: ``corr_block``,
+        ``fused_stages``); nothing for the other kinds of key, whose
+        programs see other batches (stream, sched) or other paths
+        (spatial, cascade)."""
+        if self.model is None or len(key) != 6:
+            return {}
+        from ..utils.platform import describe_program
+        return describe_program(self.model.config, self.cfg.max_batch_size,
+                                key[:2])
+
+    @property
+    def compiled_programs(self) -> Dict[str, Dict]:
+        """Per compiled plain batch key (as ``h x w x iters x ...``): the
+        lookup kernel's blocks and which encoder stages run fused."""
+        return {"x".join(str(k) for k in key): self._program_facts(key)
+                for key in sorted(k for k in self.compiled_keys
+                                  if len(k) == 6)}
 
     def is_warm(self, hw: Tuple[int, int], iters: int,
                 mode: Optional[str] = None) -> bool:
@@ -518,9 +542,15 @@ class BatchEngine:
         keyed by bucket alone.  All pairs must map to one bucket (the
         batcher groups by bucket before dispatching)."""
         assert pairs, "empty batch"
-        with timed_phase("pad_bucket", batch_size=len(pairs)) as ph:
+        # what the dispatch was asked for and what it computes: the real
+        # pairs' pixels and the padded batch's
+        bh, bw = self.bucket_of(pairs[0][0].shape)
+        px = {"real_px": sum(p[0].shape[0] * p[0].shape[1] for p in pairs),
+              "bucket_px": bh * bw * self.cfg.max_batch_size}
+        with timed_phase("pad_bucket", batch_size=len(pairs), **px) as ph:
             staged = self._stage_pairs(pairs)
         self._seg.pad = ph.window
+        self._seg.pad_px = px
         return staged
 
     def _stage_pairs(self, pairs):
@@ -598,8 +628,16 @@ class BatchEngine:
             self.last_included_compile = miss
             with self._stats_lock:
                 self._compiled.add(key)
+            if miss and self.tracer is not None:
+                # the bucket's own compile span, beside the per-program
+                # ones of the jax.monitoring listener (trace ``xla``)
+                self.tracer.record(
+                    "compile", ph_launch.t0, ph_wait.t1, "xla",
+                    attrs={"kind": "bucket", **labels,
+                           **self._program_facts(key)})
         self._seg.last = {
             "pad": getattr(self._seg, "pad", None),
+            "pad_px": getattr(self._seg, "pad_px", None),
             "launch": ph_launch.window,
             "device_wait": ph_wait.window,
             "dispatch": (start, t_compute),

@@ -10,8 +10,9 @@ lands in both.
 
 The body cap is a POLICY ARGUMENT, not a constant: every call takes
 ``limit_mb`` from the caller's ``ServeConfig.max_body_mb``, which
-auto-raises to fit the largest configured spatial bucket
-(``config.spatial_body_mb`` — a 4K fp32 pair is 253.1 MiB of base64
+auto-raises to fit the largest configured spatial bucket and the
+largest plain bucket above ``max_image_dim``
+(``config.bucket_body_mb`` — a 4K fp32 pair is 253.1 MiB of base64
 JSON body, measured as ``len(json.dumps(payload))`` for a
 3840x2160x3 pair, so the cap lands at ~316 MiB after the 25% decode
 headroom; the binary wire format carries the same pair in under a

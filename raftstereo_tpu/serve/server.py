@@ -472,6 +472,9 @@ class _Handler(JsonRequestHandler):
                 } if lat.count else None),
                 "engine": {
                     "compiled_buckets": sorted(srv.engine.compiled_keys),
+                    # per warmed bucket program: the lookup kernel's
+                    # blocks and which encoder stages run fused
+                    "programs": srv.engine.compiled_programs,
                     "queue_depth": srv.queue_depth,
                     "stream_sessions": (len(srv.stream.store)
                                         if srv.stream is not None else None),
@@ -806,10 +809,12 @@ class _Handler(JsonRequestHandler):
                 _, iters = admit_spatial(
                     srv.config, srv.engine, iters, accuracy, session_id,
                     deadline_ms, priority, left.shape)
-            elif max(left.shape[:2]) > srv.config.max_image_dim:
+            elif not srv.config.admits(*left.shape[:2]):
                 raise ValueError(
                     f"image side {max(left.shape[:2])} exceeds "
-                    f"max_image_dim {srv.config.max_image_dim}")
+                    f"max_image_dim {srv.config.max_image_dim} and "
+                    f"{left.shape[0]}x{left.shape[1]} fits no shape "
+                    f"listed in --buckets")
             if accuracy is not None:
                 # Accuracy tiers (ops/quant.py, docs/serving.md): only
                 # ADVERTISED tiers resolve — a tier the certification
@@ -1521,7 +1526,7 @@ def build_server(model, variables, config: ServeConfig,
             rset.warmup(modes=warm_modes)
     else:
         engine = BatchEngine(model, variables, config, metrics,
-                             fault_plan=fault_plan)
+                             fault_plan=fault_plan, tracer=tracer)
         scheduler = None
         if config.sched is not None:
             # Iteration-level continuous batching: the scheduler IS the

@@ -54,6 +54,40 @@ def setup_compile_cache() -> Optional[str]:
     return path
 
 
+def describe_program(config, batch: int, hw: Sequence[int]) -> Dict:
+    """What the shapes of one compiled program — ``config`` at ``batch``
+    padded images of ``hw`` — resolve to where the kernels choose from
+    shapes: ``corr_block``, the rows and pixels one grid step of the
+    on-demand lookup takes (None where another backend serves), and
+    ``fused_stages``, the encoder gates' answers as ``models/encoders.py``
+    asks them (the context encoder sees the batch, the feature encoder
+    both images of every pair).  Pure in the backend's name, the device
+    count and the override scopes of the calling thread."""
+    from ..ops.corr import resolve_implementation
+    from ..ops.pallas_corr import _BLOCK_ROWS, _block_w1
+    from ..ops.pallas_encoder import use_fused_stem
+    from ..ops.pallas_layer2 import use_fused_layer2
+
+    stride = 1 + (config.n_downsample > 2)
+    h, w = -(-hw[0] // stride), -(-hw[1] // stride)
+    stride2 = 1 + (config.n_downsample > 1)
+    corr = resolve_implementation(config.corr_implementation,
+                                  config.corr_quant)
+    stages = {}
+    for tag, norm, n in (("cnet", config.context_norm, batch),
+                         ("fnet", "instance", 2 * batch)):
+        stages[f"stem_{tag}"] = bool(use_fused_stem(
+            norm, (n, h, w, 64), config.fused_encoder))
+        stages[f"layer2_{tag}"] = bool(use_fused_layer2(
+            norm, stride2, (n, h, w, 64), override=config.fused_encoder))
+    return {
+        "corr_block": ({"rows": _BLOCK_ROWS,
+                        "pixels": _block_w1(hw[1] // 2 ** config.n_downsample)}
+                       if corr == "pallas_alt" else None),
+        "fused_stages": stages,
+    }
+
+
 def describe_runtime(config, batch: int, hw: Sequence[int]) -> Dict:
     """The device this process runs on and what each ``auto`` kernel gate
     resolves to here for ``config`` at ``batch`` padded images of ``hw`` —
@@ -64,12 +98,10 @@ def describe_runtime(config, batch: int, hw: Sequence[int]) -> Dict:
     from ..ops.corr import resolve_implementation
     from ..ops.pallas_alt import resolve_corr_matmul
     from ..ops.pallas_corr import _interpret
-    from ..ops.pallas_encoder import use_fused_stem
     from ..ops.pallas_gru import resolve_gru_backend
 
     devices = jax.devices()
-    stride = 1 + (config.n_downsample > 2)
-    h, w = -(-hw[0] // stride), -(-hw[1] // stride)
+    stages = describe_program(config, batch, hw)["fused_stages"]
     corr = resolve_implementation(config.corr_implementation,
                                   config.corr_quant)
     return {
@@ -86,12 +118,8 @@ def describe_runtime(config, batch: int, hw: Sequence[int]) -> Dict:
                                             config.corr_precision)
                         if corr == "pallas_alt" else None),
         "gru_backend": resolve_gru_backend(config),
-        # the stem gate as models/encoders.py asks it: the context encoder
-        # sees the batch, the feature encoder both images of every pair
-        "fused_stem_cnet": bool(use_fused_stem(
-            config.context_norm, (batch, h, w, 64), config.fused_encoder)),
-        "fused_stem_fnet": bool(use_fused_stem(
-            "instance", (2 * batch, h, w, 64), config.fused_encoder)),
+        "fused_stem_cnet": stages["stem_cnet"],
+        "fused_stem_fnet": stages["stem_fnet"],
         "pallas_interpret": bool(_interpret()),
         "compile_cache": compile_cache_dir(),
     }

@@ -168,9 +168,48 @@ def test_pallas_lookup_over_precomputed_volume(one_chip, compiled_kernels):
     assert kernels >= 1
 
 
+# The full-resolution Middlebury bucket (PERF.md §4): a 1988x2964 pair pads
+# to 2048x3008, a 512x752 field against 1,536 lane-padded pyramid columns.
+FULL_H, FULL_W = 2048, 3008
+
+
+def test_pallas_alt_lookup_at_full_resolution(one_chip, compiled_kernels):
+    """The served form at a 752-pixel row (rows cut to 64: a grid step
+    does not know how many there are): (8, 256) against 1,536 pyramid
+    columns are blocks Mosaic takes, and the kernel keeps the name and the
+    operands the benchmark's roofline readers find it by."""
+    from raftstereo_tpu.ops.corr import make_corr_fn
+
+    planes = LEVELS * (2 * RADIUS + 1)
+    w1 = FULL_W // 4
+
+    def lookup(f1, f2, coords, kernel, bias):
+        fn = make_corr_fn("pallas_alt", f1, f2, LEVELS, RADIUS,
+                          dtype=jnp.float32, precision="highest",
+                          out_dtype=BF16, out_channels=64,
+                          epilogue={"kernel": kernel, "bias": bias})
+        return fn(coords)
+
+    f = _sds(one_chip, (1, 64, w1, C), BF16)
+    compiled, kernels = _compile(
+        lookup, f, f, _sds(one_chip, (1, 64, w1, 1)),
+        _sds(one_chip, (1, 1, planes, 64), BF16), _sds(one_chip, (64,), BF16))
+    assert kernels == 1
+    (call,) = [ln for ln in compiled.as_text().splitlines()
+               if "tpu_custom_call" in ln]
+    assert "= bf16[64,768,64]" in call
+    assert "operand_layout_constraints={f32[64,768,256]" in call
+    assert "f32[64,1536,256]" in call and "alt_lookup_fwd" in call
+
+
+@pytest.mark.parametrize("hw", [(H, W), (FULL_H, FULL_W)],
+                         ids=["544x960", "2048x3008"])
 @pytest.mark.parametrize("which", ["feature_instance", "context_batch"])
-def test_fused_stem_and_layer2_stage(one_chip, compiled_kernels, which):
-    """The fused encoder stages at 544x960: conv1 + norm + layer1 (stem) and
+def test_fused_stem_and_layer2_stage(one_chip, compiled_kernels, which, hw):
+    """The fused encoder stages at 544x960 and at the full-resolution
+    bucket (where 32 rows a block no longer fit the kernels' VMEM and the
+    row block follows from the width, ops/pallas_norm._row_block): conv1 +
+    norm + layer1 (stem) and
     the stride-2 layer2 stage, in both norm forms the default model uses —
     instance norm with in-kernel statistics (feature encoder, both images
     of a pair) and frozen batch norm folded to affines (context encoder)."""
@@ -190,7 +229,7 @@ def test_fused_stem_and_layer2_stage(one_chip, compiled_kernels, which):
         enc.init, jax.random.key(0), jnp.zeros((1, 64, 64, 3), BF16))
     _, kernels = _compile(
         enc.apply, _tree_sds(one_chip, variables),
-        _sds(one_chip, (batch, H, W, 3), BF16))
+        _sds(one_chip, (batch, *hw, 3), BF16))
     assert kernels >= 8, kernels  # stem + layer2 are several launches each
 
 
