@@ -106,7 +106,8 @@ class ServeClient:
                  retry_statuses: Tuple[int, ...] = (502, 503),
                  wire_format: str = "binary",
                  response_encoding: str = "f32",
-                 compress: bool = True, compress_level: int = 1):
+                 compress: bool = True,
+                 compress_level: int = wire.LEVEL):
         self._conn = http.client.HTTPConnection(host, port, timeout=timeout)
         assert retries >= 0, retries
         assert wire_format in ("binary", "json"), wire_format
@@ -117,9 +118,10 @@ class ServeClient:
         self.wire_format = wire_format
         self.response_encoding = response_encoding
         self.compress = compress
-        # Level 1 by default: the shuffle filter does most of the ratio
-        # work (docs/wire_format.md "Compression"), and client-side CPU
-        # is the load generator's scarce resource.
+        # ``compress`` means "where it pays": the codec stores the tiles
+        # its sample says will not shrink and deflates the rest at
+        # ``wire.LEVEL``, the level the server's replies use too
+        # (docs/wire_format.md "Compression").
         self.compress_level = compress_level
         self.bytes_sent = 0
         self.bytes_received = 0

@@ -442,6 +442,15 @@ class ServeMetrics:
             "wire-bytes/pair SLO signal is out+in over "
             "serve_requests_total",
             labels=("direction", "format"))
+        self.wire_tiles = r.counter(
+            "serve_wire_tiles_total",
+            "compression tiles of binary /predict frames by direction "
+            "(in = request bodies, out = 200 replies) and coding "
+            "(stored = the encoder's sample did not shrink, so the "
+            "tile travels as a stored zlib stream; deflate = it did) "
+            "— how often the per-tile decision of wire/format.py "
+            "engages; raw (compress=false) planes count no tile",
+            labels=("direction", "coding"))
         self.wire_negotiations = r.counter(
             "wire_negotiations_total",
             "/predict format negotiations by resolved request dialect "

@@ -326,8 +326,8 @@ class _Handler(JsonRequestHandler):
         whole of it — encode and write — is the request's ``reply``
         phase; it closes in a ``finally``, so a client that hangs up
         mid-write still leaves the span."""
-        with self._req_phase(srv, "reply", rid):
-            self._reply_ok(srv, disparity, meta, endpoint, rid, t0)
+        with self._req_phase(srv, "reply", rid) as ph:
+            self._reply_ok(srv, disparity, meta, endpoint, rid, t0, ph)
 
     def _req_phase(self, srv: "StereoServer", name: str, rid: str,
                    parent_id: Optional[str] = None):
@@ -339,8 +339,21 @@ class _Handler(JsonRequestHandler):
             return timed_phase(name)
         return srv.tracer.phase(name, trace_id=tid, parent_id=parent_id)
 
+    @staticmethod
+    def _count_tiles(srv: "StereoServer", direction: str, ph,
+                     census: Dict[str, int]) -> None:
+        """A binary frame's tile census onto its phase's span
+        (``tiles_stored``, ``tiles_deflated``, ``bytes_raw``,
+        ``bytes_wire``) and into ``serve_wire_tiles_total``."""
+        ph.attrs.update(census)
+        for coding, key in (("stored", "tiles_stored"),
+                            ("deflate", "tiles_deflated")):
+            srv.metrics.wire_tiles.labels(
+                direction=direction, coding=coding).inc(census[key])
+
     def _reply_ok(self, srv: "StereoServer", disparity: np.ndarray,
-                  meta: Dict, endpoint: str, rid: str, t0: float) -> None:
+                  meta: Dict, endpoint: str, rid: str, t0: float,
+                  ph) -> None:
         ctx = self._wire_ctx
         if ctx is None:
             self._finish(200, {"disparity": encode_array(disparity),
@@ -348,9 +361,11 @@ class _Handler(JsonRequestHandler):
             return
         meta = dict(meta)
         meta["request_id"] = rid
-        frame = wire.encode_response(disparity, meta, **ctx)
+        with srv.reply_encode:
+            frame = wire.encode_response(disparity, meta, **ctx)
         srv.metrics.wire_bytes.labels(
             direction="out", format="binary").inc(len(frame))
+        self._count_tiles(srv, "out", ph, wire.tile_census(frame))
         srv.metrics.requests.labels(endpoint=endpoint, outcome="ok").inc()
         tid, parent = self._trace if self._trace is not None else (rid, None)
         srv.tracer.record("request", t0, time.perf_counter(), tid,
@@ -641,9 +656,9 @@ class _Handler(JsonRequestHandler):
         # the child can name it before the parent is recorded).
         self._adm_span = srv.tracer.new_span_id()
         with self._req_phase(srv, "wire_decode", rid,
-                             parent_id=self._adm_span):
+                             parent_id=self._adm_span) as ph:
             decoded = self._read_pair(srv, length, binary_in, binary_out,
-                                      endpoint, rid, t_req0)
+                                      endpoint, rid, t_req0, ph)
         if decoded is None:  # already answered
             return
         (left, right, iters, session_id, seq_no, deadline_ms, priority,
@@ -658,7 +673,7 @@ class _Handler(JsonRequestHandler):
 
     def _read_pair(self, srv: "StereoServer", length: int, binary_in: bool,
                    binary_out: bool, endpoint: str, rid: str,
-                   t_req0: float):
+                   t_req0: float, ph):
         """Read and decode one /predict body under a decode slot.
         Returns ``(left, right, iters, session_id, seq_no, deadline_ms,
         priority, accuracy, spatial)`` with the in-flight count taken
@@ -720,6 +735,7 @@ class _Handler(JsonRequestHandler):
                 return None
             try:
                 if binary_in:
+                    self._count_tiles(srv, "in", ph, dec.census())
                     req = dec.request()
                     # Mirror decode_array's contract: the engine always
                     # sees float32 (exact for uint8/int16 payloads).
@@ -1275,6 +1291,14 @@ class StereoServer(ThreadingHTTPServer):
         # queue on the semaphore instead of multiplying host RSS.
         self.decode_slots = threading.BoundedSemaphore(
             max(4, config.max_batch_size))
+        # One binary reply is encoded at a time (the write is outside).
+        # A batch's replies all become ready in the same millisecond,
+        # while the worker stages the next batch; the encode is a few
+        # ms of memory-bound host work that gains nothing from running
+        # eight at once, and eight at once cost the staging beside them
+        # 8 ms a dispatch (PERF.md §6, PR 29).  In turn they leave a few
+        # ms apart, and so do the requests that answer them.
+        self.reply_encode = threading.Lock()
         super().__init__((config.host, config.port), _Handler)
 
     @property

@@ -29,7 +29,9 @@ from .format import (
     FRAME_REQUEST,
     FRAME_RESPONSE,
     HEADER_SIZE,
+    LEVEL,
     MAGIC,
+    STORE_SHARE,
     VERSION,
     FrameDecoder,
     WireError,
@@ -41,6 +43,7 @@ from .format import (
     encode_request,
     encode_response,
     parse_header,
+    tile_census,
 )
 from .negotiate import (
     JSON_CONTENT_TYPE,
@@ -51,9 +54,10 @@ from .negotiate import (
 
 __all__ = [
     "FLAG_INT16", "FLAG_SHUFFLE", "FLAG_ZLIB", "FRAME_REQUEST",
-    "FRAME_RESPONSE", "HEADER_SIZE", "JSON_CONTENT_TYPE", "MAGIC",
-    "VERSION", "WIRE_CONTENT_TYPE", "FrameDecoder", "WireError",
-    "WireRequest", "WireResponse", "WireVersionError", "accepts_wire",
-    "decode_request", "decode_response", "encode_request",
+    "FRAME_RESPONSE", "HEADER_SIZE", "JSON_CONTENT_TYPE", "LEVEL", "MAGIC",
+    "STORE_SHARE", "VERSION", "WIRE_CONTENT_TYPE", "FrameDecoder",
+    "WireError", "WireRequest", "WireResponse", "WireVersionError",
+    "accepts_wire", "decode_request", "decode_response", "encode_request",
     "encode_response", "is_wire_content_type", "parse_header",
+    "tile_census",
 ]

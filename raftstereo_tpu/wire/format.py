@@ -23,16 +23,27 @@ Plane payload, per plane in order:
   ``u32 comp_len``, ``comp_len`` bytes of a complete zlib stream.
   Tiles partition the (possibly shuffled) plane bytes in order, at most
   ``TILE_BYTES`` raw bytes each — so a streaming decoder never stages
-  more than one compressed tile.
+  more than one compressed tile.  (This encoder also ends a tile where
+  a byte plane of a shuffled plane ends; a decoder need not know.)
 * otherwise: the raw (possibly shuffled) plane bytes.
+
+ZLIB means "deflated where it pays", decided tile by tile from the
+bytes: the encoder deflates a ``PROBE_BYTES`` sample of the tile and,
+unless the sample shrinks below ``STORE_SHARE`` of its size, writes the
+tile as a *stored* zlib stream (``zlib.compress(tile, 0)``: a copy and
+a checksum, ``comp_len`` = ``raw_len`` + ~100 B).  Sensor grain and
+float mantissas do not deflate, and deflating them anyway was most of
+a request's host time (docs/wire_format.md "Compression").  A stored
+tile is still a complete zlib stream, so every version-1 decoder reads
+it; a wrong guess costs bytes or milliseconds, never correctness.
+Tiles that do deflate use ``LEVEL`` in both directions.
 
 The SHUFFLE flag applies an HDF5-style byte-shuffle filter before
 compression: plane bytes are regrouped so all 0th bytes of each element
 come first, then all 1st bytes, etc.  Same-magnitude floats share
-exponent/high-mantissa bytes, so the grouped stream is far more
-zlib-compressible than interleaved float32 — measured ~3.3x vs ~2.6x
-for plain zlib on synthetic camera pairs.  Lossless: decode is a
-transpose.
+their exponent byte, so the filter turns a plane of which nothing
+deflates into low-mantissa byte planes that are stored and an exponent
+byte plane that deflates 30x and more.  Lossless: decode is a transpose.
 
 Float32 images whose values are exactly uint8-representable (the
 overwhelmingly common case — stereo cameras produce 8-bit intensities
@@ -55,9 +66,10 @@ import numpy as np
 __all__ = [
     "FLAG_INT16", "FLAG_SHUFFLE", "FLAG_ZLIB", "FRAME_REQUEST",
     "FRAME_RESPONSE", "HEADER_SIZE", "MAGIC", "TILE_BYTES", "VERSION",
-    "FrameDecoder", "WireError", "WireRequest", "WireResponse",
-    "WireVersionError", "decode_request", "decode_response",
-    "encode_request", "encode_response", "parse_header",
+    "LEVEL", "STORE_SHARE", "FrameDecoder", "WireError", "WireRequest",
+    "WireResponse", "WireVersionError", "decode_request",
+    "decode_response", "encode_request", "encode_response",
+    "parse_header", "tile_census",
 ]
 
 MAGIC = b"RSWF"
@@ -77,6 +89,17 @@ FLAG_SHUFFLE = 2  # byte-shuffle filter applied before compression
 FLAG_INT16 = 4    # response payload is int16 fixed-point (meta manifest)
 
 TILE_BYTES = 1 << 20  # raw bytes per compression tile
+# A tile is deflated only when a sample of it deflates below this share
+# of the sample's size; otherwise it is stored (docs/wire_format.md
+# "Compression" has the measurements the share was picked from).
+STORE_SHARE = 0.75
+# The sample: this many bytes in all, in _PROBE_SLICES evenly spaced
+# slices, so a tile that changes character half-way is seen both sides.
+PROBE_BYTES = 16 << 10
+_PROBE_SLICES = 4
+# Deflate level of every tile that is deflated, requests and replies
+# alike: after the shuffle the ratio is in the bytes, not in the search.
+LEVEL = 1
 
 # u8 dtype code -> numpy dtype.  The code describes the PAYLOAD bytes;
 # meta may direct a post-decode promotion (uint8 image -> float32).
@@ -124,11 +147,10 @@ class WireResponse:
 
 # --------------------------------------------------------------- filters
 
-def _shuffle(raw: bytes, itemsize: int) -> bytes:
-    if itemsize <= 1 or not raw:
-        return raw
-    a = np.frombuffer(raw, dtype=np.uint8).reshape(-1, itemsize)
-    return np.ascontiguousarray(a.T).tobytes()
+def _shuffle(a: np.ndarray) -> np.ndarray:
+    """Byte planes of ``a``, one row per byte of an element."""
+    return np.ascontiguousarray(
+        a.reshape(-1).view(np.uint8).reshape(-1, a.dtype.itemsize).T)
 
 
 def _unshuffle(raw: bytes, itemsize: int) -> bytes:
@@ -140,21 +162,37 @@ def _unshuffle(raw: bytes, itemsize: int) -> bytes:
 
 # --------------------------------------------------------------- encode
 
-def _encode_plane(raw: bytes, flags: int, level: int,
-                  itemsize: int) -> bytes:
-    if flags & FLAG_SHUFFLE:
-        raw = _shuffle(raw, itemsize)
+def _tile_stream(tile: np.ndarray, level: int) -> bytes:
+    """The tile as a complete zlib stream: deflated when a sample of it
+    deflates below ``STORE_SHARE`` of the sample's size, else stored."""
+    sample = tile
+    if len(tile) > PROBE_BYTES:
+        step = len(tile) // _PROBE_SLICES
+        each = PROBE_BYTES // _PROBE_SLICES
+        sample = b"".join(tile[i * step:i * step + each]
+                          for i in range(_PROBE_SLICES))
+    comp = zlib.compress(sample, level)
+    if len(comp) >= STORE_SHARE * len(sample):
+        return zlib.compress(tile, 0)
+    return comp if sample is tile else zlib.compress(tile, level)
+
+
+def _encode_plane(plane: np.ndarray, flags: int, level: int) -> list:
+    """The plane's payload as buffers for the frame's one ``join``.
+    A shuffled plane's tiles end where a byte plane ends, so no tile
+    mixes mantissa noise with exponent bytes."""
+    rows = (_shuffle(plane) if flags & FLAG_SHUFFLE
+            else plane.reshape(1, -1).view(np.uint8))
     if not flags & FLAG_ZLIB:
-        return raw
-    parts = []
-    tiles = range(0, len(raw), TILE_BYTES)
-    parts.append(struct.pack("<I", len(tiles)))
-    for off in tiles:
-        tile = raw[off:off + TILE_BYTES]
-        comp = zlib.compress(tile, level)
+        return [rows.data]
+    tiles = [row[off:off + TILE_BYTES] for row in rows
+             for off in range(0, len(row), TILE_BYTES)]
+    parts = [struct.pack("<I", len(tiles))]
+    for tile in tiles:
+        comp = _tile_stream(tile, level)
         parts.append(struct.pack("<II", len(tile), len(comp)))
         parts.append(comp)
-    return b"".join(parts)
+    return parts
 
 
 def _build_frame(frame_type: int, flags: int, dtype: np.dtype,
@@ -162,28 +200,31 @@ def _build_frame(frame_type: int, flags: int, dtype: np.dtype,
                  level: int) -> bytes:
     h, w = planes[0].shape[:2]
     meta_raw = json.dumps(meta, separators=(",", ":")).encode()
-    payload_parts = [
-        _encode_plane(np.ascontiguousarray(p, dtype=dtype).tobytes(),
-                      flags, level, dtype.itemsize)
-        for p in planes
-    ]
-    payload = b"".join(payload_parts)
+    parts = [part for p in planes
+             for part in _encode_plane(
+                 np.ascontiguousarray(p, dtype=dtype), flags, level)]
     header = _HEADER.pack(MAGIC, VERSION, frame_type, flags,
                           _DTYPE_CODES[dtype], channels, len(planes),
-                          h, w, len(meta_raw), len(payload))
-    return header + meta_raw + payload
+                          h, w, len(meta_raw),
+                          sum(memoryview(p).nbytes for p in parts))
+    return b"".join([header, meta_raw, *parts])
 
 
-def _uint8_exact(a: np.ndarray) -> bool:
-    """True when a float image is exactly a promoted 8-bit capture."""
+def _as_uint8(a: np.ndarray) -> Optional[np.ndarray]:
+    """The image as uint8 when it is exactly a promoted 8-bit capture
+    (float32 holding whole numbers 0..255), else None.  One cast and
+    one comparison: whatever the cast makes of a value outside that set
+    is a uint8, and so not equal to it."""
     if a.dtype != np.float32 or a.size == 0:
-        return False
-    return bool(np.all((a >= 0) & (a <= 255) & (a == np.floor(a))))
+        return None
+    with np.errstate(invalid="ignore"):  # NaN, inf: refused below
+        u = a.astype(np.uint8)
+    return u if np.array_equal(u, a) else None
 
 
 def encode_request(left: np.ndarray, right: np.ndarray,
                    fields: Optional[Dict] = None, *,
-                   compress: bool = True, level: int = 6,
+                   compress: bool = True, level: int = LEVEL,
                    shuffle: bool = True,
                    allow_uint8: bool = True) -> bytes:
     """Encode a stereo pair + /predict fields as one request frame.
@@ -203,11 +244,12 @@ def encode_request(left: np.ndarray, right: np.ndarray,
     if right.dtype != left.dtype:
         raise WireError("left/right dtype mismatch: "
                         f"{left.dtype} / {right.dtype}")
-    if allow_uint8 and _uint8_exact(left) and _uint8_exact(right):
-        dtype = np.dtype("u1")
-        left = left.astype(np.uint8)
-        right = right.astype(np.uint8)
-        meta["promote"] = "float32"
+    if allow_uint8:
+        u_left = _as_uint8(left)
+        u_right = _as_uint8(right) if u_left is not None else None
+        if u_right is not None:
+            dtype, left, right = np.dtype("u1"), u_left, u_right
+            meta["promote"] = "float32"
     if dtype.newbyteorder("<") not in _DTYPE_CODES:
         raise WireError(f"unsupported image dtype {dtype}")
     dtype = dtype.newbyteorder("<")
@@ -257,7 +299,7 @@ def _int16_manifest(d: np.ndarray) -> Optional[Tuple[np.ndarray, Dict]]:
 
 def encode_response(disparity: np.ndarray, meta: Optional[Dict] = None, *,
                     encoding: str = "f32", compress: bool = True,
-                    level: int = 6, shuffle: bool = True) -> bytes:
+                    level: int = LEVEL, shuffle: bool = True) -> bytes:
     """Encode one disparity plane as a response frame.
 
     ``encoding='f32'`` is bitwise; ``encoding='int16'`` quantizes to a
@@ -396,6 +438,7 @@ class FrameDecoder:
         self._tile_raw = 0
         self._payload_seen = 0
         self._payload_len = 0
+        self._tiles = [0, 0]  # deflated, stored
 
     # ------------------------------------------------------------- feed
     @property
@@ -461,6 +504,7 @@ class FrameDecoder:
             if self._plane_pos + self._tile_raw > self._plane_bytes:
                 raise WireError("tile overruns plane")
             self._check_payload_budget(comp_len)
+            self._tiles[comp_len >= self._tile_raw] += 1
             self._state = self._S_TILE_BODY
             self._need = comp_len
         elif self._state == self._S_TILE_BODY:
@@ -535,6 +579,10 @@ class FrameDecoder:
                 f"{self._payload_len}")
 
     # ----------------------------------------------------------- results
+    def census(self) -> Dict[str, int]:
+        """What ``tile_census`` says of the frame read so far."""
+        return _census(self.header, self._tiles)
+
     def _array(self, idx: int, shape: Tuple[int, ...]) -> np.ndarray:
         # View over the staging bytearray — no extra copy; promotion /
         # dequantization below copies only where it must.
@@ -582,6 +630,33 @@ class FrameDecoder:
             plane = plane.astype(np.float32)
         meta = self.meta.get("meta") or {}
         return WireResponse(plane, meta, manifest)
+
+
+def _census(header: Dict, tiles: List[int]) -> Dict[str, int]:
+    """``tiles`` counts by ``comp_len >= raw_len``: deflated, stored."""
+    return {"tiles_stored": tiles[1], "tiles_deflated": tiles[0],
+            "bytes_raw": header["plane_count"] * header["plane_bytes"],
+            "bytes_wire": header["payload_len"]}
+
+
+def tile_census(frame: bytes) -> Dict[str, int]:
+    """How an encoded frame's planes travel: ``tiles_stored`` and
+    ``tiles_deflated`` (a stored tile is one whose ``comp_len`` is not
+    under its ``raw_len``; both 0 for raw planes), ``bytes_raw`` (the
+    decoded planes) and ``bytes_wire`` (``payload_len``).  Hops from
+    tile header to tile header; inflates nothing."""
+    header = parse_header(frame[:HEADER_SIZE])
+    tiles = [0, 0]
+    if header["flags"] & FLAG_ZLIB:
+        pos = HEADER_SIZE + header["meta_len"]
+        for _ in range(header["plane_count"]):
+            (count,) = struct.unpack_from("<I", frame, pos)
+            pos += 4
+            for _ in range(count):
+                raw_len, comp_len = struct.unpack_from("<II", frame, pos)
+                tiles[comp_len >= raw_len] += 1
+                pos += 8 + comp_len
+    return _census(header, tiles)
 
 
 def _decode(buf: bytes, expect: int) -> FrameDecoder:

@@ -26,6 +26,7 @@ from raftstereo_tpu.serve import (BatchEngine, DynamicBatcher, Overloaded,
                                   run_load)
 
 from test_bench import REPO
+from test_wire import _tiles
 
 
 # ----------------------------------------------------------------- fixtures
@@ -571,6 +572,114 @@ class TestWireHTTP:
         finally:
             c32.close()
             c16.close()
+
+    def test_tiles_counted_and_one_level_both_ways(self, wire_server):
+        """One round trip whose request holds a plane that deflates and
+        one that does not: ``serve_wire_tiles_total`` counts both
+        codings, the ``wire_decode`` and ``reply`` spans carry the
+        frames' census, and wherever either side deflated a tile it
+        used the one level (docs/wire_format.md "Compression")."""
+        import zlib
+
+        from raftstereo_tpu import wire
+
+        server, metrics = wire_server
+        yy, xx = np.mgrid[0:60, 0:90].astype(np.float32)
+        shaded = np.stack([np.rint(xx + yy)] * 3, -1)  # deflates
+        grain = _img(60, 90, seed=12)                   # does not
+        cb = ServeClient("127.0.0.1", server.port, timeout=120)
+        assert cb.compress_level == wire.LEVEL
+        bodies = {}
+        inner = cb._request
+
+        def spy(method, path, body=None, headers=None):
+            status, resp, hdrs = inner(method, path, body, headers)
+            bodies.update(request=body, reply=resp)
+            return status, resp, hdrs
+
+        cb._request = spy
+        before = {lv: c.value for lv, c in metrics.wire_tiles.series()}
+        try:
+            disp, meta = cb.predict(shaded, grain)
+        finally:
+            cb.close()
+        sent = wire.tile_census(bodies["request"])
+        got = wire.tile_census(bodies["reply"])
+        assert sent["tiles_stored"] == 1 and sent["tiles_deflated"] == 1
+        # a float32 reply: one tile a byte plane, the exponent's deflated
+        assert got["tiles_stored"] + got["tiles_deflated"] == 4
+        assert got["tiles_deflated"] >= 1
+        assert got["bytes_raw"] == disp.nbytes
+        after = {lv: c.value for lv, c in metrics.wire_tiles.series()}
+        delta = {lv: after[lv] - before.get(lv, 0) for lv in after}
+        assert delta == {("in", "stored"): 1, ("in", "deflate"): 1,
+                         ("out", "stored"): got["tiles_stored"],
+                         ("out", "deflate"): got["tiles_deflated"]}
+        spans = {sp.name: sp.attrs for sp in
+                 server.tracer.spans(trace_id=meta["request_id"])}
+        assert {k: spans["wire_decode"][k] for k in sent} == sent
+        assert {k: spans["reply"][k] for k in got} == got
+        # the level class in a deflated tile's zlib header
+        want = zlib.compress(b"", wire.LEVEL)[:2]
+        assert want != zlib.compress(b"", 6)[:2]
+        for frame in bodies.values():
+            for raw_len, comp in _tiles(frame):
+                if len(comp) < raw_len:
+                    assert comp[:2] == want
+
+    def test_binary_replies_are_encoded_one_at_a_time(self, wire_server,
+                                                      monkeypatch):
+        """A batch's replies become ready together; the server encodes
+        them in turn (``StereoServer.reply_encode``), never side by
+        side, and every caller still gets its own answer."""
+        from raftstereo_tpu import wire
+
+        server, _ = wire_server
+        real, lock = wire.encode_response, threading.Lock()
+        seen = {"now": 0, "peak": 0, "calls": 0}
+
+        def slow_encode(*a, **kw):
+            with lock:
+                seen["now"] += 1
+                seen["calls"] += 1
+                seen["peak"] = max(seen["peak"], seen["now"])
+            time.sleep(0.03)        # long enough for the others to arrive
+            try:
+                return real(*a, **kw)
+            finally:
+                with lock:
+                    seen["now"] -= 1
+
+        monkeypatch.setattr(wire, "encode_response", slow_encode)
+        pairs = [(_img(60, 90, seed=20 + i), _img(60, 90, seed=30 + i))
+                 for i in range(4)]
+        out = [None] * 4
+
+        def call(i):
+            c = ServeClient("127.0.0.1", server.port, timeout=120)
+            try:
+                out[i] = c.predict(*pairs[i])[0]
+            finally:
+                c.close()
+
+        threads = [threading.Thread(target=call, args=(i,))
+                   for i in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        assert not any(t.is_alive() for t in threads)
+        assert seen["calls"] == 4 and seen["peak"] == 1, seen
+        assert all(o is not None and o.shape == (60, 90) for o in out)
+        # each caller got the answer to its own pair
+        c = ServeClient("127.0.0.1", server.port, timeout=120)
+        try:
+            monkeypatch.setattr(wire, "encode_response", real)
+            alone = c.predict(*pairs[2])[0]
+            np.testing.assert_allclose(out[2], alone, atol=1e-3)
+            assert np.abs(out[1] - alone).max() > 1e-2
+        finally:
+            c.close()
 
     def test_negotiation_matrix_never_500s(self, wire_server):
         """Binary in + JSON out (Accept without the wire type), bad

@@ -8,12 +8,14 @@ adversarially small chunks.
 
 import json
 import struct
+import zlib
 
 import numpy as np
 import pytest
 
 from raftstereo_tpu import wire
-from raftstereo_tpu.wire.format import SUPPORTED_VERSIONS, TILE_BYTES, _HEADER
+from raftstereo_tpu.wire.format import (PROBE_BYTES, SUPPORTED_VERSIONS,
+                                        TILE_BYTES, _HEADER)
 
 
 def _feed_chunked(buf, rng, expect):
@@ -26,6 +28,65 @@ def _feed_chunked(buf, rng, expect):
         pos += step
     assert dec.done
     return dec
+
+
+def _tiles(frame):
+    """(raw_len, zlib stream) of every tile of a ZLIB frame, read the way
+    docs/wire_format.md lays them out and with nothing of the codec's."""
+    (_, version, _, flags, _, _, planes, _, _, meta_len,
+     payload_len) = _HEADER.unpack(frame[:wire.HEADER_SIZE])
+    assert version == 1 and flags & wire.FLAG_ZLIB
+    pos = wire.HEADER_SIZE + meta_len
+    assert len(frame) == pos + payload_len
+    out = []
+    for _ in range(planes):
+        (count,) = struct.unpack_from("<I", frame, pos)
+        pos += 4
+        for _ in range(count):
+            raw_len, comp_len = struct.unpack_from("<II", frame, pos)
+            out.append((raw_len, frame[pos + 8:pos + 8 + comp_len]))
+            pos += 8 + comp_len
+    assert pos == len(frame)
+    return out
+
+
+def _parent_payload_bytes(planes, level=1):
+    """Payload bytes of the encoder before the per-tile decision: every
+    1-MiB tile of every plane deflated, whatever its bytes."""
+    n = 0
+    for p in planes:
+        raw = p.tobytes()
+        n += 4 + sum(8 + len(zlib.compress(raw[o:o + TILE_BYTES], level))
+                     for o in range(0, len(raw), TILE_BYTES))
+    return n
+
+
+def _grain(h, w, seed):
+    """Sensor grain: nothing of it deflates (an integer-valued float32
+    image, so it travels as uint8)."""
+    return np.random.default_rng(seed).integers(
+        0, 256, (h, w, 3)).astype(np.float32)
+
+
+def _camera(h, w, seed):
+    """A compressible synthetic capture: smooth shading in three
+    channels, quantised to 8 bits; deflates to about a third."""
+    yy, xx = np.mgrid[0:h, 0:w].astype(np.float32)
+    xx = xx + 5 * seed
+    base = 120 + 60 * np.sin(xx / 37) * np.cos(yy / 23) \
+        + 20 * np.sin((xx + yy) / 11)
+    img = np.stack([base, base * 0.9 + 10, base * 0.8 + 25], -1)
+    return np.clip(img, 0, 255).astype(np.uint8).astype(np.float32)
+
+
+def _smooth_exponent_field(h, w, seed):
+    """float32 whose low mantissa bytes are noise and whose exponent
+    byte hardly changes: a disparity field, as the shuffle sees it."""
+    rng = np.random.default_rng(seed)
+    yy, xx = np.mgrid[0:h, 0:w].astype(np.float32)
+    return (-(24 + 6 * np.sin(xx / 200) * np.cos(yy / 150))
+            + rng.standard_normal((h, w)).astype(np.float32) * 0.01
+            ).astype(np.float32)
 
 
 class TestHeader:
@@ -121,6 +182,20 @@ class TestRoundTrip:
         raw = left.nbytes + right.nbytes
         assert len(buf) < raw / 3.9
 
+    @pytest.mark.parametrize("odd", [np.nan, np.inf, -1.0, 256.0, 0.5,
+                                     1e20])
+    def test_one_value_outside_uint8_keeps_the_pair_float32(self, odd):
+        # Demotion is all or nothing, and bitwise either way: a single
+        # value that is not a whole number in 0..255 (in either image)
+        # keeps both planes float32 on the wire.
+        left, right = _grain(20, 30, 1), _grain(20, 30, 2)
+        right[7, 11, 2] = odd
+        buf = wire.encode_request(left, right)
+        assert _HEADER.unpack(buf[:32])[4] == 1  # dtype code: <f4
+        req = wire.decode_request(buf)
+        assert req.left.tobytes() == left.tobytes()
+        assert req.right.tobytes() == right.tobytes()
+
     def test_non_integer_floats_stay_float32(self):
         left = np.full((4, 4, 3), 0.5, np.float32)
         right = np.full((4, 4, 3), 1.5, np.float32)
@@ -158,6 +233,118 @@ class TestRoundTrip:
         buf = wire.encode_response(disp, {}, level=1)
         res = _feed_chunked(buf, rng, wire.FRAME_RESPONSE).response()
         assert res.disparity.tobytes() == disp.tobytes()
+
+
+class TestStoredTiles:
+    """The encoder deflates only the tiles that shrink (docs/
+    wire_format.md "Compression"); the rest are stored zlib streams."""
+
+    @pytest.mark.parametrize("feed", ["one_shot", "byte_at_a_time"])
+    def test_incompressible_plane_is_stored_and_bitwise(self, feed):
+        # 80x100x3 uint8 = 24,000 bytes a plane: over PROBE_BYTES, so
+        # the decision is made from the strided sample.
+        left, right = _grain(80, 100, 1), _grain(80, 100, 2)
+        assert left[..., 0].size * 3 > PROBE_BYTES
+        buf = wire.encode_request(left, right, {"iters": 4})
+        census = wire.tile_census(buf)
+        assert census == {"tiles_stored": 2, "tiles_deflated": 0,
+                          "bytes_raw": 2 * 24000,
+                          "bytes_wire": len(buf) - wire.HEADER_SIZE
+                          - _HEADER.unpack(buf[:32])[9]}
+        # a stored tile costs its bytes and a few of framing, no more
+        assert census["bytes_wire"] < census["bytes_raw"] + 64
+        dec = wire.FrameDecoder(expect=wire.FRAME_REQUEST)
+        if feed == "one_shot":
+            dec.feed(buf)
+        else:
+            for i in range(len(buf)):
+                dec.feed(buf[i:i + 1])
+        assert dec.done and dec.census() == census
+        req = dec.request()
+        assert req.left.dtype == np.float32
+        assert req.left.tobytes() == left.tobytes()
+        assert req.right.tobytes() == right.tobytes()
+
+    def test_stored_and_deflated_tiles_in_one_plane(self):
+        # Shuffled float32 over 4 MiB: the low mantissa byte planes are
+        # noise (stored), the exponent byte plane deflates.
+        disp = _smooth_exponent_field(1100, 1024, 3)
+        assert disp.nbytes > 4 * TILE_BYTES
+        buf = wire.encode_response(disp, {"iters": 32})
+        census = wire.tile_census(buf)
+        assert census["tiles_stored"] >= 2
+        assert census["tiles_deflated"] >= 1
+        assert census["bytes_wire"] < 0.8 * census["bytes_raw"]
+        rng = np.random.default_rng(5)
+        chunked = _feed_chunked(buf, rng, wire.FRAME_RESPONSE)
+        assert chunked.census() == census
+        for res in (wire.decode_response(buf), chunked.response()):
+            assert res.disparity.tobytes() == disp.tobytes()
+
+    def test_every_tile_is_a_plain_zlib_stream_at_version_1(self):
+        # The compatibility promise: a stored tile is an ordinary zlib
+        # stream, so a version-1 decoder that has never heard of the
+        # per-tile decision (here: bare zlib.decompress and a transpose)
+        # reads the new encoder's frames.
+        disp = _smooth_exponent_field(600, 1024, 4)
+        buf = wire.encode_response(disp, {})
+        assert wire.VERSION == 1 and SUPPORTED_VERSIONS == (1, 1)
+        version, flags = struct.unpack_from("<H", buf, 4)[0], buf[7]
+        assert version == 1
+        assert flags == wire.FLAG_ZLIB | wire.FLAG_SHUFFLE
+        tiles = _tiles(buf)
+        codings = {len(comp) >= raw_len for raw_len, comp in tiles}
+        assert codings == {True, False}, "want stored and deflated tiles"
+        planes = b"".join(zlib.decompress(comp) for _, comp in tiles)
+        assert [len(zlib.decompress(c)) for _, c in tiles] == \
+            [n for n, _ in tiles]
+        assert all(n <= TILE_BYTES and len(c) <= 2 * TILE_BYTES
+                   for n, c in tiles)
+        plain = np.frombuffer(planes, np.uint8).reshape(4, -1).T
+        assert plain.tobytes() == disp.tobytes()
+
+    def test_compressible_camera_pair_is_still_deflated(self):
+        left, right = _camera(400, 1000, 6), _camera(400, 1000, 7)
+        buf = wire.encode_request(left, right, {"iters": 4})
+        census = wire.tile_census(buf)
+        assert census["tiles_stored"] == 0 and census["tiles_deflated"] == 4
+        assert census["bytes_wire"] < 0.45 * census["bytes_raw"]
+        parent = _parent_payload_bytes(
+            [left.astype(np.uint8), right.astype(np.uint8)])
+        assert census["bytes_wire"] <= 1.02 * parent
+        req = wire.decode_request(buf)
+        assert req.left.tobytes() == left.tobytes()
+        assert req.right.tobytes() == right.tobytes()
+
+    def test_one_level_both_directions(self):
+        # Request and reply deflate at the same level, the module's:
+        # the zlib header of a deflated tile names its level class.
+        assert wire.LEVEL == 1
+        req = wire.encode_request(_camera(64, 96, 1), _camera(64, 96, 2))
+        res = wire.encode_response(np.zeros((64, 96), np.float32))
+        for frame in (req, res):
+            for raw_len, comp in _tiles(frame):
+                assert len(comp) < raw_len
+                assert comp[:2] == zlib.compress(b"x" * 64, wire.LEVEL)[:2]
+                assert comp[:2] != zlib.compress(b"x" * 64, 6)[:2]
+
+    def test_compress_false_still_means_raw_planes(self):
+        left, right = _grain(20, 30, 1), _grain(20, 30, 2)
+        buf = wire.encode_request(left, right, compress=False)
+        assert buf[7] & wire.FLAG_ZLIB == 0
+        assert wire.tile_census(buf) == {
+            "tiles_stored": 0, "tiles_deflated": 0,
+            "bytes_raw": 2 * 1800, "bytes_wire": 2 * 1800}
+        assert wire.decode_request(buf).left.tobytes() == left.tobytes()
+
+    def test_int16_reply_takes_the_same_decision(self):
+        disp = _smooth_exponent_field(700, 1024, 8)
+        buf = wire.encode_response(disp, {}, encoding="int16")
+        census = wire.tile_census(buf)
+        assert census["tiles_stored"] + census["tiles_deflated"] == 2
+        res = wire.decode_response(buf)
+        assert np.max(np.abs(res.disparity - disp)) \
+            <= res.manifest["err_bound"]
 
 
 class TestInt16Manifest:
@@ -248,17 +435,34 @@ class TestMalformedPayload:
         with pytest.raises(wire.WireError, match="meta"):
             wire.decode_response(bytes(buf))
 
-    def test_fuzz_truncations_never_complete_or_hang(self):
+    @staticmethod
+    def _fuzz_frame(coding):
+        """A request frame whose tiles are all of one coding: grain is
+        stored, floats of a few repeated values are deflated."""
+        if coding == "stored":
+            left, right = _grain(12, 18, 1), _grain(12, 18, 2)
+        else:
+            rng = np.random.default_rng(20260806)
+            left = np.rint(rng.standard_normal((12, 18, 3))
+                           ).astype(np.float32) + 0.5
+            right = left[::-1].copy()
+        buf = wire.encode_request(left, right, {"iters": 4},
+                                  compress=True)
+        census = wire.tile_census(buf)
+        mine, other = (("tiles_stored", "tiles_deflated")
+                       if coding == "stored"
+                       else ("tiles_deflated", "tiles_stored"))
+        assert census[mine] >= 2 and census[other] == 0, census
+        return buf
+
+    @pytest.mark.parametrize("coding", ["stored", "deflate"])
+    def test_fuzz_truncations_never_complete_or_hang(self, coding):
         # Chaos-plane contract: a frame cut at ANY byte boundary either
         # raises WireError (oversized claims, header damage) or leaves
         # the streaming decoder waiting for more bytes — it must never
         # report done on a prefix, which is what keeps a half-relayed
         # body from being handed to the engine as a frame.
-        rng = np.random.default_rng(20260806)
-        left = rng.standard_normal((12, 18, 3)).astype(np.float32)
-        right = rng.standard_normal((12, 18, 3)).astype(np.float32)
-        buf = wire.encode_request(left, right, {"iters": 4},
-                                  compress=True)
+        buf = self._fuzz_frame(coding)
         for cut in range(0, len(buf), 7):
             dec = wire.FrameDecoder(expect=wire.FRAME_REQUEST)
             try:
@@ -269,17 +473,16 @@ class TestMalformedPayload:
             with pytest.raises(wire.WireError, match="truncated"):
                 wire.decode_request(buf[:cut])
 
-    def test_fuzz_bitflips_raise_wire_error_or_decode(self):
+    @pytest.mark.parametrize("coding", ["stored", "deflate"])
+    def test_fuzz_bitflips_raise_wire_error_or_decode(self, coding):
         # Seeded single-bit corruption anywhere in the frame (the
         # router's corrupt_frame chaos hook does exactly this between
         # hops): the decoder must either raise WireError — the clean
         # 400 the serving stack relies on — or return a materializable
         # request.  Any other exception type would surface as a 500.
+        # A stored tile keeps its Adler-32, so it is held to the same.
         rng = np.random.default_rng(20260806)
-        left = rng.standard_normal((12, 18, 3)).astype(np.float32)
-        right = rng.standard_normal((12, 18, 3)).astype(np.float32)
-        buf = wire.encode_request(left, right, {"iters": 4},
-                                  compress=True)
+        buf = self._fuzz_frame(coding)
         rejected = 0
         for _ in range(120):
             i = int(rng.integers(0, len(buf)))
