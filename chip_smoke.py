@@ -98,7 +98,6 @@ def check_runtime(phase: str, rt: dict, count: int) -> None:
         f"kind={rt['device_kind']} count={rt['device_count']}")
     say(f"[{phase}] gates: corr={rt['corr']} auto->{rt['corr_auto']} "
         f"corr_matmul={rt['corr_matmul']} "
-        f"gru_backend={rt['gru_backend']} "
         f"fused_stem(cnet)={rt['fused_stem_cnet']} "
         f"fused_stem(fnet)={rt['fused_stem_fnet']} "
         f"pallas_interpret={rt['pallas_interpret']} "

@@ -29,7 +29,7 @@ from typing import List, Optional, Sequence
 from .core import (Finding, SourceFile, apply_baseline, format_finding,
                    iter_python_files, load_baseline, save_baseline)
 
-__all__ = ["Finding", "analyze", "apply_baseline", "baseline_entries",
+__all__ = ["Finding", "analyze", "apply_baseline",
            "default_baseline_path", "format_finding", "iter_python_files",
            "load_baseline", "save_baseline"]
 
@@ -45,12 +45,6 @@ def default_baseline_path() -> str:
         return env
     pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     return os.path.join(os.path.dirname(pkg), "analysis_baseline.txt")
-
-
-def baseline_entries(path: Optional[str] = None):
-    """The baseline multiset (empty Counter when the file is absent) —
-    bench.py's smoke modes refuse to run when this is non-empty."""
-    return load_baseline(path or default_baseline_path())
 
 
 def _ast_checkers():

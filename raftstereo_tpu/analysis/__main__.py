@@ -5,9 +5,7 @@ baselined; exit 1 on any NEW finding.  The default target is the
 ``raftstereo_tpu`` package and the default baseline is
 ``analysis_baseline.txt`` at the repo root (empty on the shipped tree).
 
-Tier-1 runs this via tests/test_analysis.py; ``bench.py`` smoke modes
-refuse to start while the baseline is dirty (known hazards must be fixed
-before perf rounds land on top of them).
+Tier-1 runs this via tests/test_analysis.py.
 """
 
 from __future__ import annotations

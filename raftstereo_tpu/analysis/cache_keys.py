@@ -21,7 +21,7 @@ method reaches the key:
   ``quant``, ``shards``, ``cascade`` or ``schedule`` — the inputs that
   select a distinct executable (shape inputs are carried by the bucket,
   which every key already starts from; ``backend`` covers
-  kernel-backend selectors like the fused-GRU ``gru_backend``,
+  kernel-backend selectors,
   ``accuracy``/``tier``/``quant`` the per-request accuracy tiers whose
   precision mode joins every serving key, serve/engine.py +
   ops/quant.py, ``shards`` the spatial mesh width — a 2-shard and a

@@ -74,17 +74,6 @@ class RAFTStereoConfig:
     # so evaluations comparing runs across device counts can pin the path.
     fused_encoder: Optional[bool] = None
 
-    # Test-mode GRU step backend (ops/pallas_gru.py).  "auto" resolves to
-    # the XLA reference step on every backend; "fused" is the Pallas
-    # megakernel (motion encoder + gru0 gates + flow head in one
-    # VMEM-resident kernel per iteration), which has only run in interpret
-    # mode — Mosaic has not lowered it at flagship size (PR 24);
-    # "fused"/"xla" pin one numeric path (the fused step matches the XLA step to fp32
-    # accumulation-order tolerance, not bitwise).  Train-mode tracing and
-    # device meshes always take the XLA step.  Serving executables are
-    # cache-keyed by the RESOLVED backend (serve/engine.py).
-    gru_backend: str = "auto"
-
     # Rematerialize each GRU iteration in the backward pass (jax.checkpoint
     # on the scan body): activation memory drops from O(iters) to O(1) at the
     # cost of one extra forward per iteration.  Required to fit the reference
@@ -111,9 +100,9 @@ class RAFTStereoConfig:
     # activations exceed one chip's HBM.  1 = the classic single-chip
     # forward.  A model-level default: ``ServeConfig.spatial_shards``
     # overrides it serverside, and the engine cache-keys every spatial
-    # executable by the resolved count.  v1 is XLA-GRU-only
-    # (parallel/spatial.validate_spatial_config rejects the fused
-    # megakernel, shared_backbone, group context norm and corr_quant).
+    # executable by the resolved count.  v1 refuses shared_backbone,
+    # group context norm and corr_quant
+    # (parallel/spatial.validate_spatial_config).
     spatial_shards: int = 1
 
     def __post_init__(self):
@@ -123,7 +112,6 @@ class RAFTStereoConfig:
             "auto", "reg", "alt", "pallas", "pallas_alt"), self.corr_implementation
         assert self.corr_precision in (
             "highest", "high", "default"), self.corr_precision
-        assert self.gru_backend in ("auto", "fused", "xla"), self.gru_backend
         assert self.input_mode in ("passive", "sl"), self.input_mode
         assert 1 <= self.n_gru_layers <= 3, self.n_gru_layers
         assert len(self.hidden_dims) >= self.n_gru_layers
@@ -540,11 +528,11 @@ class TierConfig:
 class ServeConfig:
     """Serving-layer parameters (serve/): dynamic micro-batching, the
     shape-bucketed compile cache, admission control and graceful
-    degradation.  Consumed by ``python -m raftstereo_tpu.cli.serve`` and by
-    ``bench.py --serve``; frozen + hashable like the other configs."""
+    degradation.  Consumed by ``python -m raftstereo_tpu.cli.serve``;
+    frozen + hashable like the other configs."""
 
     host: str = "127.0.0.1"
-    port: int = 8080  # 0 = ephemeral (tests/bench bind a free port)
+    port: int = 8080  # 0 = ephemeral (tests bind a free port)
 
     # Shape policy, shared bitwise with the Evaluator via
     # ops/image.BucketPadder: align to divis_by, round up to bucket_multiple.
@@ -1215,11 +1203,6 @@ def add_model_args(parser: argparse.ArgumentParser) -> None:
                         "per-row scales, int8 matmul + dequant epilogue; "
                         "ops/quant.py) — the 'turbo' serving tier's "
                         "numeric policy, inference only")
-    g.add_argument("--gru_backend", choices=["auto", "fused", "xla"],
-                   default="auto",
-                   help="test-mode GRU step backend: 'auto' = the XLA "
-                        "step; 'fused' = the Pallas megakernel, which "
-                        "has not lowered on a chip yet (ops/pallas_gru.py)")
     g.add_argument("--remat", action="store_true",
                    help="rematerialize each GRU iteration in backward: "
                         "O(1) activation memory instead of O(iters); "
@@ -1247,7 +1230,6 @@ def model_config_from_args(args: argparse.Namespace) -> RAFTStereoConfig:
         corr_dtype=args.corr_dtype,
         corr_precision=args.corr_precision,
         corr_quant=args.corr_quant,
-        gru_backend=args.gru_backend,
         remat=args.remat,
         input_mode=args.input_mode,
     )

@@ -4,7 +4,7 @@ The reference runs every augmentation op on the host inside torch DataLoader
 workers (reference: core/utils/augmentor.py:78-111 via core/stereo_datasets.py:311).
 That scales with host cores — and starves the chip when cores are scarce:
 the photometric chain (jitter + eraser) is roughly half the per-sample host
-cost measured on the KITTI (sparse-augmentor) pipeline of ``bench.py --data``. This module moves exactly that chain into
+cost measured on the KITTI (sparse-augmentor) pipeline. This module moves exactly that chain into
 the jitted training step, where it fuses with the input normalization and
 costs microseconds of TPU time; shape-changing work (decode, scale/stretch,
 flip, crop, sparse scatter) stays on the host, which is the natural split —

@@ -33,7 +33,7 @@ import dataclasses
 import json
 import logging
 import time
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -69,19 +69,27 @@ DEFAULT_CASCADE_BOUND = 0.5
 # itself swaps (compute_dtype/corr_dtype/corr_quant — config_for_mode
 # overrides those identically on both sides, so base-config differences
 # there are irrelevant to the tier programs).  Backend selectors with
-# "auto" resolution (corr_implementation, gru_backend, fused_encoder)
+# "auto" resolution (corr_implementation, fused_encoder)
 # are fingerprinted as the RAW config strings; their platform-dependent
 # resolution is covered by the separate platform check in tier_ok.
 ARCH_FIELDS = ("corr_levels", "corr_radius", "n_downsample", "n_gru_layers",
                "hidden_dims", "slow_fast_gru", "shared_backbone",
                "context_norm", "corr_implementation", "corr_precision",
-               "fused_encoder", "gru_backend", "input_mode")
+               "fused_encoder", "input_mode")
 
 
 def _arch_of(config) -> Dict[str, object]:
     d = dataclasses.asdict(config)
     return {k: (list(v) if isinstance(v, tuple) else v)
             for k, v in d.items() if k in ARCH_FIELDS}
+
+
+def _arch_mismatch(manifest: Dict, config) -> List[str]:
+    """The ARCH_FIELDS on which a manifest's model differs from
+    ``config``.  Only the fields this build fingerprints are compared, so
+    a manifest written when there were more of them still certifies."""
+    want, have = _arch_of(config), manifest.get("model") or {}
+    return sorted(k for k in want if have.get(k) != want[k])
 
 
 def _cert_data(config, hw: Tuple[int, int], n_pairs: int, seed: int):
@@ -247,11 +255,8 @@ def tier_ok(manifest: Optional[Dict], tier: str,
                            f"serving on {jax.default_backend()!r} — "
                            f"re-certify on this platform")
     if model_config is not None:
-        want = _arch_of(model_config)
-        have = manifest.get("model", {})
-        if have != want:
-            diff = sorted(k for k in want
-                          if have.get(k) != want[k])
+        diff = _arch_mismatch(manifest, model_config)
+        if diff:
             return False, (f"manifest certifies a different model "
                            f"architecture (mismatched: {diff})")
     return True, "certified"
@@ -391,8 +396,7 @@ def certify_cascades(config, variables, schedules: Sequence[str], *,
             "CERTIFIED" if entries[s.schedule]["certified"]
             else "OVER BOUND")
     if base is not None:
-        want = _arch_of(config)
-        assert base.get("model") == want, (
+        assert not _arch_mismatch(base, config), (
             "cannot merge cascade certificates into a manifest for a "
             "different model architecture")
         assert base.get("platform") == jax.default_backend(), (
@@ -444,10 +448,8 @@ def cascade_ok(manifest: Optional[Dict], schedule: str,
                            f"serving on {jax.default_backend()!r} — "
                            f"re-certify on this platform")
     if model_config is not None:
-        want = _arch_of(model_config)
-        have = manifest.get("model", {})
-        if have != want:
-            diff = sorted(k for k in want if have.get(k) != want[k])
+        diff = _arch_mismatch(manifest, model_config)
+        if diff:
             return False, (f"manifest certifies a different model "
                            f"architecture (mismatched: {diff})")
     return True, "certified"

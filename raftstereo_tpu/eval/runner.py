@@ -12,8 +12,7 @@ near-identical sizes — the same policy the serving engine
 Replaces the per-image boilerplate of the reference evaluators
 (reference: evaluate_stereo.py:28-36,70-83): pad -> forward(test_mode) ->
 unpad, plus wall-clock timing of the compiled step.  Timing spans the host
-fetch of the output: a host fetch proves execution finished (same
-protocol as bench.py).
+fetch of the output: a host fetch proves execution finished.
 """
 
 from __future__ import annotations

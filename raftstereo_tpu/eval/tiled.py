@@ -88,8 +88,7 @@ def plan_geometry(h: int, w: int, tile_hw: Tuple[int, int], overlap: int,
     """The exact tile plan ``tiled_infer`` executes for an (h, w) image:
     (th, tw, ys, xs, ph, pw) — rounded tile shape, start offsets, padded
     image shape.  One home for the rounding/stride/clamp rules so callers
-    reporting tile counts (bench.py --tiled) can never drift from what
-    actually runs."""
+    reporting tile counts can never drift from what actually runs."""
     th = min(-(-tile_hw[0] // 32) * 32, -(-h // 32) * 32)
     tw = min(-(-tile_hw[1] // 32) * 32, -(-w // 32) * 32)
     ph, pw = max(h, th), max(w, tw)

@@ -18,8 +18,7 @@ SLO", in four pieces:
                  one machine-readable JSON verdict.
 * ``capacity`` — requests/s/chip as f(tier, iters, resolution), fit
                  from a replay; feeds ``ops/autoscale.Autoscaler`` and
-                 answers what-ifs via ``cli.loadgen`` / ``bench.py
-                 --slo``.
+                 answers what-ifs via ``cli.loadgen``.
 
 ``records`` (the row store) and ``capacity`` are stdlib-only — they
 are imported by client tooling and the model-free router's autoscaler.

@@ -135,7 +135,7 @@ def summarize(rows: Sequence[RequestRow], *, mode: str, requests: int,
     """The legacy ``run_load`` stats dict, computed from rows.
 
     Key set and presence conditions are the historical contract
-    (bench.py, cli.serve --loadgen and their tests consume it):
+    (cli.serve --loadgen and its tests consume it):
     percentiles only when ok rows exist; ``late_sends``/
     ``send_lag_p99_ms`` only for rate-driven traffic; ``warm_frames``/
     ``cold_frames``/``sequence_len`` only under sequence replay.

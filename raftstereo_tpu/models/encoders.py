@@ -129,11 +129,7 @@ def _trunk_layer2(enc, x):
             # Frozen BN folds to constant prep affines, exactly like the
             # stem stage (pallas_encoder.bn_affine); stage order:
             # norm1, projection norm, norm2, layer2_1.norm1/norm2.
-            from ..ops import pallas_layer2 as _pl2
-            from ..ops.pallas_encoder import bn_affine, fused_stem_forced
-            if not (_pl2._fused_layer2_bn_enabled
-                    or fused_stem_forced(enc.fused_stem)):
-                return enc.layer2_1(enc.layer2_0(x))
+            from ..ops.pallas_encoder import bn_affine
             affines = [
                 bn_affine(m.variables["params"], m.variables["batch_stats"])
                 for m in (enc.layer2_0.norm1, enc.layer2_0.downsample_norm,

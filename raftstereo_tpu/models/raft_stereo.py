@@ -33,7 +33,7 @@ from ..ops.image import coords_grid_x
 from ..ops.upsample import convex_upsample
 from .encoders import BasicEncoder, MultiBasicEncoder
 from .layers import ResidualBlock, conv
-from .update import BasicMultiUpdateBlock, _interp_to
+from .update import BasicMultiUpdateBlock
 
 
 # The ``jax.named_scope`` stages of the served step, as a device trace's
@@ -256,16 +256,7 @@ class RAFTStereo:
         zqr_list = self.zqr.apply(self._split_vars(variables, "zqr"), inp_list)
         return net_list, zqr_list, fmap1, fmap2
 
-    def _use_fused_gru(self, test_mode: bool) -> bool:
-        """Whether this trace takes the fused GRU megakernel step
-        (ops/pallas_gru.py) — resolved once per forward and threaded
-        through ``_corr_setup`` and ``_step_body`` so the lookup policy
-        and the step body always agree."""
-        from ..ops.pallas_gru import use_fused_gru
-        return use_fused_gru(self.config.gru_backend, test_mode)
-
-    def _corr_setup(self, update_vars: Dict, test_mode: bool,
-                    fused: bool = False):
+    def _corr_setup(self, update_vars: Dict, test_mode: bool):
         """Static correlation-lookup policy shared by the monolithic and
         phase-split forwards: the volume dtype, the int8-quant gate,
         whether the motion encoder's convc1 is fused into the lookup
@@ -288,10 +279,7 @@ class RAFTStereo:
         # forward), while fp32's module conv runs at flax default precision
         # — a different rounding than any Mosaic-loweable policy — and fp32
         # is the certified-parity path, which must keep one numeric form.
-        # The fused GRU step subsumes the epilogue (convc1 runs inside the
-        # megakernel, which reads the correlation features exactly once),
-        # so it asks the lookup for RAW features instead.
-        use_epi = (test_mode and not fused and self.dtype == jnp.bfloat16
+        use_epi = (test_mode and self.dtype == jnp.bfloat16
                    and corr_epilogue_active(cfg.corr_implementation, quant))
         epi = (update_vars["params"]["encoder"]["convc1"] if use_epi
                else None)
@@ -302,68 +290,15 @@ class RAFTStereo:
         return corr_dtype, use_epi, epi, -(-cfg.cor_planes // 64) * 64, quant
 
     def _step_body(self, update_vars: Dict, zqr_list, corr_fn, grid,
-                   test_mode: bool, use_epi: bool, fused: bool = False,
-                   out_channels: int = 0, quant: bool = False):
+                   test_mode: bool, use_epi: bool):
         """The per-iteration refinement body, identical between the
         monolithic ``forward`` scan and the scheduler's single-iteration
         step executable (``forward_step``) — sharing the code is what
-        makes the two paths bitwise-comparable.
-
-        ``fused`` swaps the finest level (motion encoder + gru0 + flow
-        head) for the Pallas megakernel step (ops/pallas_gru.py); the
-        coarser GRU levels keep the module path — they run at 1/4 and
-        1/16 of the finest level's pixel count and update FIRST, exactly
-        as in the module's coarsest->finest call order, so the kernel
-        consumes the same upsampled coarser state the module would."""
+        makes the two paths bitwise-comparable."""
         cfg = self.config
         dtype = self.dtype
         sf = cfg.slow_fast_gru
         n = cfg.n_gru_layers
-
-        if fused:
-            assert test_mode, "fused GRU step is test-mode only"
-            from ..ops.corr import resolve_implementation
-            from ..ops.pallas_gru import fused_update, pack_update_params
-            # The width the lookup actually emits: the pallas_alt backend
-            # zero-pads to the lane-friendly ``out_channels`` (from the
-            # caller's _corr_setup — the SAME call that built corr_fn);
-            # every other backend returns the natural cor_planes.
-            corr_width = (out_channels
-                          if resolve_implementation(cfg.corr_implementation,
-                                                    quant)
-                          == "pallas_alt" else cfg.cor_planes)
-            ext_dim = cfg.hidden_dims[1] if n > 1 else 0
-            wpack = pack_update_params(update_vars["params"], corr_width,
-                                       ext_dim, dtype)
-            cz0, cr0, cq0 = zqr_list[0]
-
-            def fused_step(carry, _):
-                nets, d = carry
-                d = jax.lax.stop_gradient(d)
-                with jax.named_scope("lookup"):
-                    corr = corr_fn(grid + d)
-                nets = list(nets)
-                with jax.named_scope("gru"):
-                    if n == 3 and sf:
-                        nets = self.update.apply(
-                            update_vars, nets, zqr_list, iter2=True,
-                            iter1=False, iter0=False, update=False)
-                    if n >= 2 and sf:
-                        nets = self.update.apply(
-                            update_vars, nets, zqr_list, iter2=(n == 3),
-                            iter1=True, iter0=False, update=False)
-                    if n >= 2:
-                        nets = self.update.apply(
-                            update_vars, nets, zqr_list, iter2=(n == 3),
-                            iter1=True, iter0=False, update=False)
-                    ext = (_interp_to(nets[1], nets[0]) if n > 1 else None)
-                    hnew, delta = fused_update(nets[0], ext, corr, d,
-                                               cz0, cr0, cq0, wpack)
-                nets[0] = hnew
-                d = d + delta[..., :1].astype(jnp.float32)
-                return (tuple(nets), d), None
-
-            return fused_step
 
         def step(carry, _):
             nets, d = carry
@@ -404,16 +339,15 @@ class RAFTStereo:
 
     def forward(self, variables: Dict, image1: jax.Array, image2: jax.Array,
                 iters: int = 12, flow_init: Optional[jax.Array] = None,
-                test_mode: bool = False, unroll: int = 1):
+                test_mode: bool = False):
         cfg = self.config
         b = image1.shape[0]
 
         net_list, zqr_list, fmap1, fmap2 = self._encode(variables, image1,
                                                         image2)
         update_vars = self._split_vars(variables, "update")
-        fused = self._use_fused_gru(test_mode)
         corr_dtype, use_epi, epi, out_channels, quant = self._corr_setup(
-            update_vars, test_mode, fused)
+            update_vars, test_mode)
         with jax.named_scope("corr_build"):
             corr_fn = make_corr_fn(cfg.corr_implementation, fmap1, fmap2,
                                    cfg.corr_levels, cfg.corr_radius,
@@ -430,18 +364,10 @@ class RAFTStereo:
             disp = disp + flow_init.astype(jnp.float32)
 
         step = self._step_body(update_vars, zqr_list, corr_fn, grid,
-                               test_mode, use_epi, fused=fused,
-                               out_channels=out_channels, quant=quant)
+                               test_mode, use_epi)
         body = jax.checkpoint(step) if cfg.remat else step
-        # ``unroll`` feeds lax.scan's unroll factor.  Perf-neutral by default
-        # (1); bench.py's FLOP accounting compiles fully-unrolled variants
-        # because XLA's cost model counts a rolled loop body ONCE regardless
-        # of trip count (verified: scan of a matmul reports identical flops
-        # for length 1/4/16), so per-iteration flops are only observable
-        # unrolled.
         (nets, disp), ys = jax.lax.scan(
-            body, (tuple(net_list), disp), None, length=iters,
-            unroll=unroll)
+            body, (tuple(net_list), disp), None, length=iters)
         if test_mode:
             with jax.named_scope("upsample"):
                 mask = self.update.apply(update_vars, nets[0],
@@ -506,9 +432,8 @@ class RAFTStereo:
         scheduler's single-iteration step executable; test-mode only)."""
         cfg = self.config
         update_vars = self._split_vars(variables, "update")
-        fused = self._use_fused_gru(test_mode=True)
         _, use_epi, epi, out_channels, quant = self._corr_setup(
-            update_vars, test_mode=True, fused=fused)
+            update_vars, test_mode=True)
         with jax.named_scope("corr_build"):
             corr_fn = corr_fn_from_state(cfg.corr_implementation,
                                          state["corr"], cfg.corr_levels,
@@ -522,8 +447,7 @@ class RAFTStereo:
         b, h0, w0 = disp.shape[:3]
         grid = coords_grid_x(b, h0, w0)
         step = self._step_body(update_vars, state["zqr"], corr_fn, grid,
-                               test_mode=True, use_epi=use_epi, fused=fused,
-                               out_channels=out_channels, quant=quant)
+                               test_mode=True, use_epi=use_epi)
         (nets, disp), _ = jax.lax.scan(step, (tuple(state["nets"]), disp),
                                        None, length=iters)
         return dict(state, nets=tuple(nets), disp=disp)

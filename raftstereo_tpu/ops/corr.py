@@ -420,14 +420,14 @@ def make_pallas_alt_corr_fn(fmap1: jax.Array, fmap2: jax.Array,
     return corr_fn
 
 
-# A/B toggle for the fused convc1 epilogue (scripts/ab_corr_epilogue.py
-# flips it in one process; tests pin the fused == unfused numerics).
+# Off switch for the fused convc1 epilogue: tests/test_pallas_alt.py runs
+# the unfused form as the reference the fused one is held to.
 corr_epilogue_enabled = True
 
 
 def resolve_implementation(implementation: str, quant: bool = False) -> str:
     """'auto' -> the fastest backend for the active platform.  The ONE
-    resolver — make_corr_fn, corr_epilogue_active, and bench.py must agree,
+    resolver — make_corr_fn and corr_epilogue_active must agree,
     or the model could set corr_preact for a backend that ignores the
     epilogue (skipping convc1 on raw features entirely).
 

@@ -76,7 +76,7 @@ def avg_pool2x(x: jax.Array) -> jax.Array:
     """
     # Plain-python 0.0 init (weak-typed): a concrete bf16 zero constant here
     # breaks linearization when the surrounding computation is differentiated
-    # inside a lax.fori_loop body (bench --train hits this).
+    # inside a lax.fori_loop body.
     s = jax.lax.reduce_window(
         x, 0.0, jax.lax.add,
         window_dimensions=(1, 3, 3, 1), window_strides=(1, 2, 2, 1),

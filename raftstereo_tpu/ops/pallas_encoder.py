@@ -6,7 +6,7 @@ churn — every cross-(H,W) reduction forces ~4 full-tensor relayouts of
 135 MB each between the convs' space-to-depth blocked layouts and the
 reduce's, and NO XLA-side formulation escapes it (lane-packed views,
 direct/fp32 reduces, MXU ones-vector matmuls, 128-channel padding ALL
-measured 27-62 ms; scripts/mb_encoder.py).
+measured 27-62 ms on an earlier chip).
 
 The fix is to own the stage end-to-end in Pallas so every tensor stays in
 row-major (B, H, W, C):
@@ -26,7 +26,7 @@ row-major (B, H, W, C):
 * dy taps read halo rows (built by cheap strided row slices, 2 rows per
   block); dx taps are resolved post-matmul by rolling the accumulated
   output one packed column and masking the wrap (operands stay
-  contiguous — the data-stationary formulation from scripts/mb_gru_kernel).
+  contiguous: weights shift, never activations).
 
 Semantics are exactly BasicEncoder's stem + layer1 (conv1-norm1-relu,
 two ResidualBlocks; reference: core/extractor.py:122-197 structure) with
@@ -58,17 +58,6 @@ from .pallas_norm import _row_block
 # another thread must not see this thread's gate (the train step's
 # override_fused_stem(False) is load-bearing for training numerics).
 _tls = threading.local()
-
-# Conv1 dot structure: True folds the 7 dy row taps into the contraction
-# (one big-K dot, 2 nearly-full MXU K-passes) instead of 7 small-K dots
-# whose 30/36-deep contractions fill 23-28% of the MXU's 128 K-rows.
-# Measured (scripts/ab_conv1_bigk.py, alternating same-process pairs at
-# flagship b1): ratios 0.96 / 1.00 vs the 7-dot form — a wash; the r4
-# pre-shift restructure already brought the kernel to ~1.1 ms for 3
-# images (round-5 trace) and the operand concat eats the MXU saving.
-# Committed negative result; default stays on the simpler 7-dot form.
-_conv1_bigk = False
-
 
 def make_override_scope(tls, attr):
     """(getter, contextmanager) pair over a thread-local override slot.
@@ -679,25 +668,13 @@ def _stem7_kernel(x_ref, xh_ref, w_ref, b_ref, y_ref, *stat_refs,
                 [zc[:, :, :(-o)], full[:, :, :o]], axis=2))
     xcat = jnp.concatenate(shifts, axis=-1)         # (1, R+6, Wp, 30)
     wcat = w.reshape(7, 5 * w.shape[2], w.shape[3])
-    if _conv1_bigk:
-        # Fold the 7 dy taps into the contraction too: ONE K=210 dot (2
-        # MXU K-passes at ~82% fill) instead of 7 K=30 dots (7 passes at
-        # 23% fill) — the dy row slices are free (dim 1 is neither lane
-        # nor sublane), so the operand build costs only the lane concat.
-        xbig = jnp.concatenate([xcat[:, dyi:dyi + rows] for dyi in range(7)],
-                               axis=-1)             # (1, R, Wp, 210)
-        y = jax.lax.dot_general(
-            xbig, wcat.reshape(7 * wcat.shape[1], wcat.shape[2]),
+    y = None
+    for dyi in range(7):
+        m = jax.lax.dot_general(
+            xcat[:, dyi:dyi + rows], wcat[dyi],
             (((3,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-    else:
-        y = None
-        for dyi in range(7):
-            m = jax.lax.dot_general(
-                xcat[:, dyi:dyi + rows], wcat[dyi],
-                (((3,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            y = m if y is None else y + m
+        y = m if y is None else y + m
     y = y + b_ref[...][:, :, None, :]
     y_ref[...] = y.astype(y_ref.dtype)
     _acc_stats(y, stat_refs)
@@ -754,25 +731,14 @@ def _stem7s2_kernel(x_ref, xh_ref, w_ref, b_ref, y_ref, *stat_refs,
     view = xcat.reshape(1, rows + 3, 2, xcat.shape[2], xcat.shape[3])
     w = w_ref[...]                                  # (7, 3, 12, 128)
     wcat = w.reshape(7, 3 * w.shape[2], w.shape[3])  # dq-major, like xcat
-    if _conv1_bigk:
-        # Same dy-fold as _stem7_kernel: one K=252 dot (2 nearly-full
-        # K-passes) instead of 7 K=36 dots.
-        xbig = jnp.concatenate(
-            [view[:, dyi // 2:dyi // 2 + rows, dyi % 2]
-             for dyi in range(7)], axis=-1)          # (1, R, Wq, 252)
-        y = jax.lax.dot_general(
-            xbig, wcat.reshape(7 * wcat.shape[1], wcat.shape[2]),
+    y = None
+    for dyi in range(7):
+        e, par = divmod(dyi, 2)
+        m = jax.lax.dot_general(
+            view[:, e:e + rows, par], wcat[dyi],
             (((3,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-    else:
-        y = None
-        for dyi in range(7):
-            e, par = divmod(dyi, 2)
-            m = jax.lax.dot_general(
-                view[:, e:e + rows, par], wcat[dyi],
-                (((3,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            y = m if y is None else y + m
+        y = m if y is None else y + m
     y = y + b_ref[...][:, :, None, :]
     y_ref[...] = y.astype(y_ref.dtype)
     _acc_stats(y, stat_refs)

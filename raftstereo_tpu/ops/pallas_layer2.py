@@ -46,9 +46,6 @@ from .pallas_corr import _COMPILER_PARAMS, _interpret
 from .pallas_norm import _row_block
 from .pallas_encoder import make_override_scope, pack_view
 
-# A/B toggle (scripts/ab_layer2.py flips it in one process).
-_fused_layer2_enabled = True
-
 # Thread-local trace scope, like pallas_encoder.override_fused_stem: the
 # train step forces this stage OFF under differentiation (its backward
 # re-linearizes the full XLA layer2 forward — the exact pattern measured
@@ -62,12 +59,6 @@ _tls = threading.local()
 # still re-linearizes the XLA stage, a measured training loss).
 _get_l2_override, override_fused_layer2 = make_override_scope(
     _tls, "fused_layer2_override")
-
-# Sub-gate for the frozen-BN (constant-affine) variant on top of the main
-# layer2 gate: lets the batch-norm branch (context encoder / realtime
-# trunk) be A/B'd and, if need be, shipped independently of the
-# instance-norm stage (scripts/ab_layer2_bn.py).
-_fused_layer2_bn_enabled = True
 
 
 # ------------------------------------------------------------- weights
@@ -532,8 +523,6 @@ def use_fused_layer2(norm_fn, stride, shape, override=None) -> bool:
     <=4-images crossover; auto also requires ONE visible device — a bare
     pallas_call cannot be GSPMD-partitioned, and a user jitting with
     explicit shardings must keep the plain XLA stage."""
-    if not _fused_layer2_enabled:
-        return False
     if norm_fn not in ("instance", "batch") or stride != 2 or shape[2] % 2:
         return False
     if shape[1] % 2:

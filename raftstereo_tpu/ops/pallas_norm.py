@@ -1,6 +1,6 @@
 """Pallas TPU instance-norm: layout-preserving stats + apply kernels.
 
-Why this exists (measured, scripts/mb_encoder.py + a device trace): at
+Why this exists (measured on an earlier chip, with a device trace): at
 the feature encoder's hot shape
 (272x480x64 bf16) EVERY XLA formulation of the cross-(H,W) reduction —
 lane-packed view, direct reduce, fp32 reduce, even MXU ones-vector

@@ -1,8 +1,9 @@
 """Int8 quantized correlation + the serving accuracy-tier vocabulary.
 
-The round-5 perf work left the GRU/head convs at the measured MXU ceiling,
-so the remaining arithmetic-intensity lever is precision.  This module supplies the numeric core of the quantized serving
-fast path (docs/perf_notes_r07.md):
+The arithmetic-intensity lever that is left once the convolutions are at
+their ceiling is precision.  This module supplies the numeric core of the
+quantized serving fast path (what a tier earns on the chip is not
+measured: no benchmark cell runs one):
 
 * **symmetric int8 row quantization** of the left/right feature maps.  One
   scale per correlation ROW (each (b, h, w) feature vector — the matmul
@@ -16,7 +17,7 @@ fast path (docs/perf_notes_r07.md):
   kernel (MXU-native int8 pass, 4x the bf16 multiply rate).  Both paths
   apply the identical epilogue expression, so the kernel is
   bitwise-comparable to the XLA path in interpret mode
-  (tests/test_quant.py, mirroring tests/test_pallas_gru.py).
+  (tests/test_quant.py).
 * **the accuracy-tier vocabulary** shared by the serving engine, the
   certification harness (eval/certify.py) and the HTTP layer:
   per-request ``accuracy`` tiers resolve to a *precision mode* that joins

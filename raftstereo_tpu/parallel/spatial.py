@@ -50,11 +50,7 @@ neighbourhood, one halo row.
 
 v1 scope (validated in ``validate_spatial_config``):
 
-* XLA GRU step only (``gru_backend="xla"``; "auto" is accepted where it
-  resolves to XLA).  The Pallas megakernel (ops/pallas_gru.py) is a bare
-  ``pallas_call`` that cannot run under ``shard_map`` today — the sharded
-  megakernel is the documented follow-up (ROADMAP.md).  Likewise the
-  Pallas corr backends remap to their XLA twins (pallas -> reg,
+* The Pallas corr backends remap to their XLA twins (pallas -> reg,
   pallas_alt -> alt: same math, different kernels), and the plain conv
   flow head / plain stem are always used — so on TPU the spatial path's
   numerics match the CPU certified-parity path, not the single-chip TPU
@@ -116,8 +112,6 @@ def spatial_row_multiple(cfg: RAFTStereoConfig) -> int:
 def validate_spatial_config(cfg: RAFTStereoConfig) -> None:
     """Reject configs the v1 sharded forward does not cover (module
     docstring).  Cheap and pure — admission calls it per request."""
-    from ..ops.pallas_gru import use_fused_gru
-
     if cfg.shared_backbone:
         raise SpatialShardingUnsupported(
             "spatial sharding does not support shared_backbone")
@@ -129,11 +123,6 @@ def validate_spatial_config(cfg: RAFTStereoConfig) -> None:
         raise SpatialShardingUnsupported(
             "spatial sharding does not support the int8 corr volume "
             "(corr_quant); use an unquantized config")
-    if use_fused_gru(cfg.gru_backend, test_mode=True):
-        raise SpatialShardingUnsupported(
-            "spatial sharding is XLA-GRU only in v1: set gru_backend=xla "
-            "(the fused megakernel is a bare pallas_call and cannot be "
-            "partitioned under shard_map)")
 
 
 def check_spatial_shape(cfg: RAFTStereoConfig, shards: int, h: int,

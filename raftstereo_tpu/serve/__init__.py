@@ -35,8 +35,8 @@ Layers, bottom-up:
 * ``client``                — blocking client + closed/open-loop load
                               generator.
 
-Entry point: ``python -m raftstereo_tpu.cli.serve``; smoke benchmark:
-``python bench.py --serve --quick``.
+Entry point: ``python -m raftstereo_tpu.cli.serve``; the benchmark that
+measures it on the chip is ``benchmark/run.py`` (PERF.md).
 
 Video streams ride the same engine: ``/predict`` with ``session_id``/
 ``seq_no`` warm-starts each frame from the session's previous disparity

@@ -37,9 +37,8 @@ __all__ = ["ServeClient", "ServeError", "run_load", "synthetic_pair_pool"]
 
 def synthetic_pair_pool(height: int, width: int, n: int = 4, seed: int = 0):
     """``make_pair`` callable over a pool of ``n`` pre-generated random
-    pairs — request cost stays in the server, not in host-side RNG.
-    Shared by ``cli.serve --loadgen`` and ``bench.py --serve`` so the two
-    load paths drive identical synthetic traffic."""
+    pairs — request cost stays in the server, not in host-side RNG
+    (``cli.serve --loadgen``'s traffic)."""
     rng = np.random.default_rng(seed)
     pool = [(rng.integers(0, 255, (height, width, 3)).astype(np.float32),
              rng.integers(0, 255, (height, width, 3)).astype(np.float32))

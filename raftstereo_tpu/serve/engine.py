@@ -38,7 +38,6 @@ import numpy as np
 from ..config import ServeConfig
 from ..obs.trace import timed_phase
 from ..ops.image import BucketPadder
-from ..ops.pallas_gru import resolve_gru_backend
 from ..ops.quant import MODES, config_for_mode, default_mode
 from .metrics import ServeMetrics
 
@@ -78,17 +77,6 @@ class BatchEngine:
         self.variables = variables
         self.cfg = config
         self.metrics = metrics
-        # Resolved test-mode GRU step backend ("fused" Pallas megakernel
-        # or the "xla" reference step, ops/pallas_gru.py) — a MODE
-        # component of every executable cache key: the two backends
-        # compile different programs with different numerics, so a key
-        # that omitted it could serve one backend's executable to the
-        # other's request.  Resolved once per engine (platform + config
-        # are fixed for the engine's lifetime); immutable thereafter.
-        # (model=None: replica-lifecycle test stubs never dispatch — the
-        # reference backend keeps their keys well-formed.)
-        self.gru_backend = ("xla" if model is None
-                            else resolve_gru_backend(model.config))
         # Precision modes (ops/quant.py): every executable key carries the
         # resolved mode ("fp32"/"bf16"/"int8") as its LAST component — the
         # per-request ``accuracy`` tier compiles a different program with
@@ -145,14 +133,16 @@ class BatchEngine:
         # must not block behind _lock, which is held across a whole device
         # dispatch (seconds) or compile (minutes).
         self._stats_lock = threading.Lock()
-        # Compiled keys: (h, w, iters, gru_backend, input_mode, mode) for
-        # the plain forward and (h, w, iters, "stream", gru_backend,
-        # input_mode, mode) for the warm-start (flow_init) forward.
-        # Spatial keys are arity 8: (h, w, iters, "spatial", "sN",
-        # gru_backend, input_mode, mode) — the shard count rides as the
-        # STRING "sN" at position 4 so the mixed-arity key set stays
-        # sortable (ints at 0-2, strings from 3 on; /healthz sorts the
-        # whole set for a stable compiled_buckets listing).
+        # Compiled keys.  Position 3 names the KIND of program in every
+        # key, and nothing reads a kind from a key's length:
+        # (h, w, iters, "batch", input_mode, mode) for the plain forward,
+        # (h, w, iters, "stream", input_mode, mode) for the warm-start
+        # (flow_init) forward, (h, w, iters, "spatial", "sN", input_mode,
+        # mode) for the sharded one — the shard count rides as the
+        # STRING "sN" so the mixed-arity key set stays sortable (ints at
+        # 0-2, strings from 3 on; /healthz sorts the whole set for a
+        # stable compiled_buckets listing) — and the sched_* / cascade_*
+        # phases below.
         self._compiled: Set[Tuple] = set()  # guarded_by: _stats_lock
         self.last_batch_runtime: float = float("nan")  # guarded_by: _lock
         self.last_included_compile: bool = True  # guarded_by: _lock
@@ -205,7 +195,7 @@ class BatchEngine:
         ``fused_stages``); nothing for the other kinds of key, whose
         programs see other batches (stream, sched) or other paths
         (spatial, cascade)."""
-        if self.model is None or len(key) != 6:
+        if self.model is None or key[3] != "batch":
             return {}
         from ..utils.platform import describe_program
         return describe_program(self.model.config, self.cfg.max_batch_size,
@@ -217,14 +207,14 @@ class BatchEngine:
         lookup kernel's blocks and which encoder stages run fused."""
         return {"x".join(str(k) for k in key): self._program_facts(key)
                 for key in sorted(k for k in self.compiled_keys
-                                  if len(k) == 6)}
+                                  if k[3] == "batch")}
 
     def is_warm(self, hw: Tuple[int, int], iters: int,
                 mode: Optional[str] = None) -> bool:
         """Whether (bucket, iters, mode) already has a compiled
         executable."""
         with self._stats_lock:
-            return (hw[0], hw[1], iters, self.gru_backend, self.input_mode,
+            return (hw[0], hw[1], iters, "batch", self.input_mode,
                     self._mode(mode)) in self._compiled
 
     def is_stream_warm(self, hw: Tuple[int, int], iters: int,
@@ -232,8 +222,8 @@ class BatchEngine:
         """Whether (bucket, iters, mode) has a compiled WARM-START
         executable."""
         with self._stats_lock:
-            return (hw[0], hw[1], iters, "stream", self.gru_backend,
-                    self.input_mode, self._mode(mode)) in self._compiled
+            return (hw[0], hw[1], iters, "stream", self.input_mode,
+                    self._mode(mode)) in self._compiled
 
     # ------------------------------------------------------ spatial sharding
 
@@ -273,7 +263,7 @@ class BatchEngine:
         n = self._spatial_shard_count(shards)
         with self._stats_lock:
             return (hw[0], hw[1], iters, "spatial", f"s{n}",
-                    self.gru_backend, self.input_mode,
+                    self.input_mode,
                     self._mode(mode)) in self._compiled
 
     def low_hw(self, hw: Tuple[int, int]) -> Tuple[int, int]:
@@ -287,12 +277,11 @@ class BatchEngine:
         session migration (``SessionStore.export_state``/``import_state``):
         two engines may exchange warm-start state only when the 1/f grid
         (``factor``) and the executables that will consume it
-        (``input_mode``, ``gru_backend``) agree.  Pure metadata — no
-        device work, no compiles."""
+        (``input_mode``) agree.  Pure metadata — no device work, no
+        compiles."""
         cfg = getattr(self.model, "config", None)
         return {"factor": getattr(cfg, "factor", None),
-                "input_mode": self.input_mode,
-                "gru_backend": self.gru_backend}
+                "input_mode": self.input_mode}
 
     # -------------------------------------------------------- precision modes
 
@@ -464,7 +453,7 @@ class BatchEngine:
         every requested precision mode (``modes``; default = the base
         config's mode only) so a warmed accuracy tier never compiles
         under traffic either.  Returns the
-        (h, w, iters, gru_backend, input_mode, mode) keys warmed.
+        (h, w, iters, "batch", input_mode, mode) keys warmed.
         """
         buckets = list(buckets or self.cfg.buckets)
         # sorted, not set-ordered: the default {iters, degraded_iters} set
@@ -478,7 +467,7 @@ class BatchEngine:
             bh, bw = self.bucket_of((h, w, self.input_channels))
             for iters in iters_list:
                 for mode in modes:
-                    key = (bh, bw, iters, self.gru_backend,
+                    key = (bh, bw, iters, "batch",
                            self.input_mode, mode)
                     # is_warm, not a bare `in self._compiled`: membership
                     # is guarded by _stats_lock (RSA301).
@@ -499,7 +488,7 @@ class BatchEngine:
         level, mode) before serving streams, so the adaptive controller
         can move between levels mid-stream without ever stalling a session
         behind an XLA compile.  Returns the (h, w, iters, "stream",
-        gru_backend, input_mode, mode) keys warmed."""
+        input_mode, mode) keys warmed."""
         buckets = list(buckets or self.cfg.buckets)
         modes = list(modes or [self.default_mode])
         warmed = []
@@ -509,8 +498,7 @@ class BatchEngine:
             # ``warmup`` (the ladder is descending by construction).
             for iters in sorted(ladder):
                 for mode in modes:
-                    key = (bh, bw, iters, "stream", self.gru_backend,
-                           self.input_mode, mode)
+                    key = (bh, bw, iters, "stream", self.input_mode, mode)
                     if self.is_stream_warm((bh, bw), iters, mode):
                         continue
                     zero = np.zeros((h, w, self.input_channels), np.float32)
@@ -588,13 +576,11 @@ class BatchEngine:
         ``(host_outputs, included_compile)`` — the flag is per-call, not
         read back from shared engine state, so concurrent callers cannot
         race each other's compile accounting."""
-        kind = ("stream" if "stream" in key
-                else "spatial" if "spatial" in key else "batch")
-        # tier = the key's precision-mode component (always last): a
-        # compile under traffic must be attributable to the tier whose
-        # warmup missed it.
+        # mode = the key's kind (always at position 3); tier = its
+        # precision-mode component (always last): a compile under traffic
+        # must be attributable to the tier whose warmup missed it.
         labels = dict(bucket=f"{key[0]}x{key[1]}", iters=str(key[2]),
-                      mode=kind, tier=key[-1])
+                      mode=key[3], tier=key[-1])
         if self.fault_plan is not None:
             # slow_replica chaos: sleep BEFORE taking the engine lock so
             # the injected latency models a slow device, not a convoy —
@@ -659,7 +645,7 @@ class BatchEngine:
         single-mode."""
         padders, hw, i1, i2, _ = self._pad_pairs(pairs)
         m = self._mode(mode)
-        key = (hw[0], hw[1], iters, self.gru_backend, self.input_mode, m)
+        key = (hw[0], hw[1], iters, "batch", self.input_mode, m)
         (flow_up,), _ = self._dispatch(
             key, lambda: [self._fn(iters, m)(self.variables, i1, i2)[1]])
         return [padder.unpad(flow_up[i:i + 1])[0, ..., 0]
@@ -700,8 +686,7 @@ class BatchEngine:
             if pad_rows:
                 fi = jnp.pad(fi, ((0, pad_rows), (0, 0), (0, 0), (0, 0)))
         m = self._mode(mode)
-        key = (hw[0], hw[1], iters, "stream", self.gru_backend,
-               self.input_mode, m)
+        key = (hw[0], hw[1], iters, "stream", self.input_mode, m)
         (low, up), miss = self._dispatch(
             key, lambda: self._stream_fn(iters, m)(self.variables, i1, i2,
                                                    fi))
@@ -746,8 +731,7 @@ class BatchEngine:
             fi = jnp.asarray(flow_init)[None, :, :, None]
         self._seg.pad = (t_pad0, time.perf_counter())
         m = self._mode(mode)
-        key = (hw[0], hw[1], iters, "spatial", f"s{n}", self.gru_backend,
-               self.input_mode, m)
+        key = (hw[0], hw[1], iters, "spatial", f"s{n}", self.input_mode, m)
         (low, up), miss = self._dispatch(
             key, lambda: self._spatial_fn(iters, m)(self.variables, i1, i2,
                                                     fi))
@@ -760,7 +744,7 @@ class BatchEngine:
         """Compile the spatial executables for every configured spatial
         bucket before serving, so a 4K request never pays the (largest
         possible) XLA compile under traffic.  Returns the (h, w, iters,
-        "spatial", "sN", gru_backend, input_mode, mode) keys warmed."""
+        "spatial", "sN", input_mode, mode) keys warmed."""
         n = self._spatial_shard_count(None)
         buckets = list(buckets if buckets is not None
                        else getattr(self.cfg, "spatial_buckets", ()) or ())
@@ -772,7 +756,7 @@ class BatchEngine:
             for iters in iters_list:
                 for mode in modes:
                     key = (bh, bw, iters, "spatial", f"s{n}",
-                           self.gru_backend, self.input_mode, mode)
+                           self.input_mode, mode)
                     if self.is_spatial_warm((bh, bw), iters, mode):
                         continue
                     zero = np.zeros((h, w, self.input_channels), np.float32)
@@ -790,21 +774,20 @@ class BatchEngine:
     # The phase executables behind serve/sched/ (docs/serving.md): the
     # split forward runs as prologue -> step x N -> epilogue, with the
     # carried state device-resident between boundaries.  All four phases
-    # live in the same compile cache under arity-7 keys
-    # (h, w, iters_per_step, phase, gru_backend, input_mode, mode) —
+    # live in the same compile cache under keys
+    # (h, w, iters_per_step, phase, input_mode, mode) —
     # iters_per_step is 0 for the phases it cannot affect — so /healthz,
     # the RSA401 checker and the warmup accounting see them like every
     # other executable.
 
     def _sched_keys(self, hw: Tuple[int, int], iters_per_step: int,
                     mode: Optional[str] = None) -> List[Tuple]:
-        g = self.gru_backend
         im = self.input_mode
         m = self._mode(mode)
-        return [(hw[0], hw[1], 0, "sched_prologue", g, im, m),
-                (hw[0], hw[1], iters_per_step, "sched_step", g, im, m),
-                (hw[0], hw[1], 0, "sched_epilogue", g, im, m),
-                (hw[0], hw[1], 0, "sched_join", g, im, m)]
+        return [(hw[0], hw[1], 0, "sched_prologue", im, m),
+                (hw[0], hw[1], iters_per_step, "sched_step", im, m),
+                (hw[0], hw[1], 0, "sched_epilogue", im, m),
+                (hw[0], hw[1], 0, "sched_join", im, m)]
 
     def is_sched_warm(self, hw: Tuple[int, int], iters_per_step: int,
                       mode: Optional[str] = None) -> bool:
@@ -904,8 +887,7 @@ class BatchEngine:
         """
         hw, i1, i2, fi = self._sched_assemble(pairs, flow_inits, slots)
         m = self._mode(mode)
-        key = (hw[0], hw[1], 0, "sched_prologue", self.gru_backend,
-               self.input_mode, m)
+        key = (hw[0], hw[1], 0, "sched_prologue", self.input_mode, m)
         state, miss = self._dispatch_state(
             key, lambda: self._sched_prologue_fn(m)(self.variables, i1, i2,
                                                     fi))
@@ -917,7 +899,7 @@ class BatchEngine:
         GRU iterations); returns ``(state, included_compile)``."""
         m = self._mode(mode)
         key = (hw[0], hw[1], iters_per_step, "sched_step",
-               self.gru_backend, self.input_mode, m)
+               self.input_mode, m)
         return self._dispatch_state(
             key, lambda: self._sched_step_fn(iters_per_step, m)(
                 self.variables, state))
@@ -933,8 +915,7 @@ class BatchEngine:
             mk = jnp.asarray(mask, bool)
         assert mk.shape == (self.cfg.max_batch_size,), mk.shape
         m = self._mode(mode)
-        key = (hw[0], hw[1], 0, "sched_join", self.gru_backend,
-               self.input_mode, m)
+        key = (hw[0], hw[1], 0, "sched_join", self.input_mode, m)
         return self._dispatch_state(
             key, lambda: self._sched_join_fn()(running, incoming, mk))
 
@@ -945,8 +926,7 @@ class BatchEngine:
         included_compile)`` — the scheduler unpads per leaving slot
         (``padder_of``)."""
         m = self._mode(mode)
-        key = (hw[0], hw[1], 0, "sched_epilogue", self.gru_backend,
-               self.input_mode, m)
+        key = (hw[0], hw[1], 0, "sched_epilogue", self.input_mode, m)
         (low, up), miss = self._dispatch_state(
             key, lambda: self._sched_epilogue_fn(m)(self.variables, state))
         return (np.asarray(low, np.float32), np.asarray(up, np.float32),
@@ -994,8 +974,8 @@ class BatchEngine:
     # tier's for the last K iterations.  Four cascade-specific phases —
     # dual prologue (cheap state + staged certified state), stage join,
     # handoff (cast + corr swap + lane gather) and the divergence delta —
-    # under arity-8 keys (h, w, 0, phase, gru_backend, input_mode,
-    # cheap_mode, cert_mode): every cascade executable is keyed by BOTH
+    # under keys (h, w, 0, phase, input_mode, cheap_mode, cert_mode):
+    # every cascade executable is keyed by BOTH
     # precision modes (ints at 0-2, strings from 3 on, so the mixed-arity
     # key set stays sortable for /healthz).  The cheap/certified step and
     # epilogue executables are the UNMODIFIED per-mode sched phases — a
@@ -1013,13 +993,12 @@ class BatchEngine:
     def _cascade_keys(self, hw: Tuple[int, int],
                       cheap_mode: Optional[str] = None,
                       cert_mode: Optional[str] = None) -> List[Tuple]:
-        g = self.gru_backend
         im = self.input_mode
         cm, xm = self._cascade_pair(cheap_mode, cert_mode)
-        return [(hw[0], hw[1], 0, "cascade_prologue", g, im, cm, xm),
-                (hw[0], hw[1], 0, "cascade_stage_join", g, im, cm, xm),
-                (hw[0], hw[1], 0, "cascade_handoff", g, im, cm, xm),
-                (hw[0], hw[1], 0, "cascade_delta", g, im, cm, xm)]
+        return [(hw[0], hw[1], 0, "cascade_prologue", im, cm, xm),
+                (hw[0], hw[1], 0, "cascade_stage_join", im, cm, xm),
+                (hw[0], hw[1], 0, "cascade_handoff", im, cm, xm),
+                (hw[0], hw[1], 0, "cascade_delta", im, cm, xm)]
 
     def is_cascade_warm(self, hw: Tuple[int, int], iters_per_step: int,
                         cheap_mode: Optional[str] = None,
@@ -1049,8 +1028,7 @@ class BatchEngine:
         until the handoff swaps its corr in."""
         hw, i1, i2, fi = self._sched_assemble(pairs, flow_inits, slots)
         cm, xm = self._cascade_pair(cheap_mode, cert_mode)
-        key = (hw[0], hw[1], 0, "cascade_prologue", self.gru_backend,
-               self.input_mode, cm, xm)
+        key = (hw[0], hw[1], 0, "cascade_prologue", self.input_mode, cm, xm)
         (state, stage), miss = self._dispatch_state(
             key, lambda: self._cascade_prologue_fn(cm, xm)(
                 self.variables, i1, i2, fi))
@@ -1068,8 +1046,7 @@ class BatchEngine:
             mk = jnp.asarray(mask, bool)
         assert mk.shape == (self.cfg.max_batch_size,), mk.shape
         cm, xm = self._cascade_pair(cheap_mode, cert_mode)
-        key = (hw[0], hw[1], 0, "cascade_stage_join", self.gru_backend,
-               self.input_mode, cm, xm)
+        key = (hw[0], hw[1], 0, "cascade_stage_join", self.input_mode, cm, xm)
         return self._dispatch_state(
             key, lambda: self._sched_join_fn()(running, incoming, mk))
 
@@ -1093,8 +1070,7 @@ class BatchEngine:
         with self._device_ctx():
             idx = jnp.asarray(slot_map)
         cm, xm = self._cascade_pair(cheap_mode, cert_mode)
-        key = (hw[0], hw[1], 0, "cascade_handoff", self.gru_backend,
-               self.input_mode, cm, xm)
+        key = (hw[0], hw[1], 0, "cascade_handoff", self.input_mode, cm, xm)
         return self._dispatch_state(
             key, lambda: self._cascade_handoff_fn(cm, xm)(state, stage,
                                                           idx))
@@ -1107,8 +1083,7 @@ class BatchEngine:
         input (serve/cascade/policy.py).  Returns ``((B,) float32,
         included_compile)``."""
         cm, xm = self._cascade_pair(cheap_mode, cert_mode)
-        key = (hw[0], hw[1], 0, "cascade_delta", self.gru_backend,
-               self.input_mode, cm, xm)
+        key = (hw[0], hw[1], 0, "cascade_delta", self.input_mode, cm, xm)
         (deltas,), miss = self._dispatch(
             key, lambda: [self._cascade_delta_fn()(prev_disp, disp)])
         return deltas, miss

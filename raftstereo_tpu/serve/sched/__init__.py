@@ -14,8 +14,7 @@ batching style.
 * ``scheduler`` — the running-batch state machine, admission control and
                   the scheduling worker thread.
 
-Enable with ``--sched`` on ``python -m raftstereo_tpu.cli.serve``;
-smoke benchmark: ``python bench.py --sched --quick``.
+Enable with ``--sched`` on ``python -m raftstereo_tpu.cli.serve``.
 """
 
 from .policy import PRIORITIES, priority_class, should_exit  # noqa: F401

@@ -1201,7 +1201,7 @@ class StereoServer(ThreadingHTTPServer):
     """HTTP server owning the engine + batcher + metrics + tracer.
 
     ``config.port == 0`` binds an ephemeral port; read the real one from
-    ``server.server_address[1]`` (tests and ``bench.py --serve`` do).
+    ``server.server_address[1]`` (tests do).
     """
 
     daemon_threads = True

@@ -16,8 +16,8 @@ accuracy.  Layers, bottom-up:
 * ``runner``     — ``StreamRunner`` (frame stepper over the serve
                    ``BatchEngine``'s warm-start executables) plus the
                    offline ``run_sequence``/``compare_warm_cold`` harness
-                   shared by ``cli/stream.py``, ``bench.py --stream`` and
-                   the acceptance tests.
+                   shared by ``cli/stream.py`` and the acceptance
+                   tests.
 * ``tier``       — durable session tier: a model-free shared store for
                    session snapshots (``cli.sessiontier`` service +
                    ``TierClient`` + the backends' write-behind
@@ -27,8 +27,7 @@ accuracy.  Layers, bottom-up:
 
 Entry points: ``python -m raftstereo_tpu.cli.stream`` (offline sequence
 runner), session-aware ``/predict`` (``session_id``/``seq_no``) on
-``python -m raftstereo_tpu.cli.serve``; smoke benchmark:
-``python bench.py --stream --quick``.
+``python -m raftstereo_tpu.cli.serve``.
 """
 
 from .controller import AdaptiveIterController  # noqa: F401

@@ -12,10 +12,10 @@ session path (serve/server.py) and the offline ``cli/stream.py`` runner
 produce bitwise-identical disparities on the same frames (tested).
 
 ``run_sequence`` / ``compare_warm_cold`` are the offline evaluation
-harness shared by ``cli/stream.py``, ``bench.py --stream`` and the tier-1
-acceptance tests: warm-start streaming vs a cold-start full-iteration
-baseline on the same frames, reporting EPE, temporal-consistency EPE, and
-the iterations/latency saved.
+harness shared by ``cli/stream.py`` and the tier-1 acceptance tests:
+warm-start streaming vs a cold-start full-iteration baseline on the same
+frames, reporting EPE, temporal-consistency EPE, and the
+iterations/latency saved.
 """
 
 from __future__ import annotations
@@ -325,8 +325,8 @@ def compare_warm_cold(engine, frames: Sequence[Tuple],
                       stream_cfg: StreamConfig, metrics=None,
                       tracer=None) -> Dict:
     """Warm-start streaming vs the cold full-iteration baseline on the same
-    frames; the summary is what ``cli/stream.py`` and ``bench.py --stream``
-    report and what the acceptance test asserts."""
+    frames; the summary is what ``cli/stream.py`` reports and what the
+    acceptance test asserts."""
     # Cold first: it compiles only ladder[0]; the warm pass then adds the
     # warm levels, so each pass's first-frame compile flags are honest.
     cold = run_sequence(engine, frames, stream_cfg, warm=False,

@@ -35,11 +35,12 @@ STATE_VERSION = 1
 
 # The engine-level keys of the state-schema fingerprint.  Two stores may
 # exchange warm state only when these agree: ``factor`` fixes the 1/f
-# grid ``prev_disp_low`` lives on, ``input_mode``/``gru_backend`` fix
-# which executables the state feeds (a bucket served by one engine and
-# not the other simply re-buckets cold at the next frame, so the bucket
-# itself rides along informationally, not as a hard gate).
-_SCHEMA_KEYS = ("factor", "input_mode", "gru_backend")
+# grid ``prev_disp_low`` lives on, ``input_mode`` fixes which executables
+# the state feeds (a bucket served by one engine and not the other simply
+# re-buckets cold at the next frame, so the bucket itself rides along
+# informationally, not as a hard gate).  Only these keys are compared: a
+# snapshot whose schema carries more (an older build's) imports warm.
+_SCHEMA_KEYS = ("factor", "input_mode")
 
 # Fixed accounted overhead of one session beyond the disparity plane:
 # the controller scalars carried across frames (next_seq, frame_idx,

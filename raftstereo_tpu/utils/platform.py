@@ -98,7 +98,6 @@ def describe_runtime(config, batch: int, hw: Sequence[int]) -> Dict:
     from ..ops.corr import resolve_implementation
     from ..ops.pallas_alt import resolve_corr_matmul
     from ..ops.pallas_corr import _interpret
-    from ..ops.pallas_gru import resolve_gru_backend
 
     devices = jax.devices()
     stages = describe_program(config, batch, hw)["fused_stages"]
@@ -117,7 +116,6 @@ def describe_runtime(config, batch: int, hw: Sequence[int]) -> Dict:
                                             config.corr_dtype,
                                             config.corr_precision)
                         if corr == "pallas_alt" else None),
-        "gru_backend": resolve_gru_backend(config),
         "fused_stem_cnet": stages["stem_cnet"],
         "fused_stem_fnet": stages["stem_fnet"],
         "pallas_interpret": bool(_interpret()),

@@ -17,7 +17,6 @@ Two halves:
 """
 
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -28,7 +27,7 @@ from raftstereo_tpu.analysis import (analyze, apply_baseline,
 from raftstereo_tpu.analysis.__main__ import main as analysis_main
 from raftstereo_tpu.analysis.retrace_guard import RetraceBudgetExceeded
 
-from test_bench import REPO
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "analysis_fixtures")
@@ -262,24 +261,6 @@ class TestRunner:
         table = capsys.readouterr().out
         for code in ("RSA101", "RSA201", "RSA301", "RSA401", "RSA501"):
             assert code in table
-
-    def test_bench_smoke_refuses_dirty_baseline(self, tmp_path,
-                                                monkeypatch):
-        """bench.py smoke modes must not measure on top of known
-        hazards: a non-empty baseline refuses before any model work."""
-        dirty = tmp_path / "baseline.txt"
-        dirty.write_text(
-            "RSA301 raftstereo_tpu/serve/engine.py BatchEngine.warmup\n")
-        monkeypatch.setenv("RAFTSTEREO_ANALYSIS_BASELINE", str(dirty))
-        if REPO not in sys.path:
-            sys.path.insert(0, REPO)
-        import bench
-
-        monkeypatch.setattr(sys, "argv", ["bench.py", "--serve",
-                                          "--quick"])
-        with pytest.raises(SystemExit) as ei:
-            bench.main()
-        assert "baseline" in str(ei.value)
 
 
 # ---------------------------------------------------------- retrace guard

@@ -299,6 +299,24 @@ class TestCertification:
         ok, reason = cascade_ok(loaded, SCHEDULE, other)
         assert not ok and "different model" in reason
 
+    def test_a_parent_builds_manifest_still_certifies(
+            self, cascade_manifest, cascade_model):
+        """The cascade twin of the tier gate: a manifest whose model
+        block still carries an older build's ``gru_backend`` certifies
+        its schedules, and its mismatches are still named."""
+        from raftstereo_tpu.config import RAFTStereoConfig as RC
+        from raftstereo_tpu.eval.certify import cascade_ok
+
+        model, _ = cascade_model
+        old = dict(cascade_manifest,
+                   model=dict(cascade_manifest["model"],
+                              gru_backend="auto"))
+        assert cascade_ok(old, SCHEDULE, model.config) \
+            == (True, "certified")
+        ok, reason = cascade_ok(old, SCHEDULE,
+                                RC(**dict(TINY, corr_levels=4)))
+        assert not ok and "['corr_levels']" in reason
+
     def test_resolve_cascades_without_manifest_refuses_all(self):
         from raftstereo_tpu.eval.certify import resolve_cascades
 

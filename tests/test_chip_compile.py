@@ -239,17 +239,3 @@ def test_instance_norm_stats_kernel(one_chip, compiled_kernels):
     _, kernels = _compile(lambda x: instance_norm_act(x, True),
                           _sds(one_chip, (2, H4, W4, 128), BF16))
     assert kernels == 2  # statistics + apply
-
-
-def test_gru_auto_resolves_to_xla_on_a_single_tpu(monkeypatch):
-    """PR 24 took the megakernel out of ``auto``: Mosaic does not lower it
-    at 136x240x128 in a time a server start can bear (ROADMAP Speed 2).
-    An explicit ``fused`` still selects it, so the compiler's own error
-    reaches whoever asks for it."""
-    from raftstereo_tpu.config import RAFTStereoConfig
-    from raftstereo_tpu.ops.pallas_gru import resolve_gru_backend
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(jax, "devices", lambda *a: [object()])
-    assert resolve_gru_backend(RAFTStereoConfig()) == "xla"
-    assert resolve_gru_backend(RAFTStereoConfig(gru_backend="fused")) == "fused"

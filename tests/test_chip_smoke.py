@@ -52,3 +52,31 @@ def test_compile_cache_is_placed_from_outside_or_under_the_checkout():
     want = os.path.join(REPO, ".jax_cache")
     assert _cache_probe(JAX_PLATFORMS="tpu") == (want, want)
     assert _cache_probe() == (want, want)
+
+
+def test_runtime_line_has_what_the_benchmark_reads_and_no_gru_backend(capsys):
+    """The ``runtime:`` line is an interface: ``benchmark/child.py``'s
+    ``check_runtime`` and ``benchmark/run.py`` index it by name, and
+    ``chip_smoke.py`` prints every gate.  One GRU step means no
+    ``gru_backend`` entry to report."""
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    from benchmark import child
+    from raftstereo_tpu.config import RAFTStereoConfig
+    from raftstereo_tpu.utils.platform import describe_runtime
+
+    rt = describe_runtime(RAFTStereoConfig(compute_dtype="bfloat16"), 8,
+                          (544, 960))
+    assert set(rt) == {
+        "platform", "device_kind", "device_count", "corr", "corr_auto",
+        "corr_matmul", "fused_stem_cnet", "fused_stem_fnet",
+        "pallas_interpret", "compile_cache"}
+    child.check_runtime(rt, 1, rehearse=True)  # indexes its keys by name
+    assert "platform is 'cpu', not 'tpu'" in capsys.readouterr().err
+    with open(os.path.join(REPO, "chip_smoke.py")) as f:
+        gates, = (fn for fn in ast.parse(f.read()).body
+                  if getattr(fn, "name", None) == "check_runtime")
+    printed = {node.slice.value for node in ast.walk(gates)
+               if isinstance(node, ast.Subscript)
+               and getattr(node.value, "id", None) == "rt"}
+    assert printed and printed <= set(rt)

@@ -67,6 +67,24 @@ class TestHelpRegression:
         out = capsys.readouterr().out
         assert "--cascades" in out and "--cascade_divergence" in out
 
+    @pytest.mark.parametrize("name,required", [
+        ("serve", []), ("train", []), ("stream", []), ("certify", []),
+        ("sl", []), ("evaluate", ["--dataset", "kitti"]),
+        ("demo", ["--restore_ckpt", "x", "-l", "a", "-r", "b"])])
+    def test_a_removed_flag_fails_loudly(self, name, required, capsys):
+        # One GRU step: every entry point that shares the model flags took
+        # `--gru_backend`; an old command line that still pins it is an
+        # argparse error (exit 2) naming the flag, not a flag accepted
+        # and ignored.
+        import importlib
+
+        mod = importlib.import_module(f"raftstereo_tpu.cli.{name}")
+        with pytest.raises(SystemExit) as ei:
+            mod.main(required + ["--gru_backend", "xla"])
+        assert ei.value.code == 2
+        assert "unrecognized arguments: --gru_backend xla" \
+            in capsys.readouterr().err
+
     def test_router_help_lists_observability_flags(self, capsys):
         # The fleet-observatory knobs (docs/observability.md "Fleet
         # observatory") must stay wired through add_router_args.

@@ -63,7 +63,7 @@ from raftstereo_tpu.serve.server import snapshot_to_wire, wire_to_snapshot
 from raftstereo_tpu.stream.session import STATE_VERSION, SessionStore
 from raftstereo_tpu.utils.faults import FaultPlan
 
-from test_bench import REPO
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # ----------------------------------------------------------------- fixtures
 
@@ -284,7 +284,7 @@ class TestDispatcherPolicy:
 
 # Engine-level state-schema fingerprint used by the store-level tests
 # (shape of BatchEngine.session_schema()).
-SCHEMA = {"factor": 4, "input_mode": "concat", "gru_backend": "pallas"}
+SCHEMA = {"factor": 4, "input_mode": "passive"}
 
 
 def _warm_store(sid="cam0", next_seq=3):
@@ -501,7 +501,7 @@ class TestDispatcherMigration:
 
     def test_schema_mismatch_handoff_is_cold_schema(self):
         r0 = StoreStubReplica(0)
-        r1 = StoreStubReplica(1, schema=dict(SCHEMA, gru_backend="xla"))
+        r1 = StoreStubReplica(1, schema=dict(SCHEMA, input_mode="sl"))
         d, _ = _dispatcher([r0, r1])
         assert d.step("cam0", 0, _img(), _img()).replica == "r0"
         _seed_state(r0, "cam0")
@@ -1782,6 +1782,70 @@ class TestRouter:
         assert proc.returncode == 0, proc.stderr[-3000:]
         assert "MODEL_FREE_OK" in proc.stdout
 
+    def test_router_migrates_a_parent_builds_snapshot_warm(self):
+        """A rolling restart across builds: the source backend's snapshot
+        still carries the schema key an older build wrote
+        (``gru_backend``); the router relays it verbatim and the
+        destination, which compares only the keys it knows, installs it
+        warm and bitwise — not ``cold_schema``."""
+        import http.server
+
+        src_store, dst_store = _warm_store("cam0"), SessionStore(
+            limit=4, ttl_s=60.0)
+
+        class Stub(http.server.BaseHTTPRequestHandler):
+            def _json(self, obj):
+                body = json.dumps(obj).encode()
+                self.send_response(200)
+                self.send_header("Content-Type", "application/json")
+                self.send_header("Content-Length", str(len(body)))
+                self.end_headers()
+                self.wfile.write(body)
+
+            def do_GET(self):
+                if self.path.startswith("/debug/sessions/"):
+                    self._json(snapshot_to_wire(src_store.export_state(
+                        "cam0", schema=dict(SCHEMA, gru_backend="xla"))))
+                else:
+                    self._json({"live": True, "ready": True,
+                                "queue_depth": 0})
+
+            def do_POST(self):
+                raw = self.rfile.read(int(self.headers["Content-Length"]))
+                self._json({"outcome": dst_store.import_state(
+                    wire_to_snapshot(json.loads(raw)), schema=SCHEMA)})
+
+            def log_message(self, *a):
+                pass
+
+        stubs = [http.server.ThreadingHTTPServer(("127.0.0.1", 0), Stub)
+                 for _ in range(2)]
+        threads = [threading.Thread(target=s.serve_forever, daemon=True)
+                   for s in stubs]
+        for t in threads:
+            t.start()
+        router = build_router(RouterConfig(
+            port=0, backends=tuple(("127.0.0.1", s.server_address[1])
+                                   for s in stubs),
+            probe_interval_s=30.0))
+        rt = threading.Thread(target=router.serve_forever, daemon=True)
+        rt.start()
+        try:
+            assert router._handoff("cam0", *router.backends) == "warm"
+            outs = {lv: c.value for lv, c in
+                    router.cluster_metrics.session_handoffs.series()}
+            assert outs == {("warm",): 1}
+            sess, created = dst_store.get_or_create("cam0")
+            src, _ = src_store.get_or_create("cam0")
+            assert not created and sess.next_seq == 3
+            np.testing.assert_array_equal(sess.prev_disp_low,
+                                          src.prev_disp_low)
+        finally:
+            router.close()
+            rt.join(5)
+            for s, t in zip(stubs, threads):
+                _stop_stub(s, t)
+
     def test_router_failover_unit_no_model(self):
         """Deterministic failover path: a backend that died between
         probes (router still believes it ready) fails at connect time
@@ -2339,30 +2403,29 @@ class TestClientRetries:
         c.close()
 
 
-# ------------------------------------------------------------- bench smoke
+# ------------------------------------------- load generator over a cluster
 
-class TestBenchCluster:
-    def test_bench_cluster_quick_smoke(self, monkeypatch, capsys):
-        """bench.py --cluster --quick: the CI smoke for replicated
-        serving (in-process, same rationale as the --serve smoke).  Also
-        proves the mode refuses nothing on a clean analysis baseline and
-        that BOTH replicas took traffic."""
-        if REPO not in sys.path:
-            sys.path.insert(0, REPO)
-        import bench
-
-        monkeypatch.setattr(sys, "argv",
-                            ["bench.py", "--cluster", "--quick",
-                             "--reps", "8"])
-        bench.main()
-        lines = [l for l in capsys.readouterr().out.strip().splitlines()
-                 if l.startswith("{")]
-        record = json.loads(lines[-1])
-        assert record["unit"] == "pairs/sec" and record["value"] > 0
-        assert record["replicas"] == 2
-        assert record["cold"]["error"] == 0
-        assert record["stream"]["error"] == 0
-        assert record["stream"]["warm_frames"] > 0
-        by_replica = record["dispatch_by_replica"]
-        assert by_replica.get("r0/ok", 0) > 0
-        assert by_replica.get("r1/ok", 0) > 0
+class TestClusterLoadgen:
+    def test_run_load_over_two_replicas(self, cluster_model):
+        """The load generator's own counts against a two-replica server:
+        every request answered, none an error, and both replicas took
+        traffic (a single hot replica means placement is broken)."""
+        model, variables = cluster_model
+        cfg = _cfg(warmup=True, degraded_iters=4)  # one program a replica
+        server = build_server(model, variables, cfg, ServeMetrics())
+        thread = threading.Thread(target=server.serve_forever, daemon=True)
+        thread.start()
+        try:
+            pair = (_img(60, 90, 1), _img(60, 90, 2))
+            stats = run_load("127.0.0.1", server.port, lambda i: pair,
+                             requests=12, concurrency=4, retries=2)
+            assert stats["ok"] == 12 and stats["error"] == 0, stats
+            by_replica = {
+                lv: c.value for lv, c in
+                server.cluster.cluster_metrics.dispatch.series()}
+            assert by_replica.get(("r0", "ok"), 0) > 0, by_replica
+            assert by_replica.get(("r1", "ok"), 0) > 0, by_replica
+            assert sum(by_replica.values()) == 12, by_replica
+        finally:
+            server.close()
+            thread.join(10)

@@ -11,6 +11,7 @@ under 2% of request latency, and tracing adds zero XLA compiles.
 """
 
 import json
+import os
 import sys
 import threading
 import time
@@ -28,7 +29,7 @@ from raftstereo_tpu.serve import ServeClient, ServeError, ServeMetrics, \
     build_server
 from raftstereo_tpu.serve.metrics import MetricsRegistry
 
-from test_bench import REPO
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 TINY = dict(n_gru_layers=2, hidden_dims=(32, 32), corr_levels=2,
             corr_radius=2)

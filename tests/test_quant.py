@@ -7,7 +7,7 @@ Four layers, mirroring how the feature is built:
   inputs the int8 volume equals the fp32 volume bit-for-bit), a
   quantization-theory error bound on random inputs, and the Pallas int8
   kernel verified BITWISE against the XLA integer-einsum path in
-  interpret mode on CPU (same protocol as tests/test_pallas_gru.py);
+  interpret mode on CPU;
 * **corr wiring** — quant resolution forces a volume backend, the
   convc1 epilogue disengages, and the phase-split state path
   (build_corr_state / corr_fn_from_state) matches the monolithic
@@ -262,15 +262,14 @@ class TestEngineTiers:
         a, b = _img(seed=1), _img(seed=2)
 
         warmed = eng.warmup(iters_list=[2], modes=["fp32", "bf16", "int8"])
-        assert sorted(warmed) == [(64, 96, 2, "xla", "passive", "bf16"),
-                                  (64, 96, 2, "xla", "passive", "fp32"),
-                                  (64, 96, 2, "xla", "passive", "int8")]
+        assert sorted(warmed) == [(64, 96, 2, "batch", "passive", "bf16"),
+                                  (64, 96, 2, "batch", "passive", "fp32"),
+                                  (64, 96, 2, "batch", "passive", "int8")]
         # Stream + sched tier executables (bf16 exercises a non-default
         # mode through BOTH split paths).
         eng.warmup_stream(ladder=[2], modes=["bf16"])
         eng.warmup_sched(iters_per_step=1, modes=["bf16"])
-        assert (64, 96, 2, "stream", "xla", "passive",
-                "bf16") in eng.compiled_keys
+        assert (64, 96, 2, "stream", "passive", "bf16") in eng.compiled_keys
         assert eng.is_stream_warm((64, 96), 2, mode="bf16")
         assert not eng.is_stream_warm((64, 96), 2)  # default not warmed
         assert eng.is_sched_warm((64, 96), 1, mode="bf16")
@@ -377,6 +376,22 @@ class TestCertification:
         # The impossible bound flags turbo as over-bound, so the
         # manifest carries a genuinely refusable entry.
         assert fast_manifest["tiers"]["turbo"]["certified"] is False
+
+    def test_a_parent_builds_manifest_still_certifies(self, fast_manifest,
+                                                      quant_model):
+        """A manifest written when the architecture fingerprint had one
+        more field (``gru_backend``, always the XLA step on a chip) keeps
+        its certificate: only the fields this build fingerprints are
+        compared, and a mismatch among those still names itself."""
+        from raftstereo_tpu.eval.certify import tier_ok
+
+        model, _ = quant_model
+        assert "gru_backend" not in fast_manifest["model"]
+        old = dict(fast_manifest,
+                   model=dict(fast_manifest["model"], gru_backend="auto"))
+        assert tier_ok(old, "fast", model.config) == (True, "certified")
+        ok, reason = tier_ok(old, "fast", _tiny_cfg(corr_levels=3))
+        assert not ok and "['corr_levels']" in reason
 
     def test_manifest_roundtrip_and_validation(self, fast_manifest,
                                                quant_model, tmp_path):

@@ -14,8 +14,6 @@ beats the monolithic micro-batcher baseline measured in the same test
 """
 
 import dataclasses
-import json
-import sys
 import threading
 import time
 
@@ -34,7 +32,6 @@ from raftstereo_tpu.serve import (BatchEngine, DynamicBatcher,
 from raftstereo_tpu.serve.sched.policy import (effective_class,
                                                priority_class, should_exit)
 
-from test_bench import REPO
 
 # ----------------------------------------------------------------- fixtures
 
@@ -298,10 +295,10 @@ class TestSchedEngine:
                            min_duration_s=0.5) as cold:
             warmed = engine.warmup_sched()
         assert sorted(warmed) == [
-            (64, 96, 0, "sched_epilogue", "xla", "passive", "fp32"),
-            (64, 96, 0, "sched_join", "xla", "passive", "fp32"),
-            (64, 96, 0, "sched_prologue", "xla", "passive", "fp32"),
-            (64, 96, 1, "sched_step", "xla", "passive", "fp32")]
+            (64, 96, 0, "sched_epilogue", "passive", "fp32"),
+            (64, 96, 0, "sched_join", "passive", "fp32"),
+            (64, 96, 0, "sched_prologue", "passive", "fp32"),
+            (64, 96, 1, "sched_step", "passive", "fp32")]
         # The step executable (the GRU body) is a model-scale compile:
         # if the 0.5 s floor ever rises above the real compile times, the
         # warm budget-0 guard below would pass vacuously — keep that loud.
@@ -396,10 +393,10 @@ class TestSchedEngine:
         engine, cfg, metrics = sched_engine
         if not engine.is_sched_warm((64, 96), 1):
             engine.warmup_sched()
-        # Controller thresholds pinned out of reach (same protocol as
-        # bench.py --stream): random-weight update magnitudes would trip
-        # the trained-checkpoint-scale cold-reset threshold, and this
-        # test measures the scheduling path, not controller policy.
+        # Controller thresholds pinned out of reach: random-weight update
+        # magnitudes would trip the trained-checkpoint-scale cold-reset
+        # threshold, and this test measures the scheduling path, not
+        # controller policy.
         http_cfg = dataclasses.replace(
             cfg, stream=StreamConfig(ladder=(14, 7), session_ttl_s=300.0,
                                      demote_threshold=0.0,
@@ -455,6 +452,37 @@ class TestSchedEngine:
             server.close()
             thread.join(10)
 
+    def test_run_load_against_a_sched_server(self, sched_engine,
+                                             retrace_guard):
+        """The load generator's own counts through the scheduler path:
+        closed-loop traffic at an iteration count no monolithic program
+        was compiled for is all answered, none an error, with zero
+        compiles (the step executable serves any count)."""
+        from raftstereo_tpu.serve import run_load
+
+        engine, cfg, metrics = sched_engine
+        if not engine.is_sched_warm((64, 96), 1):
+            engine.warmup_sched()
+        http_cfg = dataclasses.replace(cfg, request_timeout_ms=120000.0)
+        scheduler = IterationScheduler(engine, http_cfg, metrics).start()
+        server = StereoServer(http_cfg, engine, None, metrics,
+                              scheduler=scheduler)
+        thread = threading.Thread(target=server.serve_forever, daemon=True)
+        thread.start()
+        joins0 = metrics.sched_joins.value
+        try:
+            pair = (_img(60, 90, 5), _img(60, 90, 6))
+            with retrace_guard(0, what="sched load-gen traffic is warm",
+                               min_duration_s=0.5):
+                stats = run_load("127.0.0.1", server.port, lambda i: pair,
+                                 requests=8, concurrency=3, iters=5)
+            assert stats["ok"] == 8 and stats["error"] == 0, stats
+            assert stats["p99_ms"] > 0
+            assert metrics.sched_joins.value - joins0 >= 8
+        finally:
+            server.close()
+            thread.join(10)
+
     def test_monolithic_server_rejects_sched_fields(self, sched_model):
         """Without --sched, deadline_ms/priority are a clear 400, not a
         silent ignore."""
@@ -475,24 +503,3 @@ class TestSchedEngine:
             client.close()
             server.close()
             thread.join(10)
-
-
-# -------------------------------------------------------------- bench smoke
-
-def test_bench_sched_quick_smoke(monkeypatch, capsys):
-    """bench.py --sched --quick: the CI smoke for the scheduler path
-    (mirrors the --serve/--stream smokes; refuses a dirty analysis
-    baseline through the same gate, covered in test_analysis.py)."""
-    if REPO not in sys.path:
-        sys.path.insert(0, REPO)
-    import bench
-
-    monkeypatch.setattr(sys, "argv", ["bench.py", "--sched", "--quick"])
-    bench.main()
-    lines = [l for l in capsys.readouterr().out.strip().splitlines()
-             if l.startswith("{")]
-    record = json.loads(lines[-1])
-    assert record["unit"] == "ms" and record["value"] > 0
-    assert record["sched"]["short_p99_ms"] > 0
-    assert record["mono"]["short_p99_ms"] > 0
-    assert record["short_iters"] < record["long_iters"]

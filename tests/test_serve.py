@@ -9,7 +9,6 @@ deadlocks, metrics non-zero.
 """
 
 import json
-import sys
 import threading
 import time
 
@@ -25,7 +24,6 @@ from raftstereo_tpu.serve import (BatchEngine, DynamicBatcher, Overloaded,
                                   build_server, decode_array, encode_array,
                                   run_load)
 
-from test_bench import REPO
 from test_wire import _tiles
 
 
@@ -208,8 +206,8 @@ class TestEngine:
         eng = BatchEngine(model, variables, cfg)
         # Warmup compiles the configured bucket at BOTH iteration levels.
         warmed = eng.warmup()
-        assert sorted(warmed) == [(64, 96, 1, "xla", "passive", "fp32"),
-                                  (64, 96, 2, "xla", "passive", "fp32")]
+        assert sorted(warmed) == [(64, 96, 1, "batch", "passive", "fp32"),
+                                  (64, 96, 2, "batch", "passive", "fp32")]
         a, b = _img(60, 90, 1), _img(64, 96, 2)  # same 64x96 bucket
         eng.infer_batch([(a, a)], iters=2)
         assert not eng.last_included_compile  # warmup paid the compile
@@ -227,6 +225,102 @@ class TestEngine:
             eng.infer_batch([(_img(60, 90),) * 2, (_img(70, 100),) * 2], 2)
         with pytest.raises(AssertionError, match="max_batch_size"):
             eng.infer_batch([(_img(),) * 2] * 3, 2)
+
+
+class _KeyCaptured(Exception):
+    """Raised by the dispatch spy of ``TestKeyKinds`` in place of the
+    device work, carrying the cache key the entry point built."""
+
+
+class TestKeyKinds:
+    """Every kind of program the engine can compile names its kind at
+    position 3 of its cache key: no two kinds share a tuple at one
+    (bucket, iters, input_mode, mode), and nothing that reads a key
+    (``is_*_warm``, ``_dispatch``'s metric label, ``compiled_programs``)
+    tells kinds apart by the key's length."""
+
+    KINDS = ("batch", "stream", "spatial", "sched_prologue", "sched_step",
+             "sched_epilogue", "sched_join", "cascade_prologue",
+             "cascade_stage_join", "cascade_handoff", "cascade_delta")
+
+    @pytest.fixture(scope="class")
+    def engine_and_keys(self, serve_model):
+        """One engine and, per kind, the key its OWN entry point hands to
+        ``_dispatch`` / ``_dispatch_state`` — captured by a spy, so no
+        program compiles."""
+        model, variables = serve_model
+        metrics = ServeMetrics()
+        eng = BatchEngine(model, variables,
+                          _cfg(max_batch_size=2, spatial_shards=4), metrics)
+        real = eng._dispatch, eng._dispatch_state
+
+        def spy(via):
+            def capture(key, call):
+                raise _KeyCaptured(key, via)
+            return capture
+
+        eng._dispatch = spy("_dispatch")
+        eng._dispatch_state = spy("_dispatch_state")
+        a, hw, it = _img(), (64, 96), 3
+        mask, pair = np.zeros(2, bool), dict(cheap_mode="bf16",
+                                             cert_mode="fp32")
+        entries = {
+            "batch": lambda: eng.infer_batch([(a, a)], it),
+            "stream": lambda: eng.infer_stream_batch([(a, a)], it, [None]),
+            "spatial": lambda: eng.infer_spatial(a, a, it),
+            "sched_prologue": lambda: eng.infer_sched_prologue(
+                [(a, a)], [None], [0]),
+            "sched_step": lambda: eng.infer_sched_step(hw, None, it),
+            "sched_epilogue": lambda: eng.infer_sched_epilogue(hw, None),
+            "sched_join": lambda: eng.infer_sched_join(hw, None, None, mask),
+            "cascade_prologue": lambda: eng.infer_cascade_prologue(
+                [(a, a)], [None], [0], **pair),
+            "cascade_stage_join": lambda: eng.infer_cascade_stage_join(
+                hw, None, None, mask, **pair),
+            "cascade_handoff": lambda: eng.infer_cascade_handoff(
+                hw, None, None, np.zeros(2, np.int32), **pair),
+            "cascade_delta": lambda: eng.infer_cascade_delta(
+                hw, None, None, **pair),
+        }
+        keys = {}
+        for kind, entry in entries.items():
+            with pytest.raises(_KeyCaptured) as ei:
+                entry()
+            keys[kind] = ei.value.args  # (key, the dispatcher it took)
+        eng._dispatch, eng._dispatch_state = real
+        return eng, metrics, keys
+
+    @pytest.mark.parametrize("kind", KINDS)
+    def test_kind_is_tagged_and_read_from_the_tag(self, engine_and_keys,
+                                                  kind):
+        eng, metrics, keys = engine_and_keys
+        assert sorted(keys) == sorted(self.KINDS)
+        key, via = keys[kind]
+        assert key[:2] == (64, 96) and key[3] == kind
+        assert [k for k, (other, _) in keys.items() if other == key] \
+            == [kind]
+        sorted(k for k, _ in keys.values())  # /healthz sorts the mixed set
+
+        # Run the real bookkeeping on this key alone (a trivial device
+        # call stands in for the program).
+        with eng._stats_lock:
+            eng._compiled.clear()
+        if via == "_dispatch_state":  # the result stays on the device
+            _, miss = eng._dispatch_state(key, lambda: jax.numpy.zeros(1))
+        else:
+            _, miss = eng._dispatch(key, lambda: [jax.numpy.zeros(1)])
+        assert miss and eng.compiled_keys == {key}
+        labels = dict(bucket="64x96", iters=str(key[2]), mode=kind,
+                      tier=key[-1])
+        assert metrics.compile_misses.labels(**labels).value == 1
+
+        # The readers: only the kind's own predicate sees its key, and
+        # only a plain batch program has facts to show.
+        assert eng.is_warm((64, 96), 3) == (kind == "batch")
+        assert eng.is_stream_warm((64, 96), 3) == (kind == "stream")
+        assert eng.is_spatial_warm((64, 96), 3) == (kind == "spatial")
+        assert bool(eng._program_facts(key)) == (kind == "batch")
+        assert len(eng.compiled_programs) == (kind == "batch")
 
 
 # ------------------------------------------------------------ metrics + wire
@@ -369,7 +463,8 @@ class TestEndToEnd:
             # would pass vacuously — this assert makes that loud.
             assert cold_report.compiles == 2, cold_report.durations
             assert server.engine.compiled_keys == {
-                (64, 96, 3, "xla", "passive", "fp32"), (96, 128, 3, "xla", "passive", "fp32")}
+                (64, 96, 3, "batch", "passive", "fp32"),
+                (96, 128, 3, "batch", "passive", "fp32")}
             assert metrics.compile_misses.value == 2
 
             # (2) bitwise equality with the single-image Evaluator under
@@ -466,38 +561,12 @@ class TestEndToEnd:
             health = client.healthz()
             assert health["status"] == "ok"
             assert sorted(tuple(k) for k in health["compiled_buckets"]) \
-                == [(64, 96, 3, "xla", "passive", "fp32"),
-                    (96, 128, 3, "xla", "passive", "fp32")]
+                == [(64, 96, 3, "batch", "passive", "fp32"),
+                    (96, 128, 3, "batch", "passive", "fp32")]
             client.close()
         finally:
             server.close()
             thread.join(10)
-
-    def test_bench_serve_quick_smoke(self, monkeypatch, capsys):
-        """bench.py --serve --quick: the CI smoke for the serving path.
-
-        Runs bench's main() in-process (argv-level, same code path as the
-        shell) — a subprocess would pay ~10 s of fresh jax import for no
-        extra coverage, and the tier-1 budget is tight.
-        """
-        if REPO not in sys.path:
-            sys.path.insert(0, REPO)
-        import bench
-
-        monkeypatch.setattr(sys, "argv", ["bench.py", "--serve", "--quick"])
-        bench.main()
-        lines = [l for l in capsys.readouterr().out.strip().splitlines()
-                 if l.startswith("{")]
-        record = json.loads(lines[-1])
-        assert record["unit"] == "pairs/sec" and record["value"] > 0
-        assert record["p99_ms"] > 0
-        assert record["ok"] >= 12 and record["error"] == 0
-        # Dual-dialect measurement (docs/wire_format.md): the record
-        # states the wire-bytes/pair of BOTH formats and the acceptance
-        # floor — binary carries a pair in at least 4x fewer bytes.
-        assert record["wire_format"] == "binary"
-        assert record["json"]["ok"] >= 12
-        assert record["wire_reduction_x"] >= 4.0, record
 
 
 # ------------------------------------------------- binary wire over HTTP

@@ -33,7 +33,7 @@ from raftstereo_tpu.sl import (NUM_PATTERNS, SL_CHANNELS, SLShiftStereoDataset,
                                SLTrainView, make_learnable_sl, masked_epe,
                                stack_sl_inputs)
 
-from test_bench import REPO
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 TINY = dict(corr_levels=2, corr_radius=2, n_gru_layers=2, hidden_dims=(32, 32))
 SL_CFG = RAFTStereoConfig(input_mode="sl", **TINY)
@@ -253,7 +253,7 @@ class TestServingE2E:
         server = build_server(model, variables, cfg, metrics)  # warms
         assert server.engine.input_mode == "sl"
         assert server.engine.input_channels == SL_CHANNELS
-        assert (64, 96, 3, "xla", "sl", "fp32") in server.engine.compiled_keys
+        assert (64, 96, 3, "batch", "sl", "fp32") in server.engine.compiled_keys
         warm_misses = metrics.compile_misses.value
         thread = threading.Thread(target=server.serve_forever, daemon=True)
         thread.start()

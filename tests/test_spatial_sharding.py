@@ -149,8 +149,7 @@ class TestSpatialEngine:
         with retrace_guard(1, what="one spatial bucket, one compile",
                            min_duration_s=0.5):
             warmed = eng.warmup_spatial()
-        assert warmed == [(128, 96, 2, "spatial", "s4", "xla", "passive",
-                           "fp32")]
+        assert warmed == [(128, 96, 2, "spatial", "s4", "passive", "fp32")]
         assert eng.is_spatial_warm((128, 96), 2)
         assert eng.warmup_spatial() == []  # idempotent: already warm
 

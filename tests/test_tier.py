@@ -51,11 +51,11 @@ from raftstereo_tpu.stream.tier import (SessionTier, TierClient,
                                         TierMetrics, TierPublisher,
                                         _TierStore, build_session_tier)
 
-from test_bench import REPO
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # ----------------------------------------------------------------- helpers
 
-_SCHEMA = {"factor": 4, "input_mode": "pad", "gru_backend": "sequential"}
+_SCHEMA = {"factor": 4, "input_mode": "passive"}
 
 
 def _snapshot(sid="s0", next_seq=3, hw=(15, 23), seed=0, schema=None,
@@ -433,6 +433,31 @@ class TestSessionTierService:
             tier.close()
             th.join(5)
 
+    def test_a_parent_builds_snapshot_resumes_warm_from_the_tier(self):
+        """The tier stores a snapshot verbatim, whatever build wrote it:
+        one whose schema still carries an older build's ``gru_backend``
+        comes back as it went in, and a backend of this build (which
+        compares only ``factor`` / ``input_mode``) resumes it warm."""
+        tier, th = _tier()
+        client = TierClient("127.0.0.1", tier.port, timeout_s=5.0)
+        try:
+            snap = _snapshot("cam0", next_seq=4,
+                             schema=dict(_SCHEMA, gru_backend="xla"))
+            assert client.put_wire(snapshot_to_wire(snap))["outcome"] \
+                == "stored"
+            got = client.get_session("cam0")
+            assert got["schema"]["gru_backend"] == "xla"
+            store = SessionStore(limit=4, ttl_s=100.0)
+            assert store.import_state(wire_to_snapshot(got),
+                                      schema=_SCHEMA) == "warm"
+            out = store.export_state("cam0", schema=_SCHEMA)
+            assert out["next_seq"] == 4
+            np.testing.assert_array_equal(out["prev_disp_low"],
+                                          snap["prev_disp_low"])
+        finally:
+            tier.close()
+            th.join(5)
+
     def test_chaos_grammar_tier_slow_and_outage(self):
         """The armable chaos seams: tier_slow delays the next N replies,
         tier_outage holds EVERY reply until the window ends — clients
@@ -523,7 +548,7 @@ class TestSessionTierProcess:
             # Mixed fleet: an importer whose engine fingerprint differs
             # refuses the tier copy with the documented cold fallback.
             other = SessionStore(limit=4, ttl_s=100.0)
-            mismatched = dict(_SCHEMA, gru_backend="fused")
+            mismatched = dict(_SCHEMA, input_mode="sl")
             assert other.import_state(again, schema=mismatched) \
                 == "cold_schema"
         finally:

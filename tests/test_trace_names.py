@@ -18,7 +18,7 @@ from raftstereo_tpu.config import RAFTStereoConfig, TrainConfig
 from raftstereo_tpu.models import RAFTStereo
 from raftstereo_tpu.models.raft_stereo import STAGES
 
-from test_bench import REPO
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 TINY = dict(n_gru_layers=2, hidden_dims=(32, 32), corr_levels=2,
             corr_radius=2)
@@ -54,7 +54,7 @@ class TestPallasNames:
                                            "*.py")):
             with open(path) as f:
                 n += f.read().count("pallas_call(")
-        assert len(SITES) == n >= 18
+        assert len(SITES) == n >= 17
 
     @pytest.mark.parametrize(
         "site", SITES, ids=[f"{f}:{n or line}" for f, line, n in SITES])

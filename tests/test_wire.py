@@ -346,6 +346,24 @@ class TestStoredTiles:
         assert np.max(np.abs(res.disparity - disp)) \
             <= res.manifest["err_bound"]
 
+    def test_binary_carries_a_pair_in_a_quarter_of_the_json_bytes(self):
+        """The floor docs/wire_format.md states: request + reply of one
+        camera-style pair take at least 4x fewer bytes as wire frames
+        than as the base64 JSON dialect (a small reply's exponent plane
+        has to stay deflated for it: 3.7x with that plane stored)."""
+        from raftstereo_tpu.serve import encode_array
+
+        left, right = _grain(64, 96, 1), _grain(64, 96, 2)
+        disp = _smooth_exponent_field(64, 96, 3)
+        meta = {"iters": 4, "request_id": "r"}
+        binary = len(wire.encode_request(left, right, {})) \
+            + len(wire.encode_response(disp, meta))
+        as_json = len(json.dumps({"left": encode_array(left),
+                                  "right": encode_array(right)})) \
+            + len(json.dumps({"disparity": encode_array(disp),
+                              "meta": meta}))
+        assert as_json >= 4.0 * binary, (as_json, binary)
+
 
 class TestInt16Manifest:
     def test_manifest_bounds_hold(self):
