@@ -18,9 +18,13 @@ method reaches the key:
 
 * key-relevant = the parameter name contains ``iters``, ``mode``,
   ``precision``, ``dtype``, ``backend``, ``accuracy``, ``tier``,
-  ``quant``, ``shards``, ``cascade`` or ``schedule`` — the inputs that
-  select a distinct executable (shape inputs are carried by the bucket,
-  which every key already starts from; ``backend`` covers
+  ``quant``, ``shards``, ``rows``, ``cascade`` or ``schedule`` — the
+  inputs that select a distinct executable (shape inputs are carried by
+  the bucket, which every key already starts from; ``rows`` is the
+  compiled row count of a plain batch program — a dispatch holds the
+  rows that came, at row count 1 or ``max_batch_size``, and the one-row
+  and the eight-row program at the same bucket are different
+  executables, serve/engine.py ``row_counts``; ``backend`` covers
   kernel-backend selectors,
   ``accuracy``/``tier``/``quant`` the per-request accuracy tiers whose
   precision mode joins every serving key, serve/engine.py +
@@ -57,7 +61,7 @@ __all__ = ["check"]
 
 _METHOD_RE = re.compile(r"^(infer|warmup)_")
 _KEY_TOKENS = ("iters", "mode", "precision", "dtype", "backend",
-               "accuracy", "tier", "quant", "input_mode", "shards",
+               "accuracy", "tier", "quant", "input_mode", "shards", "rows",
                "cascade", "schedule")
 _CACHE_ATTR_RE = re.compile(r"compiled|cache", re.IGNORECASE)
 _DISPATCH_RE = re.compile(r"dispatch", re.IGNORECASE)

@@ -86,6 +86,7 @@ def run_metrics_lint() -> List[Finding]:
                                 mode="batch", tier="fp32").inc()
     serve.compile_hits.labels(bucket="64x96", iters="8",
                               mode="stream", tier="bf16").inc()
+    serve.batch_rows.labels(rows="8").inc()
     serve.stream_cold_frames.labels(reason="new").inc()
     serve.stream_tier_pushes.labels(outcome="ok").inc()
     serve.wire_bytes.labels(direction="in", format="binary").inc(1024)

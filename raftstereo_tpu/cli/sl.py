@@ -59,7 +59,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="bad-pixel threshold for the bad-px metric")
     p.add_argument("--batch_pad", type=int, default=None,
                    help="serving-parity mode: zero-pad the batch axis to "
-                        "this size (the engine's max_batch_size) so "
+                        "this size (the row count of the engine's "
+                        "dispatch: 1 for a pair served alone) so "
                         "results match /predict bitwise")
     add_model_args(p)
     return p

@@ -544,9 +544,10 @@ class ServeConfig:
     warmup: bool = True
 
     # Dynamic micro-batching: a batch closes at max_batch_size or when the
-    # oldest member has waited max_wait_ms, whichever comes first.  Every
-    # dispatched batch is zero-padded to max_batch_size so each shape bucket
-    # compiles exactly once.
+    # oldest member has waited max_wait_ms, whichever comes first.  A
+    # dispatch holds the rows that came, at row count 1 or max_batch_size
+    # and with no zero row (a full batch when one is queued, one row when
+    # not), so each shape bucket compiles once per row count: twice.
     max_batch_size: int = 8
     max_wait_ms: float = 5.0
 

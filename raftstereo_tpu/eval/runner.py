@@ -54,7 +54,10 @@ class Evaluator:
         self.bucket_multiple = bucket_multiple
         # Serving-parity mode: zero-pad the batch axis to this size so the
         # pair executes at the serving engine's padded-batch program shape
-        # (serve/engine.py pads every batch to max_batch_size).  XLA tiles
+        # (serve/engine.py: a plain dispatch holds the rows that came, at
+        # row count 1 or max_batch_size, so a pair served alone is
+        # batch_pad=1 and one of a full batch is max_batch_size; only the
+        # warm-start path still pads to max_batch_size).  XLA tiles
         # reductions differently per program shape, so only identical
         # shapes guarantee bitwise-identical per-sample results.
         self.batch_pad = batch_pad

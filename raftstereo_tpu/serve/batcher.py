@@ -1,11 +1,17 @@
-"""Dynamic micro-batcher: coalesce concurrent requests into padded batches.
+"""Dynamic micro-batcher: coalesce concurrent requests into batches of a
+row count the engine has compiled — a full batch when one is queued, one
+row when not, never a zero row.
 
 Deadline-aware dynamic batching in the spirit of Clipper (Crankshaw et al.,
 NSDI 2017): a single worker thread groups queued requests by (shape bucket,
 requested iterations) and closes a batch when it reaches
 ``max_batch_size`` or when the OLDEST member has waited ``max_wait_ms``,
 whichever comes first — so batching never adds more than one deadline of
-latency at low load, and amortizes dispatch at high load.
+latency at low load, and amortizes dispatch at high load.  A closed batch
+takes the largest compiled row count (``engine.row_counts``: 1 and
+``max_batch_size``) that the queued rows fill and leaves the rest queued,
+first in first out: their deadline has passed, so the next cycle closes at
+once.
 
 Robustness controls, all tested in tests/test_serve.py:
 
@@ -155,7 +161,8 @@ class DynamicBatcher:
     The engine contract is ``bucket_of(shape) -> (h, w)`` and
     ``infer_batch(pairs, iters, mode=None) -> [disparity]`` (see
     engine.BatchEngine; tests substitute stubs — ``mode`` is the
-    request's resolved precision mode, always passed by keyword).
+    request's resolved precision mode, always passed by keyword), and
+    optionally ``row_counts``: the batch sizes it has programs for.
     """
 
     def __init__(self, engine, config: ServeConfig,
@@ -251,6 +258,18 @@ class DynamicBatcher:
         """Key whose head request has waited longest (caller holds lock)."""
         return min(self._queues, key=lambda k: self._queues[k][0].seq)
 
+    def _take(self, queued: int) -> int:
+        """How many of ``queued`` rows a closing batch takes: the largest
+        row count the engine has compiled that they fill, so no dispatch
+        holds a zero row; an engine that names no counts (the test
+        doubles) takes what is there, up to ``max_batch_size``."""
+        n = min(queued, self.cfg.max_batch_size)
+        counts = getattr(self.engine, "row_counts", None) or ()
+        return max((c for c in counts if c <= n), default=n)
+
+    def _timed_out(self, r: _Request, now: float) -> bool:
+        return now - r.t_enqueue > self.cfg.request_timeout_ms / 1000.0
+
     def _phase(self, name: str, btid: Optional[str], **attrs):
         """One phase of the worker's cycle: a ring span under the batch's
         trace plus a profiler annotation (``Tracer.phase``); without a
@@ -296,8 +315,16 @@ class DynamicBatcher:
                     q = self._queues.get(key)
                     if not q:  # drained by a non-drain stop
                         continue
-                    batch = [q.popleft() for _ in
-                             range(min(len(q), self.cfg.max_batch_size))]
+                    # Requests past request_timeout_ms head the queue
+                    # (one time-out, FIFO): they leave with this batch to
+                    # be failed, and the rows taken are counted among the
+                    # live ones behind them, so what reaches the engine
+                    # is still a compiled row count.
+                    now = time.perf_counter()
+                    expired = sum(1 for _ in itertools.takewhile(
+                        lambda r: self._timed_out(r, now), q))
+                    batch = [q.popleft() for _ in range(
+                        expired + self._take(len(q) - expired))]
                     if not q:
                         del self._queues[key]
                     self._depth -= len(batch)
@@ -333,8 +360,8 @@ class DynamicBatcher:
                                  ("device_wait", seg.get("device_wait")),
                                  ("host_fetch", seg.get("host_fetch"))):
                 if window:
-                    # pad_bucket also says how much of the batch is real
-                    # (real_px / bucket_px, engine._pad_pairs)
+                    # pad_bucket also says what the dispatch was staged
+                    # at (rows / real_px / bucket_px, engine._pad_pairs)
                     extra = (seg.get("pad_px") or {}
                              if name == "pad_bucket" else {})
                     self.tracer.record(name, *window, btid,
@@ -354,6 +381,9 @@ class DynamicBatcher:
                                    attrs=attrs)
                 continue
             attrs["compile"] = seg["compile"]
+            if seg.get("pad_px"):
+                # the compiled row count of the dispatch
+                attrs["rows"] = seg["pad_px"].get("rows")
             parent = self.tracer.record(
                 "dispatch", t_run0, seg["dispatch"][1], r.trace_id,
                 attrs=attrs)
@@ -368,10 +398,9 @@ class DynamicBatcher:
     def _dispatch(self, key: _Key, batch, backlog: int,
                   btid: Optional[str] = None) -> None:
         now = time.perf_counter()
-        timeout_s = self.cfg.request_timeout_ms / 1000.0
         alive = []
         for r in batch:
-            if now - r.t_enqueue > timeout_s:
+            if self._timed_out(r, now):
                 self.metrics.timeouts.inc()
                 if self.tracer is not None and r.trace_id is not None:
                     self.tracer.record(
@@ -379,7 +408,7 @@ class DynamicBatcher:
                         attrs={"outcome": "timeout"})
                 r.future._resolve(exc=RequestTimedOut(
                     f"queued {now - r.t_enqueue:.3f}s > "
-                    f"{timeout_s:.3f}s limit"))
+                    f"{self.cfg.request_timeout_ms / 1000.0:.3f}s limit"))
             else:
                 alive.append(r)
         if not alive:

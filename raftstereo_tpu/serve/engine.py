@@ -1,21 +1,25 @@
-"""Shape-bucketed, padded-batch compiled inference engine.
+"""Shape-bucketed compiled inference engine: a dispatch holds the rows
+that came.
 
 The serving analogue of ``eval/runner.Evaluator``: one compiled executable
-per (shape bucket, GRU iterations, GRU backend, input mode, precision
-mode), reused across requests.
+per (shape bucket, GRU iterations, row count, input mode, precision mode),
+reused across requests.
 Three shape decisions keep the XLA compile count small and predictable:
 
 * every image is padded with the SAME ``BucketPadder`` policy the Evaluator
   uses (divis_by alignment, then round-up to ``bucket_multiple``), so
   near-identical sizes share a bucket — and per-sample numerics match the
-  single-image Evaluator bitwise;
-* every dispatched batch is zero-padded along the batch axis to
-  ``max_batch_size``, so a bucket compiles exactly once regardless of how
-  many requests the micro-batcher coalesced (padding rows are dead weight
-  on the MXU but convs/norms are per-sample, so real samples are
-  unaffected);
-* configured buckets are compiled eagerly at startup (``warmup``), so the
-  first real request never pays the multi-second XLA compile.
+  Evaluator bitwise at the same batch shape (``batch_pad=rows``);
+* a plain dispatch holds exactly a compiled ROW COUNT of real rows and no
+  zero rows (``row_counts``: 1 and ``max_batch_size``), so a bucket
+  compiles once per row count and a lone pair never pays for a full
+  batch.  The batcher takes a full batch when one is queued and one row
+  when not; ``infer_batch`` runs any other ``n`` as the fewest dispatches
+  of compiled counts (3 -> 1 + 1 + 1).  The warm-start (``stream``) path
+  keeps one program, zero-padded to ``max_batch_size`` rows;
+* configured buckets are compiled eagerly at startup (``warmup``), every
+  row count of them, so the first real request never pays the
+  multi-second XLA compile.
 
 The engine is deliberately synchronous and lock-serialized: ordering and
 batching policy live in the batcher; this layer owns shapes, compiles and
@@ -46,8 +50,30 @@ logger = logging.getLogger(__name__)
 __all__ = ["BatchEngine"]
 
 
+def row_counts(max_batch_size: int) -> Tuple[int, ...]:
+    """The batch shapes the plain path compiles, from ``max_batch_size``
+    alone: one row and a full batch (8 -> 1, 8; 1 -> 1).  A row costs the
+    same device time at either on the v5e (PERF.md §5, ``T(rows)``), so a
+    count in between would only add a program to every start; the take
+    rules below are written over the tuple, for the cell that shows a
+    shape where a mid-size batch is cheaper a row than singles."""
+    return tuple(sorted({1, max_batch_size}))
+
+
+def split_rows(n: int, counts: Sequence[int]) -> List[int]:
+    """``n`` rows as the fewest dispatches of compiled row counts, largest
+    first (counts 1, 8: 8 -> [8]; 3 -> [1, 1, 1]; 11 -> [8, 1, 1, 1]).
+    ``counts`` holds 1, so every ``n`` has an answer."""
+    out = []
+    for c in sorted(counts, reverse=True):
+        out += [c] * (n // c)
+        n %= c
+    return out
+
+
 class BatchEngine:
-    """Batched test-mode forward behind a shape-bucketed compile cache."""
+    """Batched test-mode forward behind a shape-bucketed compile cache:
+    a plain dispatch runs the program of exactly its row count."""
 
     def __init__(self, model, variables, config: ServeConfig,
                  metrics: Optional[ServeMetrics] = None, device=None,
@@ -77,6 +103,10 @@ class BatchEngine:
         self.variables = variables
         self.cfg = config
         self.metrics = metrics
+        # The row counts a plain dispatch can hold, from max_batch_size
+        # alone; the batcher reads them to decide how many queued rows to
+        # take (serve/batcher.py ``_take``).
+        self.row_counts = row_counts(config.max_batch_size)
         # Precision modes (ops/quant.py): every executable key carries the
         # resolved mode ("fp32"/"bf16"/"int8") as its LAST component — the
         # per-request ``accuracy`` tier compiles a different program with
@@ -135,14 +165,15 @@ class BatchEngine:
         self._stats_lock = threading.Lock()
         # Compiled keys.  Position 3 names the KIND of program in every
         # key, and nothing reads a kind from a key's length:
-        # (h, w, iters, "batch", input_mode, mode) for the plain forward,
+        # (h, w, iters, "batch", "rN", input_mode, mode) for the plain
+        # forward at N rows (``_batch_key``),
         # (h, w, iters, "stream", input_mode, mode) for the warm-start
         # (flow_init) forward, (h, w, iters, "spatial", "sN", input_mode,
-        # mode) for the sharded one — the shard count rides as the
-        # STRING "sN" so the mixed-arity key set stays sortable (ints at
-        # 0-2, strings from 3 on; /healthz sorts the whole set for a
-        # stable compiled_buckets listing) — and the sched_* / cascade_*
-        # phases below.
+        # mode) for the sharded one — the row and shard counts ride as
+        # the STRINGS "rN" / "sN" so the mixed-arity key set stays
+        # sortable (ints at 0-2, strings from 3 on; /healthz sorts the
+        # whole set for a stable compiled_buckets listing) — and the
+        # sched_* / cascade_* phases below.
         self._compiled: Set[Tuple] = set()  # guarded_by: _stats_lock
         self.last_batch_runtime: float = float("nan")  # guarded_by: _lock
         self.last_included_compile: bool = True  # guarded_by: _lock
@@ -189,17 +220,25 @@ class BatchEngine:
         with self._stats_lock:
             return set(self._compiled)
 
+    def _batch_key(self, hw: Tuple[int, int], iters: int, rows: int,
+                   mode: str) -> Tuple:
+        return (hw[0], hw[1], iters, "batch", f"r{rows}", self.input_mode,
+                mode)
+
     def _program_facts(self, key: Tuple) -> Dict:
-        """What the shapes of a plain batch program resolved to
-        (utils/platform.describe_program: ``corr_block``,
-        ``fused_stages``); nothing for the other kinds of key, whose
-        programs see other batches (stream, sched) or other paths
-        (spatial, cascade)."""
-        if self.model is None or key[3] != "batch":
+        """What the shapes of a plain batch program resolved to: its
+        ``rows`` and, at that row count, ``corr_block`` and
+        ``fused_stages`` (utils/platform.describe_program); nothing for
+        the other kinds of key, whose programs see other batches (stream,
+        sched) or other paths (spatial, cascade)."""
+        if key[3] != "batch":
             return {}
-        from ..utils.platform import describe_program
-        return describe_program(self.model.config, self.cfg.max_batch_size,
-                                key[:2])
+        facts = {"rows": int(key[4][1:])}  # "rN", _batch_key
+        if self.model is not None:
+            from ..utils.platform import describe_program
+            facts.update(describe_program(self.model.config, facts["rows"],
+                                          key[:2]))
+        return facts
 
     @property
     def compiled_programs(self) -> Dict[str, Dict]:
@@ -210,12 +249,16 @@ class BatchEngine:
                                   if k[3] == "batch")}
 
     def is_warm(self, hw: Tuple[int, int], iters: int,
-                mode: Optional[str] = None) -> bool:
+                mode: Optional[str] = None,
+                rows: Optional[int] = None) -> bool:
         """Whether (bucket, iters, mode) already has a compiled
-        executable."""
+        executable at ``rows``; with no ``rows``, at every row count (no
+        batch the batcher can form would compile)."""
+        m = self._mode(mode)
         with self._stats_lock:
-            return (hw[0], hw[1], iters, "batch", self.input_mode,
-                    self._mode(mode)) in self._compiled
+            return all(self._batch_key(hw, iters, r, m) in self._compiled
+                       for r in (self.row_counts if rows is None
+                                 else (rows,)))
 
     def is_stream_warm(self, hw: Tuple[int, int], iters: int,
                        mode: Optional[str] = None) -> bool:
@@ -449,11 +492,16 @@ class BatchEngine:
 
         Covers both iteration levels (normal + degraded) so flipping into
         graceful degradation under load never stalls the queue behind a
-        compile — exactly the moment a compile is least affordable — and
+        compile — exactly the moment a compile is least affordable —
         every requested precision mode (``modes``; default = the base
         config's mode only) so a warmed accuracy tier never compiles
-        under traffic either.  Returns the
-        (h, w, iters, "batch", input_mode, mode) keys warmed.
+        under traffic either, and every row count of each, so no batch
+        the batcher can form does.  A row count is warmed by a batch of
+        that many zero pairs: the eager staging programs of
+        ``_stage_pairs`` depend on the row count alone, so they all run
+        here as well and no dispatch meets one for the first time under
+        traffic.  Returns the (h, w, iters, "batch", "rN", input_mode,
+        mode) keys warmed.
         """
         buckets = list(buckets or self.cfg.buckets)
         # sorted, not set-ordered: the default {iters, degraded_iters} set
@@ -465,21 +513,23 @@ class BatchEngine:
         warmed = []
         for h, w in buckets:
             bh, bw = self.bucket_of((h, w, self.input_channels))
+            zero = np.zeros((h, w, self.input_channels), np.float32)
             for iters in iters_list:
                 for mode in modes:
-                    key = (bh, bw, iters, "batch",
-                           self.input_mode, mode)
-                    # is_warm, not a bare `in self._compiled`: membership
-                    # is guarded by _stats_lock (RSA301).
-                    if self.is_warm((bh, bw), iters, mode):
-                        continue
-                    zero = np.zeros((h, w, self.input_channels), np.float32)
-                    t0 = time.perf_counter()
-                    self.infer_batch([(zero, zero)], iters, mode=mode)
-                    logger.info("warmup: bucket %dx%d iters=%d mode=%s "
-                                "compiled in %.1fs", bh, bw, iters, mode,
-                                time.perf_counter() - t0)
-                    warmed.append(key)
+                    for rows in self.row_counts:
+                        # is_warm, not a bare `in self._compiled`:
+                        # membership is guarded by _stats_lock (RSA301).
+                        if self.is_warm((bh, bw), iters, mode, rows=rows):
+                            continue
+                        t0 = time.perf_counter()
+                        self.infer_batch([(zero, zero)] * rows, iters,
+                                         mode=mode)
+                        logger.info("warmup: bucket %dx%d iters=%d rows=%d "
+                                    "mode=%s compiled in %.1fs", bh, bw,
+                                    iters, rows, mode,
+                                    time.perf_counter() - t0)
+                        warmed.append(self._batch_key((bh, bw), iters, rows,
+                                                      mode))
         return warmed
 
     def warmup_stream(self, buckets=None, ladder: Sequence[int] = (),
@@ -524,27 +574,32 @@ class BatchEngine:
         shows the same phases as host events."""
         return getattr(self._seg, "last", None)
 
-    def _pad_pairs(self, pairs):
-        """Shared shape policy: per-pair BucketPadder padding plus batch-
-        axis zero-padding to ``max_batch_size``, so the compile cache is
-        keyed by bucket alone.  All pairs must map to one bucket (the
-        batcher groups by bucket before dispatching)."""
+    def _pad_pairs(self, pairs, rows: int):
+        """Shared shape policy: per-pair BucketPadder padding, staged as a
+        batch of ``rows`` — the dispatch's compiled batch shape: exactly
+        ``len(pairs)`` on the plain path (no zero row), ``max_batch_size``
+        on the warm-start one (zero rows behind the real ones) — so the
+        compile cache is keyed by bucket and row count alone.  All pairs
+        must map to one bucket (the batcher groups by bucket before
+        dispatching)."""
         assert pairs, "empty batch"
         # what the dispatch was asked for and what it computes: the real
-        # pairs' pixels and the padded batch's
+        # pairs' pixels and the staged batch's (equal on the plain path
+        # but for the bucket's own border)
         bh, bw = self.bucket_of(pairs[0][0].shape)
-        px = {"real_px": sum(p[0].shape[0] * p[0].shape[1] for p in pairs),
-              "bucket_px": bh * bw * self.cfg.max_batch_size}
+        px = {"rows": rows,
+              "real_px": sum(p[0].shape[0] * p[0].shape[1] for p in pairs),
+              "bucket_px": bh * bw * rows}
         with timed_phase("pad_bucket", batch_size=len(pairs), **px) as ph:
-            staged = self._stage_pairs(pairs)
+            staged = self._stage_pairs(pairs, rows)
         self._seg.pad = ph.window
         self._seg.pad_px = px
         return staged
 
-    def _stage_pairs(self, pairs):
-        assert len(pairs) <= self.cfg.max_batch_size, (
-            f"batch {len(pairs)} exceeds max_batch_size "
-            f"{self.cfg.max_batch_size}")
+    def _stage_pairs(self, pairs, rows: int):
+        assert len(pairs) <= rows <= self.cfg.max_batch_size, (
+            f"batch {len(pairs)} does not fit {rows} rows of "
+            f"max_batch_size {self.cfg.max_batch_size}")
         padders = [self._padder(p[0].shape) for p in pairs]
         hw = padders[0].bucket_hw
         assert all(p.bucket_hw == hw for p in padders), (
@@ -561,12 +616,17 @@ class BatchEngine:
                                     jnp.asarray(im2, jnp.float32)[None])
                 lefts.append(i1)
                 rights.append(i2)
-            pad_rows = self.cfg.max_batch_size - len(pairs)
+            pad_rows = rows - len(pairs)
+            if pad_rows:
+                # Warm-start path only (a plain dispatch is staged at its
+                # own length).  The rows nobody sent are ONE zero row,
+                # repeated: the eager programs below then depend on
+                # ``rows`` and never on the occupancy.
+                zero = jnp.zeros_like(lefts[0])
+                lefts += [zero] * pad_rows
+                rights += [zero] * pad_rows
             i1 = jnp.concatenate(lefts, axis=0)
             i2 = jnp.concatenate(rights, axis=0)
-            if pad_rows:
-                i1 = jnp.pad(i1, ((0, pad_rows), (0, 0), (0, 0), (0, 0)))
-                i2 = jnp.pad(i2, ((0, pad_rows), (0, 0), (0, 0), (0, 0)))
         return padders, hw, i1, i2, pad_rows
 
     def _dispatch(self, key, call):
@@ -639,15 +699,40 @@ class BatchEngine:
     def infer_batch(self, pairs: Sequence[Tuple[np.ndarray, np.ndarray]],
                     iters: int, mode: Optional[str] = None
                     ) -> List[np.ndarray]:
-        """Run a coalesced batch; returns one (H, W) disparity per pair.
+        """Run ``1 <= n <= max_batch_size`` pairs of one bucket; returns
+        one (H, W) disparity per pair.  A compiled row count
+        (``row_counts``) is one dispatch of exactly those rows — all the
+        batcher ever hands over, so one batch stays one dispatch; any
+        other ``n`` (direct callers) runs as the fewest dispatches of
+        compiled counts, in order (3 -> 1 + 1 + 1), and no reply rides
+        beside a zero row.
         ``mode`` is the resolved precision mode (None = the default
         path); the micro-batcher groups by it, so a batch is always
         single-mode."""
-        padders, hw, i1, i2, _ = self._pad_pairs(pairs)
+        assert 1 <= len(pairs) <= self.cfg.max_batch_size, (
+            f"batch {len(pairs)} outside 1..max_batch_size "
+            f"{self.cfg.max_batch_size}")
+        split = split_rows(len(pairs), self.row_counts)
+        if len(split) > 1:  # one dispatch checks its own (_stage_pairs)
+            buckets = {self.bucket_of(p[0].shape) for p in pairs}
+            assert len(buckets) == 1, (
+                f"mixed buckets in one batch: {sorted(buckets)}")
+        out, start = [], 0
+        for rows in split:
+            out += self._infer_rows(pairs[start:start + rows], iters, mode)
+            start += rows
+        return out
+
+    def _infer_rows(self, pairs, iters: int, mode: Optional[str]):
+        """One plain dispatch: ``len(pairs)`` is a compiled row count."""
+        rows = len(pairs)
+        padders, hw, i1, i2, _ = self._pad_pairs(pairs, rows)
         m = self._mode(mode)
-        key = (hw[0], hw[1], iters, "batch", self.input_mode, m)
+        key = self._batch_key(hw, iters, rows, m)
         (flow_up,), _ = self._dispatch(
             key, lambda: [self._fn(iters, m)(self.variables, i1, i2)[1]])
+        if self.metrics is not None:
+            self.metrics.batch_rows.labels(rows=str(rows)).inc()
         return [padder.unpad(flow_up[i:i + 1])[0, ..., 0]
                 for i, padder in enumerate(padders)]
 
@@ -666,10 +751,15 @@ class BatchEngine:
         field — the session state a stream forward-warps into the next
         frame's ``flow_init`` (kept padded so it is already at the shape
         the next dispatch needs) — and whether this call paid the XLA
-        compile.  Same bucket/batch-pad policy as ``infer_batch``.
+        compile.  Same bucket policy as ``infer_batch``; the batch axis
+        is always zero-padded to ``max_batch_size`` (one program a ladder
+        level, not one a row count: no record says what stream batches
+        look like, so this path is kept apart from the plain one's
+        rule).
         """
         assert len(pairs) == len(flow_inits), (len(pairs), len(flow_inits))
-        padders, hw, i1, i2, pad_rows = self._pad_pairs(pairs)
+        padders, hw, i1, i2, pad_rows = self._pad_pairs(
+            pairs, self.cfg.max_batch_size)
         lh, lw = self.low_hw(hw)
         inits = []
         with self._device_ctx():  # stage on this replica's device

@@ -297,8 +297,15 @@ class ServeMetrics:
         self.queue_depth = r.gauge(
             "serve_queue_depth", "requests currently waiting in the queue")
         self.batch_size = r.histogram(
-            "serve_batch_size", "real (un-padded) requests per batch",
+            "serve_batch_size", "requests per batch the batcher closed",
             bounds=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48, 64))
+        self.batch_rows = r.counter(
+            "serve_batch_rows_total",
+            "plain dispatches by compiled row count (serve/engine.py "
+            "row_counts: 1 and max_batch_size), each holding exactly that "
+            "many real rows; serve_batch_size counts the rows of a batch "
+            "the batcher closed",
+            labels=("rows",))
         self.latency = r.histogram(
             "serve_request_latency_seconds",
             "submit-to-result latency per request (queue wait + compute)")

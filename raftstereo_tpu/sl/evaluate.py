@@ -8,7 +8,9 @@ projector-shadow band carries no pattern signal, so predictions there are
 unconstrained; unmasked metrics on SL scenes are meaningless by design
 (sl/synthetic.py module docstring).
 
-Serving parity: pass ``batch_pad=engine.max_batch_size`` (plus the
+Serving parity: pass ``batch_pad`` = the row count of the engine's
+dispatch (1 for a pair served alone, ``max_batch_size`` for one of a full
+batch; serve/engine.py ``row_counts``) (plus the
 engine's ``divis_by``/``bucket_multiple``) and the underlying
 :class:`~raftstereo_tpu.eval.runner.Evaluator` executes each pair at the
 serving engine's padded program shape, making the returned disparities

@@ -247,7 +247,7 @@ def build_stream_engine(model, variables, image_hw: Tuple[int, int],
     For bitwise parity with an HTTP server, pass the SAME ``divis_by``,
     ``bucket_multiple`` and ``max_batch_size`` the server runs — XLA only
     guarantees identical numerics for identical program shapes, and the
-    engine pads every batch to ``max_batch_size``.
+    engine pads every warm-start batch to ``max_batch_size``.
     """
     from ..config import ServeConfig
     from ..serve.engine import BatchEngine

@@ -230,5 +230,9 @@ def convert_checkpoint(pth_path: str, config: RAFTStereoConfig,
     from ..models import RAFTStereo
 
     model = RAFTStereo(config)
-    template = model.init(jax.random.key(0), image_hw=image_hw)
+    # Structure, shapes and dtypes are all the template gives: traced, not
+    # run (an eager init is ~320 small programs on the device, tens of
+    # seconds of a server's start).
+    template = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), image_hw=image_hw))
     return torch_to_variables(load_state_dict(pth_path), template, config)

@@ -717,7 +717,9 @@ class TestClusterEndToEnd:
             ref_engine = BatchEngine(model, variables,
                                      _cfg(max_batch_size=2))
             a, b = _img(60, 90, 1), _img(60, 90, 2)
-            ref_cold = ref_engine.infer_batch([(a, b)], 4)[0]
+            # both rows filled: the program shape of the replicas'
+            # two-slot batches
+            ref_cold = ref_engine.infer_batch([(a, b)] * 2, 4)[0]
         assert server.is_ready
         port = server.port
         thread = threading.Thread(target=server.serve_forever, daemon=True)

@@ -99,7 +99,8 @@ def test_wide_row_took_more_than_one_pixel_block(wide_pair_fields):
     assert span.attrs["bucket"] == "64x1216"
     assert span.attrs["corr_block"] == facts["corr_block"]
     seg = engine.last_segments
-    assert seg["pad_px"] == {"real_px": 64 * 1216, "bucket_px": 64 * 1216}
+    assert seg["pad_px"] == {"rows": 1, "real_px": 64 * 1216,
+                             "bucket_px": 64 * 1216}
 
 
 # ------------------------------------------------ (b) the lookup's blocks

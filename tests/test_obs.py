@@ -491,9 +491,10 @@ class TestEndToEnd:
                                endpoint="predict", outcome="bad_request") == 1
 
         # The engine-level view of the same invariant: warmup paid the
-        # only compile, traffic added no cache keys.
+        # only compiles (one a row count), traffic added no cache keys.
         assert set(server.engine.compiled_keys) == compiled_before
-        assert server.metrics.compile_misses.value == 1
+        assert server.metrics.compile_misses.value \
+            == len(compiled_before) == 2
 
         # Overhead: per-span record cost x spans-per-request under 2% of
         # the observed latency (measured, not assumed).
@@ -509,6 +510,67 @@ class TestEndToEnd:
         spans_per_request = len(events)
         assert spans_per_request * per_span < 0.02 * observed_latency
         client.close()
+
+    def test_spans_and_counter_say_the_row_count_and_no_row_is_zero(
+            self, obs_server):
+        """``rows`` rides on the ``dispatch`` and ``pad_bucket`` spans and
+        on the bucket's ``compile`` span, ``serve_batch_rows_total``
+        counts the dispatches by it, and no plain dispatch holds a row
+        nobody sent: ``rows == batch_size`` and, for bucket-sized pairs,
+        ``real_px == bucket_px``."""
+        server = obs_server
+        assert server.engine.row_counts == (1, 2)
+        counted = {lv[0]: c.value
+                   for lv, c in server.metrics.batch_rows.series()}
+        pair = (_img(64, 96, 5), _img(64, 96, 6))  # bucket-sized: 64x96
+        rids = []
+
+        def send():
+            client = ServeClient("127.0.0.1", server.port, timeout=120)
+            rids.append(client.predict(*pair)[1]["request_id"])
+            client.close()
+
+        send()  # alone: one row
+        threads = [threading.Thread(target=send) for _ in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        assert len(rids) == 5
+        spans = server.tracer.spans()
+        mine = [s for s in spans if s.trace_id in rids]
+        dispatches = [s for s in mine if s.name == "dispatch"]
+        pads = [s for s in mine if s.name == "pad_bucket"]
+        assert len(dispatches) == len(pads) == 5
+        for s in dispatches:
+            assert s.attrs["rows"] == s.attrs["batch_size"]
+            assert s.attrs["rows"] in (1, 2)
+        for s in pads:
+            rows = s.attrs["rows"]
+            assert s.attrs == {"rows": rows, "real_px": rows * 64 * 96,
+                               "bucket_px": rows * 64 * 96}
+        first = [s for s in dispatches if s.trace_id == rids[0]]
+        assert first[0].attrs["rows"] == 1
+        # once a dispatch, under the batch's own trace, with the same facts
+        batch_pads = [s for s in spans if s.name == "pad_bucket"
+                      and (s.trace_id or "").startswith("batch:")
+                      and set(s.attrs["request_ids"]) & set(rids)]
+        assert sum(s.attrs["rows"] for s in batch_pads) == 5
+        for s in batch_pads:
+            assert s.attrs["rows"] == s.attrs["batch_size"] \
+                == len(s.attrs["request_ids"])
+            assert s.attrs["real_px"] == s.attrs["bucket_px"]
+        after = {lv[0]: c.value
+                 for lv, c in server.metrics.batch_rows.series()}
+        for rows in (1, 2):
+            assert after.get(str(rows), 0) - counted.get(str(rows), 0) \
+                == sum(s.attrs["rows"] == rows for s in batch_pads)
+        # the programs' own compile spans name their row count
+        compiled = {s.attrs["rows"] for s in spans if s.name == "compile"
+                    and s.attrs.get("kind") == "bucket"}
+        assert compiled == {1, 2}
+        programs = server.engine.compiled_programs
+        assert sorted(p["rows"] for p in programs.values()) == [1, 2]
 
     def test_debug_vars_threads_profile(self, obs_server):
         server = obs_server
@@ -823,33 +885,39 @@ class TestLightCapture:
 class TestCompileAndMemoryInstruments:
     def test_compile_counter_sees_the_staging_programs(self):
         """The engine stages a batch with eager ops whose shapes depend on
-        the number of real rows: a new occupancy is a new program, which
-        ``serve_xla_compiles_total`` counts (once) and the engine's own
-        hit/miss counters never saw."""
+        the row count staged and never on the number of real rows (on the
+        plain path the two are equal; the warm-start path stages zero
+        rows behind the real ones): a new row count is new programs,
+        which ``serve_xla_compiles_total`` counts (once) and the engine's
+        own hit/miss counters never saw; a new occupancy of a row count
+        already staged is none."""
         from raftstereo_tpu.serve.engine import BatchEngine
         from raftstereo_tpu.serve.server import watch_xla_compiles
 
         metrics, tracer = ServeMetrics(), Tracer(capacity=64)
-        # a bucket no other test of this process stages at
+        # a bucket (64x160) no other test of this process stages at
         cfg = ServeConfig(max_batch_size=2, bucket_multiple=32,
-                          buckets=((40, 72),))
+                          buckets=((40, 136),))
         engine = BatchEngine(None, {}, cfg, metrics)
-        pair = (np.ones((40, 72, 3), np.float32),) * 2
+        pair = (np.ones((40, 136, 3), np.float32),) * 2
         unwatch = watch_xla_compiles(metrics, tracer)
         try:
-            engine._pad_pairs([pair])           # occupancy 1: several
+            engine._pad_pairs([pair], 1)        # one row: several
             before = metrics.xla_compiles.value
             assert before >= 1
-            engine._pad_pairs([pair, pair])     # occupancy 2: concatenate
-            assert metrics.xla_compiles.value == before + 1
-            engine._pad_pairs([pair, pair])     # staged before: none
-            assert metrics.xla_compiles.value == before + 1
+            # two rows, one of them real: the zero row and concatenate
+            engine._pad_pairs([pair], 2)
+            staged = metrics.xla_compiles.value
+            assert staged >= before + 2
+            engine._pad_pairs([pair, pair], 2)  # another occupancy: none
+            engine._pad_pairs([pair], 2)        # staged before: none
+            assert metrics.xla_compiles.value == staged
         finally:
             unwatch()
-        engine._pad_pairs([(np.ones((33, 70, 3), np.float32),) * 2])
-        assert metrics.xla_compiles.value == before + 1     # unsubscribed
+        engine._pad_pairs([(np.ones((33, 130, 3), np.float32),) * 2], 1)
+        assert metrics.xla_compiles.value == staged     # unsubscribed
         compiles = [s for s in tracer.spans() if s.name == "compile"]
-        assert len(compiles) == before + 1
+        assert len(compiles) == staged
         assert {s.attrs["kind"] for s in compiles} == {"compile"}
         assert 'serve_xla_compiles_total{kind="compile"}' in metrics.render()
 

@@ -262,9 +262,10 @@ class TestEngineTiers:
         a, b = _img(seed=1), _img(seed=2)
 
         warmed = eng.warmup(iters_list=[2], modes=["fp32", "bf16", "int8"])
-        assert sorted(warmed) == [(64, 96, 2, "batch", "passive", "bf16"),
-                                  (64, 96, 2, "batch", "passive", "fp32"),
-                                  (64, 96, 2, "batch", "passive", "int8")]
+        # every row count (max_batch_size 2 -> 1, 2) of every tier
+        assert sorted(warmed) == [
+            (64, 96, 2, "batch", f"r{rows}", "passive", mode)
+            for rows in (1, 2) for mode in ("bf16", "fp32", "int8")]
         # Stream + sched tier executables (bf16 exercises a non-default
         # mode through BOTH split paths).
         eng.warmup_stream(ladder=[2], modes=["bf16"])

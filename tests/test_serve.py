@@ -3,11 +3,12 @@
 Batcher policy tests run against a stub engine (no model cost) so timing
 assertions stay tight; engine and end-to-end tests use a tiny real model.
 The end-to-end test is the subsystem's acceptance gate: concurrent
-mixed-shape requests over real HTTP, one compile per bucket, responses
-bitwise-equal to the single-image Evaluator, overload sheds rather than
-deadlocks, metrics non-zero.
+mixed-shape requests over real HTTP, one compile per (bucket, row count)
+met, responses bitwise-equal to the Evaluator at the dispatch's row
+count, overload sheds rather than deadlocks, metrics non-zero.
 """
 
+import contextlib
 import json
 import threading
 import time
@@ -192,6 +193,136 @@ class TestBatcher:
         assert all(r.iters == 3 and not r.degraded for r in res1)
 
 
+class RowCountStub(StubEngine):
+    """A stub that names its compiled row counts, as ``BatchEngine`` does,
+    and records which requests (their left image's first value) rode in
+    each dispatch."""
+
+    def __init__(self, row_counts, **kw):
+        super().__init__(**kw)
+        if row_counts is not None:
+            self.row_counts = row_counts
+        self.rode = []
+
+    def infer_batch(self, pairs, iters, mode=None):
+        out = super().infer_batch(pairs, iters, mode=mode)
+        self.rode.append([int(p[0][0, 0, 0]) for p in pairs])
+        return out
+
+
+def _tagged(i):
+    """A pair whose left image carries ``i`` — the request's identity in
+    ``RowCountStub.rode``."""
+    return np.full((60, 90, 3), i, np.float32), _img()
+
+
+class TestBatcherTakeRule:
+    """A closing batch takes the largest compiled row count the queued
+    rows fill and leaves the rest queued, first in first out."""
+
+    @staticmethod
+    @contextlib.contextmanager
+    def _parked(eng, cfg, metrics=None):
+        """A started batcher whose worker is parked in the engine on a
+        sentinel request, so what is submitted inside queues up behind
+        it; leaving releases the gate and waits the sentinel out."""
+        b = DynamicBatcher(eng, cfg, metrics).start()
+        try:
+            sentinel = b.submit(*_tagged(-1))
+            deadline = time.perf_counter() + 5.0
+            while b.queue_depth and time.perf_counter() < deadline:
+                time.sleep(0.002)
+            yield b
+            eng.gate.set()
+            sentinel.result(timeout=10)
+        finally:
+            eng.gate.set()
+            b.stop()
+
+    @classmethod
+    def _drain(cls, eng, cfg, n, metrics=None):
+        """Queue ``n`` tagged requests behind a parked worker, release:
+        the dispatches that answered them."""
+        with cls._parked(eng, cfg, metrics) as b:
+            futs = [b.submit(*_tagged(i)) for i in range(n)]
+            time.sleep(0.02)  # every deadline (1 ms) has passed
+        res = [f.result(timeout=10) for f in futs]
+        assert eng.rode[0] == [-1]
+        return eng.rode[1:], res
+
+    @pytest.mark.parametrize("queued,want", [
+        (1, [1]), (7, [1] * 7), (8, [8]), (11, [8, 1, 1, 1]),
+        (17, [8, 8, 1])])
+    def test_takes_largest_compiled_count_and_leaves_the_rest(
+            self, queued, want):
+        eng = RowCountStub((1, 8), gate=threading.Event())
+        cfg = _cfg(max_batch_size=8, max_wait_ms=1.0, queue_limit=64,
+                   degrade_queue_depth=64)
+        metrics = ServeMetrics()
+        rode, res = self._drain(eng, cfg, queued, metrics)
+        assert [len(r) for r in rode] == want
+        # first in, first out: across dispatches and inside one
+        assert [i for r in rode for i in r] == list(range(queued))
+        # a reply says the size of the dispatch it rode in
+        assert [r.batch_size for r in res] \
+            == [len(r) for r in rode for _ in r]
+        assert metrics.responses.value == queued + 1
+        assert metrics.batch_size.count == len(want) + 1
+
+    @pytest.mark.parametrize("queued,want", [
+        (3, [3]), (7, [7]), (11, [8, 3])])
+    def test_engine_without_counts_takes_what_is_queued(self, queued, want):
+        eng = RowCountStub(None, gate=threading.Event())
+        assert not hasattr(eng, "row_counts")
+        cfg = _cfg(max_batch_size=8, max_wait_ms=1.0, queue_limit=64,
+                   degrade_queue_depth=64)
+        rode, _ = self._drain(eng, cfg, queued)
+        assert [len(r) for r in rode] == want
+        assert [i for r in rode for i in r] == list(range(queued))
+
+    def test_a_mid_size_count_is_taken_when_the_engine_has_one(self):
+        """The rule is written over the tuple: an engine with a four-row
+        program gets four of six queued rows, then one and one."""
+        eng = RowCountStub((1, 4, 8), gate=threading.Event())
+        cfg = _cfg(max_batch_size=8, max_wait_ms=1.0, queue_limit=64,
+                   degrade_queue_depth=64)
+        rode, _ = self._drain(eng, cfg, 6)
+        assert [len(r) for r in rode] == [4, 1, 1]
+
+    def test_backlog_counts_the_rows_left_queued(self):
+        """Degradation reads the backlog at batch close, the rows a take
+        leaves behind included: 7 queued run as singles at backlogs
+        7, 6, ... 1 — degraded while the backlog is >= 4."""
+        eng = RowCountStub((1, 8), gate=threading.Event())
+        cfg = _cfg(max_batch_size=8, max_wait_ms=1.0, queue_limit=64,
+                   iters=8, degraded_iters=2, degrade_queue_depth=4)
+        metrics = ServeMetrics()
+        _, res = self._drain(eng, cfg, 7, metrics)
+        assert [r.degraded for r in res] == [True] * 4 + [False] * 3
+        assert [it for _, it in eng.batches[1:]] == [2] * 4 + [8] * 3
+        assert metrics.degraded_batches.value == 4
+
+    def test_timed_out_rows_leave_with_the_batch_and_are_not_counted(self):
+        """Requests past ``request_timeout_ms`` head the queue: they are
+        failed with the batch that closes, and the rows taken are counted
+        among the live ones — the engine still gets a compiled count."""
+        eng = RowCountStub((1, 8), gate=threading.Event())
+        cfg = _cfg(max_batch_size=8, max_wait_ms=1.0, queue_limit=64,
+                   request_timeout_ms=150.0, degrade_queue_depth=64)
+        metrics = ServeMetrics()
+        with self._parked(eng, cfg, metrics) as b:
+            stale = [b.submit(*_tagged(100 + i)) for i in range(3)]
+            time.sleep(0.2)  # the three are past their time-out
+            fresh = [b.submit(*_tagged(i)) for i in range(8)]
+        for f in stale:
+            with pytest.raises(RequestTimedOut):
+                f.result(timeout=10)
+        res = [f.result(timeout=10) for f in fresh]
+        assert eng.rode == [[-1], list(range(8))]
+        assert all(r.batch_size == 8 for r in res)
+        assert metrics.timeouts.value == 3
+
+
 # ------------------------------------------------------------------- engine
 
 class TestEngine:
@@ -204,19 +335,25 @@ class TestEngine:
         cfg = _cfg(max_batch_size=2, iters=2, degraded_iters=1,
                    buckets=((60, 90),))
         eng = BatchEngine(model, variables, cfg)
-        # Warmup compiles the configured bucket at BOTH iteration levels.
+        # Warmup compiles the configured bucket at BOTH iteration levels,
+        # each at BOTH row counts (max_batch_size 2 -> 1, 2).
         warmed = eng.warmup()
-        assert sorted(warmed) == [(64, 96, 1, "batch", "passive", "fp32"),
-                                  (64, 96, 2, "batch", "passive", "fp32")]
+        assert sorted(warmed) == [
+            (64, 96, it, "batch", f"r{rows}", "passive", "fp32")
+            for it in (1, 2) for rows in (1, 2)]
+        assert eng.warmup() == []  # all warm: nothing left to compile
         a, b = _img(60, 90, 1), _img(64, 96, 2)  # same 64x96 bucket
         eng.infer_batch([(a, a)], iters=2)
         assert not eng.last_included_compile  # warmup paid the compile
         out = eng.infer_batch([(a, a), (b, b)], iters=2)
-        assert not eng.last_included_compile  # padded batch: same executable
+        assert not eng.last_included_compile  # two rows: warmed as well
         assert out[0].shape == (60, 90) and out[1].shape == (64, 96)
-        eng.infer_batch([(_img(70, 100, 3),) * 2], iters=2)  # 96x128 bucket
+        # 96x128 bucket: one (bucket, row count) more, and only that one
+        eng.infer_batch([(_img(70, 100, 3),) * 2], iters=2)
         assert eng.last_included_compile
-        assert eng.cache_stats == {"compiled": 3}
+        assert not eng.is_warm((96, 128), 2)  # its two-row program is cold
+        assert eng.is_warm((96, 128), 2, rows=1)
+        assert eng.cache_stats == {"compiled": 5}
 
     def test_rejects_mixed_buckets_and_oversize(self, serve_model):
         model, variables = serve_model
@@ -228,8 +365,159 @@ class TestEngine:
 
 
 class _KeyCaptured(Exception):
-    """Raised by the dispatch spy of ``TestKeyKinds`` in place of the
-    device work, carrying the cache key the entry point built."""
+    """Raised by a dispatch spy in place of the device work, carrying the
+    cache key the entry point built."""
+
+
+def _capture_key(key, call):
+    raise _KeyCaptured(key)
+
+
+class TestRowCounts:
+    """A plain dispatch holds exactly a compiled row count of real rows —
+    one row or ``max_batch_size`` — and ``infer_batch`` runs any other
+    ``n`` as the fewest such dispatches, in order.  No program runs: a spy
+    takes the key and the staged batch where the device call would be."""
+
+    COUNTS = {1: (1,), 2: (1, 2), 6: (1, 6), 8: (1, 8)}
+
+    @pytest.mark.parametrize("max_batch", sorted(COUNTS))
+    def test_row_counts_are_one_and_max_batch_size(self, max_batch):
+        from raftstereo_tpu.serve.engine import row_counts, split_rows
+
+        counts = row_counts(max_batch)
+        assert counts == self.COUNTS[max_batch]
+        assert BatchEngine(None, {}, _cfg(
+            max_batch_size=max_batch)).row_counts == counts
+        for n in range(1, 2 * max_batch + 1):
+            split = split_rows(n, counts)
+            assert sum(split) == n and set(split) <= set(counts)
+            assert split == [max_batch] * (n // max_batch) \
+                + [1] * (n % max_batch)
+
+    @pytest.mark.parametrize("max_batch,n", [
+        (m, n) for m in sorted(COUNTS) for n in range(1, m + 1)])
+    def test_n_pairs_run_as_fewest_dispatches_with_no_zero_row(
+            self, max_batch, n):
+        metrics = ServeMetrics()
+        eng = BatchEngine(None, {}, _cfg(max_batch_size=max_batch,
+                                         queue_limit=32), metrics)
+        seen = []
+
+        def spy(key, call):
+            rows = int(key[4][1:])
+            seen.append((key, dict(eng._seg.pad_px)))
+            return [np.zeros((rows, 32, 64, 1), np.float32)], False
+
+        eng._dispatch = spy
+        # bucket-sized images (32x64): every staged pixel is a real one
+        pairs = [(_img(32, 64, i), _img(32, 64, 50 + i)) for i in range(n)]
+        out = eng.infer_batch(pairs, 3)
+        assert len(out) == n and all(d.shape == (32, 64) for d in out)
+        want = [n] if n == max_batch else [1] * n
+        assert [k for k, _ in seen] == [
+            (32, 64, 3, "batch", f"r{rows}", "passive", "fp32")
+            for rows in want]
+        for (key, px), rows in zip(seen, want):
+            assert px == {"rows": rows, "real_px": rows * 32 * 64,
+                          "bucket_px": rows * 32 * 64}
+            assert eng._program_facts(key) == {"rows": rows}
+        for rows in set(want):
+            assert metrics.batch_rows.labels(rows=str(rows)).value \
+                == want.count(rows)
+        assert metrics.batch_rows.value == len(want)
+
+    @pytest.mark.parametrize("n", [1, 2, 4])
+    def test_plain_staging_holds_the_rows_in_order(self, n):
+        """What ``_stage_pairs`` hands the program at ``rows == n``: the
+        ``n`` real rows in order, and nothing else."""
+        eng = BatchEngine(None, {}, _cfg(max_batch_size=4))
+        pairs = [(_img(20, 40, i), _img(20, 40, 9 + i)) for i in range(n)]
+        padders, hw, i1, i2, pad_rows = eng._pad_pairs(pairs, n)
+        assert hw == (32, 64) and pad_rows == 0
+        assert i1.shape == i2.shape == (n, 32, 64, 3)
+        for i, (padder, (a, b)) in enumerate(zip(padders, pairs)):
+            np.testing.assert_array_equal(
+                padder.unpad(np.asarray(i1[i:i + 1]))[0], a)
+            np.testing.assert_array_equal(
+                padder.unpad(np.asarray(i2[i:i + 1]))[0], b)
+
+    def test_stream_batch_still_pads_to_max_batch_size(self, serve_model):
+        """The warm-start path keeps one program a ladder level: every
+        occupancy is zero-padded to ``max_batch_size`` under the old
+        key."""
+        model, variables = serve_model
+        eng = BatchEngine(model, variables, _cfg(max_batch_size=4))
+        staged = []
+        stage = eng._stage_pairs
+
+        def spy_stage(pairs, rows):
+            staged.append(stage(pairs, rows))
+            return staged[-1]
+
+        eng._stage_pairs = spy_stage
+        eng._dispatch = _capture_key
+        a = _img()
+        for n in (1, 3):
+            with pytest.raises(_KeyCaptured) as ei:
+                eng.infer_stream_batch([(a, a)] * n, 3, [None] * n)
+            assert ei.value.args == ((64, 96, 3, "stream", "passive",
+                                      "fp32"),)
+            assert eng._seg.pad_px == {"rows": 4, "real_px": n * 60 * 90,
+                                       "bucket_px": 4 * 64 * 96}
+            _, _, i1, i2, pad_rows = staged[-1]
+            assert i1.shape == i2.shape == (4, 64, 96, 3)
+            assert pad_rows == 4 - n
+            assert np.asarray(i1[:n]).any()
+            assert not np.asarray(i1[n:]).any()  # the rows nobody sent
+
+
+class TestRowCountEngine:
+    """One warmed engine (``max_batch_size`` 3 -> row counts 1, 3),
+    through every ``n`` a direct caller can hand ``infer_batch``."""
+
+    @pytest.fixture(scope="class")
+    def warm_engine(self, serve_model):
+        model, variables = serve_model
+        metrics = ServeMetrics()
+        cfg = _cfg(max_batch_size=3, iters=2, degraded_iters=2)
+        eng = BatchEngine(model, variables, cfg, metrics)
+        warmed = eng.warmup()
+        assert warmed == [
+            (64, 96, 2, "batch", f"r{rows}", "passive", "fp32")
+            for rows in (1, 3)]
+        assert metrics.compile_misses.value == 2
+        return eng, metrics
+
+    @pytest.mark.parametrize("n", [1, 2, 3])
+    def test_warm_n_compiles_nothing_and_matches_evaluator(
+            self, warm_engine, retrace_guard, n):
+        """After ``warmup()`` no ``n`` meets a program for the first
+        time — the eager staging programs included, so the guard has no
+        duration floor — and every reply is bitwise the Evaluator's at
+        ``batch_pad`` = the row count of the dispatch it rode in."""
+        from raftstereo_tpu.eval import Evaluator
+
+        eng, metrics = warm_engine
+        rows = n if n in eng.row_counts else 1
+        pairs = [(_img(seed=10 + i), _img(seed=20 + i)) for i in range(n)]
+        before = metrics.batch_rows.labels(rows=str(rows)).value
+        misses = metrics.compile_misses.value
+        with retrace_guard(0, what=f"{n} pairs after warmup: staging "
+                                   "and model programs all warm"):
+            out = eng.infer_batch(pairs, 2)
+        assert not eng.last_included_compile
+        assert metrics.compile_misses.value == misses
+        assert eng.compiled_keys == {
+            (64, 96, 2, "batch", f"r{r}", "passive", "fp32")
+            for r in (1, 3)}
+        assert metrics.batch_rows.labels(rows=str(rows)).value \
+            == before + n // rows
+        assert eng.last_segments["pad_px"]["rows"] == rows
+        ev = Evaluator(eng.model, eng.variables, iters=2, divis_by=32,
+                       bucket_multiple=32, batch_pad=rows)
+        for disp, pair in zip(out, pairs):
+            np.testing.assert_array_equal(disp, ev(*pair))
 
 
 class TestKeyKinds:
@@ -297,6 +585,8 @@ class TestKeyKinds:
         assert sorted(keys) == sorted(self.KINDS)
         key, via = keys[kind]
         assert key[:2] == (64, 96) and key[3] == kind
+        if kind == "batch":  # the row count rides right after the kind
+            assert key == (64, 96, 3, "batch", "r1", "passive", "fp32")
         assert [k for k, (other, _) in keys.items() if other == key] \
             == [kind]
         sorted(k for k, _ in keys.values())  # /healthz sorts the mixed set
@@ -316,11 +606,51 @@ class TestKeyKinds:
 
         # The readers: only the kind's own predicate sees its key, and
         # only a plain batch program has facts to show.
-        assert eng.is_warm((64, 96), 3) == (kind == "batch")
+        assert eng.is_warm((64, 96), 3, rows=1) == (kind == "batch")
+        assert not eng.is_warm((64, 96), 3)  # never every row count
         assert eng.is_stream_warm((64, 96), 3) == (kind == "stream")
         assert eng.is_spatial_warm((64, 96), 3) == (kind == "spatial")
         assert bool(eng._program_facts(key)) == (kind == "batch")
+        assert eng._program_facts(key).get("rows") \
+            == (1 if kind == "batch" else None)
         assert len(eng.compiled_programs) == (kind == "batch")
+
+
+class TestConvertCheckpoint:
+    def test_eval_shape_template_gives_the_eager_templates_variables(
+            self, tmp_path):
+        """``convert_checkpoint`` takes structure, shapes and dtypes from a
+        traced ``model.init`` (no device program runs at a server's
+        start); the variables are bitwise those the eager template
+        gives."""
+        import os
+        import sys
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        sys.path.insert(0, repo)
+        from benchmark.weights import make_weights, write_pth
+        from raftstereo_tpu.models import RAFTStereo
+        from raftstereo_tpu.utils.convert import (convert_checkpoint,
+                                                  load_state_dict,
+                                                  torch_to_variables)
+
+        with open(os.path.join(repo, "benchmark", "configs",
+                               "raftstereo_default.json")) as f:
+            weights = make_weights(json.load(f)["model"], 11)
+        path = str(tmp_path / "seeded.pth")
+        write_pth(weights, path)
+        config = RAFTStereoConfig()
+        got = convert_checkpoint(path, config)
+        eager = torch_to_variables(
+            load_state_dict(path),
+            RAFTStereo(config).init(jax.random.key(0), image_hw=(64, 96)),
+            config)
+        assert jax.tree.structure(got) == jax.tree.structure(eager)
+        leaves = jax.tree.leaves(got)
+        assert len(leaves) > 100
+        for a, b in zip(leaves, jax.tree.leaves(eager)):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ------------------------------------------------------------ metrics + wire
@@ -334,6 +664,8 @@ class TestMetrics:
         m.queue_depth.set(2)
         m.latency.observe(0.05)
         m.batch_size.observe(4)
+        m.batch_rows.labels(rows="4").inc()
+        m.batch_rows.labels(rows="8").inc(2)
         m.compile_misses.labels(bucket="64x96", iters="8", mode="batch",
                                 tier="fp32").inc()
         text = m.render()
@@ -351,8 +683,23 @@ class TestMetrics:
         assert "serve_queue_depth 2" in text
         assert 'serve_request_latency_seconds_bucket{le="+Inf"} 1' in text
         assert "serve_batch_size_count 1" in text
+        assert 'serve_batch_rows_total{rows="4"} 1' in text
+        assert 'serve_batch_rows_total{rows="8"} 2' in text
+        assert m.batch_rows.value == 3
         assert ('serve_compile_cache_misses_total{bucket="64x96",iters="8",'
                 'mode="batch",tier="fp32"} 1') in text
+
+    def test_batch_rows_family_passes_the_metrics_lint(self):
+        """``serve_batch_rows_total`` is one of the families the analysis
+        runner's metrics pass instantiates, populates and validates."""
+        from raftstereo_tpu.analysis.metrics_lint import run_metrics_lint
+        from raftstereo_tpu.obs import lint_registry
+
+        m = ServeMetrics()
+        m.batch_rows.labels(rows="2").inc()
+        assert "# TYPE serve_batch_rows_total counter" in m.render()
+        assert lint_registry(m.registry.entries()) == []
+        assert run_metrics_lint() == []
 
     def test_duplicate_metric_name_rejected(self):
         from raftstereo_tpu.serve import MetricsRegistry
@@ -405,12 +752,14 @@ class TestEndToEnd:
                                             retrace_guard):
         """Acceptance gate: concurrent mixed-shape traffic over real HTTP.
 
-        Asserts (1) each bucket compiled exactly once — enforced both at
-        the engine cache level and by the retrace guard counting actual
-        XLA compiles (budget 2 for the cold traffic, budget 0 once warm),
-        (2) responses equal the single-image Evaluator bitwise at the
-        same iteration count, (3) overload sheds instead of deadlocking,
-        (4) /metrics reports non-zero batch-size and latency histograms.
+        Asserts (1) each (bucket, row count) the traffic met compiled
+        exactly once — enforced both at the engine cache level and by
+        the retrace guard counting actual XLA compiles (one per key met
+        for the cold traffic, budget 0 once warm), (2) responses equal
+        the Evaluator bitwise at the same iteration count and the row
+        count of the dispatch, (3) overload sheds instead of deadlocking,
+        (4) /metrics reports non-zero batch-size and latency histograms
+        and the dispatches by row count.
         """
         from raftstereo_tpu.eval import Evaluator
 
@@ -443,12 +792,14 @@ class TestEndToEnd:
                 except Exception as e:  # pragma: no cover - failure detail
                     errors.append(e)
 
-            # (1) one compile per (bucket, iters): batch padding makes the
-            # executable independent of the coalesced batch size.  The
-            # retrace guard counts ACTUAL XLA compiles (model-scale via
-            # the 0.5 s floor): 2 buckets -> budget 2, however the 6
-            # requests interleave.
-            with retrace_guard(2, what="2 buckets compile exactly once",
+            # (1) one compile per (bucket, iters, row count) met: the
+            # executable depends on the row count of the dispatch (one
+            # row, or a full batch of four when four were queued), and
+            # on nothing else.  The retrace guard counts ACTUAL XLA
+            # compiles (model-scale via the 0.5 s floor): 2 buckets x row
+            # counts 1, 4 -> at most 4, however the 6 requests interleave.
+            with retrace_guard(4, what="each (bucket, row count) met "
+                                       "compiles exactly once",
                                min_duration_s=0.5) as cold_report:
                 threads = [threading.Thread(target=send, args=(i, s))
                            for i in range(2) for s in shapes]
@@ -458,26 +809,44 @@ class TestEndToEnd:
                     t.join(120)
                 assert not errors, errors
                 assert len(results) == 6
-            # EXACTLY 2, not just <= 2: if the 0.5 s floor ever rises
-            # above the real compile time, the warm budget-0 guards below
-            # would pass vacuously — this assert makes that loud.
-            assert cold_report.compiles == 2, cold_report.durations
-            assert server.engine.compiled_keys == {
-                (64, 96, 3, "batch", "passive", "fp32"),
-                (96, 128, 3, "batch", "passive", "fp32")}
-            assert metrics.compile_misses.value == 2
+            # The keys met are those of the dispatches the replies rode
+            # in: meta's batch_size IS a compiled row count, no reply
+            # rode beside a zero row.
+            engine = server.engine
+            assert engine.row_counts == (1, 4)
+            assert {meta["batch_size"] for _, meta in results.values()} \
+                <= set(engine.row_counts)
+            met = {(*engine.bucket_of(shape + (3,)), 3, "batch",
+                    f"r{meta['batch_size']}", "passive", "fp32")
+                   for (_, shape), (_, meta) in results.items()}
+            assert engine.compiled_keys == met
+            assert {k[:2] for k in met} == {(64, 96), (96, 128)}
+            # EXACTLY one a key, not just <= 4: if the 0.5 s floor ever
+            # rises above the real compile time, the warm budget-0 guards
+            # below would pass vacuously — this assert makes that loud.
+            assert cold_report.compiles == len(met), cold_report.durations
+            assert metrics.compile_misses.value == len(met)
 
-            # (2) bitwise equality with the single-image Evaluator under
-            # the same shape policy: shared BucketPadder, same iters, and
-            # batch_pad = the engine's padded batch size (XLA only
+            # (2) bitwise equality with the Evaluator under the same
+            # shape policy: shared BucketPadder, same iters, and
+            # batch_pad = the row count of the dispatch (XLA only
             # guarantees identical numerics for identical program shapes).
-            ev = Evaluator(model, variables, iters=3, divis_by=32,
-                           bucket_multiple=32,
-                           batch_pad=cfg.max_batch_size)
+            evs = {rows: Evaluator(model, variables, iters=3, divis_by=32,
+                                   bucket_multiple=32, batch_pad=rows)
+                   for rows in engine.row_counts}
             for (_, shape), (disp, meta) in results.items():
-                expected = ev(*pairs[shape])
+                ev = evs[meta["batch_size"]]
                 assert disp.shape == shape
-                np.testing.assert_array_equal(disp, expected)
+                np.testing.assert_array_equal(disp, ev(*pairs[shape]))
+
+            # The burst below may form any batch of the 64x96 bucket:
+            # warm what is left of its row counts first, as a started
+            # server has (``warmup`` skips what the traffic compiled).
+            warmed = engine.warmup(buckets=[(60, 90)], iters_list=[3])
+            assert engine.is_warm((64, 96), 3)
+            assert not set(warmed) & met
+            n_keys = len(met) + len(warmed)
+            assert metrics.compile_misses.value == n_keys
 
             # (3) overload: a burst far past queue_limit must shed with
             # clean 503s, and every accepted request completes.  Warm
@@ -486,16 +855,19 @@ class TestEndToEnd:
             with retrace_guard(0, what="burst + explicit iters reuse "
                                        "warm executables",
                                min_duration_s=0.5):
+                # 32 callers against 4 rows in flight + 8 queued: single
+                # rows ride the one-row program and drain fast, so the
+                # burst has to be well past what the queue holds
                 burst_stats = run_load(
                     "127.0.0.1", port, lambda i: pairs[(60, 90)],
-                    requests=30, concurrency=15, timeout=120)
+                    requests=64, concurrency=32, timeout=120)
                 assert burst_stats["shed"] > 0, burst_stats
                 assert burst_stats["ok"] + burst_stats["shed"] \
-                    + burst_stats["timeout"] == 30
+                    + burst_stats["timeout"] == 64
                 assert burst_stats["error"] == 0
                 # No new compiles: the burst reused the warm 64x96
-                # executable.
-                assert metrics.compile_misses.value == 2
+                # executables.
+                assert metrics.compile_misses.value == n_keys
                 assert metrics.compile_hits.value >= 1
 
             # (4) observability: batch + latency histograms are non-zero
@@ -512,17 +884,24 @@ class TestEndToEnd:
             assert sample("serve_request_latency_seconds_count") > 0
             assert sample("serve_request_latency_seconds_sum") > 0
             assert sample("serve_responses_total") >= 6
+            # every plain dispatch is counted under its row count
+            by_rows = {lv[0]: c.value
+                       for lv, c in metrics.batch_rows.series()}
+            assert set(by_rows) <= {"1", "4"}
+            assert sum(by_rows.values()) == sample("serve_batch_size_count")\
+                + len(warmed)
+            assert "# TYPE serve_batch_rows_total counter" in text
 
             # Explicit iters: configured levels are served (warm
             # executable), anything else is a 400 — never a fresh compile.
             disp, meta = client.predict(*pairs[(60, 90)], iters=3)
-            assert meta["iters"] == 3
-            np.testing.assert_array_equal(disp, ev(*pairs[(60, 90)]))
+            assert meta["iters"] == 3 and meta["batch_size"] == 1
+            np.testing.assert_array_equal(disp, evs[1](*pairs[(60, 90)]))
             from raftstereo_tpu.serve import ServeError
             with pytest.raises(ServeError) as ei:
                 client.predict(*pairs[(60, 90)], iters=7)
             assert ei.value.status == 400
-            assert metrics.compile_misses.value == 2  # still just the two
+            assert metrics.compile_misses.value == n_keys  # and no more
 
             # Admission caps reject before any decode or compile: image
             # side over max_image_dim -> 400, body over max_body_mb -> 413.
@@ -543,7 +922,7 @@ class TestEndToEnd:
             except (BrokenPipeError, ConnectionResetError):
                 pass
             conn.close()
-            assert metrics.compile_misses.value == 2  # caps cost no compile
+            assert metrics.compile_misses.value == n_keys  # caps cost none
 
             # A POSTed body to a wrong path must be drained, not parsed as
             # the next request on this keep-alive connection.
@@ -561,8 +940,7 @@ class TestEndToEnd:
             health = client.healthz()
             assert health["status"] == "ok"
             assert sorted(tuple(k) for k in health["compiled_buckets"]) \
-                == [(64, 96, 3, "batch", "passive", "fp32"),
-                    (96, 128, 3, "batch", "passive", "fp32")]
+                == sorted(met | set(warmed))
             client.close()
         finally:
             server.close()
@@ -684,8 +1062,12 @@ class TestWireHTTP:
         assert delta == {("in", "stored"): 1, ("in", "deflate"): 1,
                          ("out", "stored"): got["tiles_stored"],
                          ("out", "deflate"): got["tiles_deflated"]}
-        spans = {sp.name: sp.attrs for sp in
-                 server.tracer.spans(trace_id=meta["request_id"])}
+        for _ in range(200):  # the reply span closes after the client read
+            spans = {sp.name: sp.attrs for sp in
+                     server.tracer.spans(trace_id=meta["request_id"])}
+            if "reply" in spans:
+                break
+            time.sleep(0.01)
         assert {k: spans["wire_decode"][k] for k in sent} == sent
         assert {k: spans["reply"][k] for k in got} == got
         # the level class in a deflated tile's zlib header

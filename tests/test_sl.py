@@ -243,17 +243,20 @@ class TestServingE2E:
                           iters=3, degraded_iters=3)
         # Offline serving-parity reference FIRST (its compile must not
         # land inside the retrace budget below): same bucket policy, same
-        # iters, batch_pad = the engine's padded batch size.
+        # iters, batch_pad = the row count a one-pair dispatch is padded
+        # to (the requests below are sent one after another).
         metrics_off, preds = masked_epe(model, variables, ds, iters=3,
                                         divis_by=32, bucket_multiple=32,
-                                        batch_pad=cfg.max_batch_size)
+                                        batch_pad=1)
         assert np.isfinite(metrics_off["epe"])
 
         metrics = ServeMetrics()
         server = build_server(model, variables, cfg, metrics)  # warms
         assert server.engine.input_mode == "sl"
         assert server.engine.input_channels == SL_CHANNELS
-        assert (64, 96, 3, "batch", "sl", "fp32") in server.engine.compiled_keys
+        assert server.engine.compiled_keys == {
+            (64, 96, 3, "batch", f"r{rows}", "sl", "fp32")
+            for rows in (1, 2)}
         warm_misses = metrics.compile_misses.value
         thread = threading.Thread(target=server.serve_forever, daemon=True)
         thread.start()

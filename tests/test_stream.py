@@ -216,7 +216,7 @@ class TestEngineStream:
         # Mixed plain/stream compile keys coexist (and stay sortable for
         # /healthz).
         keys = eng.compiled_keys
-        assert (64, 96, 12, "batch", "passive", "fp32") in keys
+        assert (64, 96, 12, "batch", "r1", "passive", "fp32") in keys
         assert (64, 96, 12, "stream", "passive", "fp32") in keys
         sorted(keys)
 
