@@ -238,6 +238,26 @@ class BucketPadder:
                    for x in out]
         return out if len(out) > 1 else out[0]
 
+    def pad_into(self, out: np.ndarray, image: np.ndarray) -> None:
+        """Host twin of ``pad`` for ONE (H, W, C) image: fills ``out``
+        (bucket_h, bucket_w, C) with the padded image, the same bits
+        (edge replication copies, and an edge replicated twice is that
+        edge replicated once by the sum) and no device program — the
+        serving engine stages a batch with it, row by row into one
+        array (serve/engine.py ``_stage_pairs``)."""
+        l, r, t, b = self._padder._pad
+        h, w = image.shape[:2]
+        assert out.shape[:2] == self.bucket_hw, (out.shape, self.bucket_hw)
+        out[t:t + h, l:l + w] = image
+        if l:
+            out[t:t + h, :l] = out[t:t + h, l:l + 1]
+        if l + w < out.shape[1]:
+            out[t:t + h, l + w:] = out[t:t + h, l + w - 1:l + w]
+        if t:
+            out[:t] = out[t:t + 1]
+        if t + h < out.shape[0]:
+            out[t + h:] = out[t + h - 1:t + h]
+
     def unpad(self, x: jax.Array) -> jax.Array:
         if self.extra_h or self.extra_w:
             x = x[:, :x.shape[1] - self.extra_h,

@@ -1,9 +1,10 @@
 """Dynamic micro-batcher: coalesce concurrent requests into batches of a
 row count the engine has compiled — a full batch when one is queued, one
-row when not, never a zero row.
+row when not, never a zero row — and launch the next full batch while the
+one before it runs.
 
 Deadline-aware dynamic batching in the spirit of Clipper (Crankshaw et al.,
-NSDI 2017): a single worker thread groups queued requests by (shape bucket,
+NSDI 2017): a launcher thread groups queued requests by (shape bucket,
 requested iterations) and closes a batch when it reaches
 ``max_batch_size`` or when the OLDEST member has waited ``max_wait_ms``,
 whichever comes first — so batching never adds more than one deadline of
@@ -12,6 +13,29 @@ takes the largest compiled row count (``engine.row_counts``: 1 and
 ``max_batch_size``) that the queued rows fill and leaves the rest queued,
 first in first out: their deadline has passed, so the next cycle closes at
 once.
+
+**Launch-ahead of depth one.**  The engine's plain dispatch comes in two
+halves (``launch_batch`` stages the rows and calls the program, which
+returns at once; ``finish_batch`` waits for the result and fetches it).
+The launcher never waits on the device: it hands each launched batch to a
+second thread of the same batcher, the finisher, which waits, fetches and
+resolves the futures, batch by batch in launch order.  JAX runs what it is
+handed in the order of the launches, so a batch launched while another
+runs lies staged on the device and starts the moment that one ends.  When
+a batch closes follows from what the batcher can observe — the rows
+queued, the compiled row counts, the dispatches in flight (launched, not
+yet answered) — in three cases, and there is no option:
+
+* **none in flight** — the rule above, exactly;
+* **one in flight** — a batch closes only when the queued rows fill the
+  LARGEST compiled row count (``max(row_counts)``), and is staged and
+  launched at once, behind the running one (``closed_by=full_ahead``).
+  A partial queue waits: launched ahead it would buy one staging time and
+  cost the rows that would have joined; when the running dispatch is
+  answered the launcher wakes and the first case applies (the deadline
+  has passed: it closes at once, as it always did);
+* **two in flight** — one running, one queued behind it on the device:
+  the launcher waits for the first to be answered.
 
 Robustness controls, all tested in tests/test_serve.py:
 
@@ -35,7 +59,7 @@ import dataclasses
 import itertools
 import threading
 import time
-from typing import Deque, Dict, Optional, Tuple
+from typing import Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -155,19 +179,68 @@ class _Request:
 _Key = Tuple[int, int, Optional[int], Optional[str]]
 
 
-class DynamicBatcher:
-    """Thread-safe request queue + single dispatch worker over an engine.
+@dataclasses.dataclass
+class _Whole:
+    """The pending dispatch of a ``_WholeCall``: nothing has run yet."""
 
-    The engine contract is ``bucket_of(shape) -> (h, w)`` and
-    ``infer_batch(pairs, iters, mode=None) -> [disparity]`` (see
-    engine.BatchEngine; tests substitute stubs — ``mode`` is the
-    request's resolved precision mode, always passed by keyword), and
-    optionally ``row_counts``: the batch sizes it has programs for.
+    pairs: list
+    iters: int
+    mode: Optional[str]
+    segments: Optional[Dict[str, object]] = None
+
+
+class _WholeCall:
+    """An engine that offers only ``infer_batch`` (the test doubles), in
+    the two-halves shape the loop is written for: the launch does
+    nothing, the finish is the whole call."""
+
+    def __init__(self, engine):
+        self.engine = engine
+
+    def launch_batch(self, pairs, iters, mode=None) -> _Whole:
+        return _Whole(pairs, iters, mode)
+
+    def finish_batch(self, pending: _Whole):
+        out = self.engine.infer_batch(pending.pairs, pending.iters,
+                                      mode=pending.mode)
+        pending.segments = getattr(self.engine, "last_segments", None)
+        return out
+
+
+@dataclasses.dataclass
+class _Flight:
+    """A launched batch the finisher has yet to answer."""
+
+    key: _Key
+    batch: List[_Request]  # the live requests, in dispatch order
+    iters: int
+    degraded: bool
+    t_run0: float  # batch closed and handed to the engine
+    btid: Optional[str]
+    ahead: bool  # closed and launched while another was in flight
+    pending: object  # the engine's, between launch_batch and finish_batch
+
+
+class DynamicBatcher:
+    """Thread-safe request queue + a launcher and a finisher thread over
+    an engine.
+
+    The engine contract is ``bucket_of(shape) -> (h, w)`` and the plain
+    dispatch in two halves, ``launch_batch(pairs, iters, mode=None) ->
+    pending`` and ``finish_batch(pending) -> [disparity]`` (see
+    engine.BatchEngine; ``pending.segments`` holds the dispatch's phase
+    windows once finished) — or only ``infer_batch(pairs, iters,
+    mode=None) -> [disparity]``, which is wrapped into the two (tests
+    substitute stubs; ``mode`` is the request's resolved precision mode,
+    always passed by keyword) — and optionally ``row_counts``: the batch
+    sizes it has programs for.
     """
 
     def __init__(self, engine, config: ServeConfig,
                  metrics: Optional[ServeMetrics] = None, tracer=None):
         self.engine = engine
+        self._halves = (engine if hasattr(engine, "launch_batch")
+                        else _WholeCall(engine))
         self.cfg = config
         self.metrics = metrics or ServeMetrics()
         self.tracer = tracer  # obs.Tracer or None (tracing is optional)
@@ -176,7 +249,12 @@ class DynamicBatcher:
         self._depth = 0  # guarded_by: _cv
         self._seq = 0  # guarded_by: _cv
         self._closed = False  # guarded_by: _cv
+        # Launched and not yet answered, in launch order: at most two,
+        # one running and one queued behind it on the device.
+        self._flying: Deque[_Flight] = collections.deque()  # guarded_by: _cv
+        self._launcher_done = False  # guarded_by: _cv
         self._thread: Optional[threading.Thread] = None
+        self._finisher: Optional[threading.Thread] = None
 
     # ------------------------------------------------------------- lifecycle
 
@@ -184,12 +262,18 @@ class DynamicBatcher:
         assert self._thread is None, "batcher already started"
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="serve-batcher")
+        self._finisher = threading.Thread(target=self._finish_loop,
+                                          daemon=True,
+                                          name="serve-batcher-finish")
         self._thread.start()
+        self._finisher.start()
         return self
 
     def stop(self, drain: bool = True, timeout: float = 30.0) -> None:
-        """Stop the worker.  ``drain=True`` answers everything still queued
-        first; ``drain=False`` fails queued requests with ``ShuttingDown``."""
+        """Stop both threads.  ``drain=True`` answers everything still
+        queued first; ``drain=False`` fails queued requests with
+        ``ShuttingDown``.  A batch already launched is answered either
+        way."""
         to_fail = []
         with self._cv:
             self._closed = True
@@ -204,8 +288,10 @@ class DynamicBatcher:
         # (or another replica's) queue depth — see Future._resolve.
         for fut in to_fail:
             fut._resolve(exc=ShuttingDown("batcher stopped"))
-        if self._thread is not None:
-            self._thread.join(timeout)
+        t_end = time.perf_counter() + timeout
+        for thread in (self._thread, self._finisher):
+            if thread is not None:
+                thread.join(max(t_end - time.perf_counter(), 0.0))
 
     def __enter__(self) -> "DynamicBatcher":
         return self.start()
@@ -278,102 +364,154 @@ class DynamicBatcher:
             return timed_phase(name, **attrs)
         return self.tracer.phase(name, trace_id=btid, **attrs)
 
-    def _loop(self) -> None:
-        """The worker's cycle, in phases that leave no hole, each recorded
-        ONCE per dispatch under the batch's own trace ``batch:<seq>``:
-        ``queue_empty`` (nothing queued) -> ``batch_form`` (first request
-        seen -> batch closed) -> ``pad_bucket`` -> ``launch`` ->
-        ``device_wait`` -> ``host_fetch`` (the engine's, handed over in
-        ``last_segments``) -> ``reply_handoff`` (docs/observability.md)."""
-        max_wait_s = self.cfg.max_wait_ms / 1000.0
-        while True:
-            btid = f"batch:{next(_BATCH_SEQ)}"
-            with self._cv:
-                if not self._closed and self._depth == 0:
-                    with self._phase("queue_empty", btid):
-                        while not self._closed and self._depth == 0:
-                            self._cv.wait()
-                if self._depth == 0:  # closed and drained
-                    return
-                with self._phase("batch_form", btid) as form:
-                    key = self._oldest_key()
-                    deadline = self._queues[key][0].t_enqueue + max_wait_s
-                    # Hold the batch open until it fills or the oldest
-                    # member's deadline passes; new arrivals notify the
-                    # condition.
-                    closed_by = "full"
-                    while (len(self._queues.get(key, ()))
-                           < self.cfg.max_batch_size):
-                        if self._closed:
-                            closed_by = "shutdown"
-                            break
-                        remaining = deadline - time.perf_counter()
-                        if remaining <= 0:
-                            closed_by = "deadline"
-                            break
-                        self._cv.wait(remaining)
-                    q = self._queues.get(key)
-                    if not q:  # drained by a non-drain stop
-                        continue
-                    # Requests past request_timeout_ms head the queue
-                    # (one time-out, FIFO): they leave with this batch to
-                    # be failed, and the rows taken are counted among the
-                    # live ones behind them, so what reaches the engine
-                    # is still a compiled row count.
-                    now = time.perf_counter()
-                    expired = sum(1 for _ in itertools.takewhile(
-                        lambda r: self._timed_out(r, now), q))
-                    batch = [q.popleft() for _ in range(
-                        expired + self._take(len(q) - expired))]
-                    if not q:
-                        del self._queues[key]
-                    self._depth -= len(batch)
-                    # Backlog measured at batch close, including this
-                    # batch: the signal that decides graceful degradation.
-                    backlog = self._depth + len(batch)
-                    self.metrics.queue_depth.set(self._depth)
-                    form.attrs.update(closed_by=closed_by,
-                                      batch_size=len(batch),
-                                      bucket=f"{key[0]}x{key[1]}")
-            self._dispatch(key, batch, backlog, btid)
+    def _await_close(self, max_wait_s: float) -> Optional[str]:  # guarded_by: _cv
+        """Block until the oldest group's batch may close; returns why
+        (``closed_by``), or None when a non-drain stop emptied the queue
+        meanwhile.  The three cases of the module docstring, read anew
+        at every wake-up (a submit, a stop, a dispatch answered)."""
+        full_rows = self._take(self.cfg.max_batch_size)
+        while self._depth:
+            q = self._queues[self._oldest_key()]
+            if not self._flying:
+                # Hold the batch open until it fills or the oldest
+                # member's deadline passes.
+                if len(q) >= self.cfg.max_batch_size:
+                    return "full"
+                if self._closed:
+                    return "shutdown"
+                remaining = (q[0].t_enqueue + max_wait_s
+                             - time.perf_counter())
+                if remaining <= 0:
+                    return "deadline"
+                self._cv.wait(remaining)
+            elif (len(self._flying) == 1
+                  and len(q) - self._expired(q) >= full_rows):
+                return "full_ahead"
+            else:
+                self._cv.wait()
+        return None
 
-    def _trace_batch(self, key: _Key, batch, iters: int, degraded: bool,
-                     t_run0: float, t_done: float, error=None,
-                     btid: Optional[str] = None) -> None:
-        """Reconstruct each request's phase spans from the dispatch the
-        worker just ran: queue wait (enqueue -> batch close), dispatch
-        (engine call through device compute) and host fetch — siblings
-        under the request's trace id, so their durations sum to the
-        server-side latency (asserted in tests/test_obs.py).  The
-        engine's phases of the dispatch itself go ONCE under the batch's
-        trace ``btid``, from the same windows."""
-        seg = getattr(self.engine, "last_segments", None) if error is None \
-            else None
+    def _expired(self, q: Deque[_Request]) -> int:
+        """Requests past ``request_timeout_ms`` at the head of ``q`` (one
+        time-out, FIFO: the expired are a prefix)."""
+        now = time.perf_counter()
+        return sum(1 for _ in itertools.takewhile(
+            lambda r: self._timed_out(r, now), q))
+
+    def _loop(self) -> None:
+        """The launcher's cycle, in phases that leave no hole on its
+        thread, each recorded ONCE per dispatch under the batch's own
+        trace ``batch:<seq>``: ``queue_empty`` (nothing queued) ->
+        ``batch_form`` (first request seen -> batch closed; the wait for
+        a dispatch in flight is in it) -> ``pad_bucket`` -> ``launch``
+        (the engine's, handed over in ``pending.segments``).  The
+        finisher's follow in ``_finish_loop``."""
+        max_wait_s = self.cfg.max_wait_ms / 1000.0
+        try:
+            while True:
+                btid = f"batch:{next(_BATCH_SEQ)}"
+                with self._cv:
+                    if not self._closed and self._depth == 0:
+                        with self._phase("queue_empty", btid):
+                            while not self._closed and self._depth == 0:
+                                self._cv.wait()
+                    if self._depth == 0:  # closed and drained
+                        return
+                    with self._phase("batch_form", btid) as form:
+                        closed_by = self._await_close(max_wait_s)
+                        if closed_by is None:  # drained by a non-drain stop
+                            continue
+                        key = self._oldest_key()
+                        q = self._queues[key]
+                        # Requests past request_timeout_ms head the queue:
+                        # they leave with this batch to be failed, and the
+                        # rows taken are counted among the live ones
+                        # behind them, so what reaches the engine is
+                        # still a compiled row count.
+                        expired = self._expired(q)
+                        batch = [q.popleft() for _ in range(
+                            expired + self._take(len(q) - expired))]
+                        if not q:
+                            del self._queues[key]
+                        self._depth -= len(batch)
+                        # Backlog measured at batch close, including this
+                        # batch: the signal that decides graceful
+                        # degradation.
+                        backlog = self._depth + len(batch)
+                        self.metrics.queue_depth.set(self._depth)
+                        ahead = closed_by == "full_ahead"
+                        form.attrs.update(closed_by=closed_by, ahead=ahead,
+                                          batch_size=len(batch),
+                                          bucket=f"{key[0]}x{key[1]}")
+                self._launch(key, batch, backlog, btid, ahead)
+        finally:
+            with self._cv:
+                self._launcher_done = True
+                self._cv.notify_all()
+
+    def _finish_loop(self) -> None:
+        """The finisher's cycle, one flight at a time in launch order:
+        ``device_wait`` -> ``host_fetch`` (the engine's) ->
+        ``reply_handoff``.  A flight counts as in flight until its
+        futures are resolved; then the launcher is woken."""
+        while True:
+            with self._cv:
+                while not self._flying and not self._launcher_done:
+                    self._cv.wait()
+                if not self._flying:
+                    return
+                flight = self._flying[0]
+            try:
+                self._answer(flight)
+            finally:
+                with self._cv:
+                    self._flying.popleft()
+                    self._cv.notify_all()
+
+    def _trace_batch(self, flight: _Flight, t_done: float,
+                     error=None) -> None:
+        """Reconstruct each request's phase spans from the dispatch just
+        answered: queue wait (enqueue -> batch close), dispatch (engine
+        call through device compute) and host fetch — siblings under the
+        request's trace id, so their durations sum to the server-side
+        latency (asserted in tests/test_obs.py); under ``dispatch`` its
+        parts in order, ``pad_bucket``, ``launch``, ``device_queued``
+        (behind the dispatch before it; zero-length when the device was
+        free) and ``device_compute``.  The engine's phases of the
+        dispatch itself go ONCE under the batch's trace, from the same
+        windows."""
+        key, batch, t_run0 = flight.key, flight.batch, flight.t_run0
+        seg = (getattr(flight.pending, "segments", None)
+               if error is None else None)
         bucket = f"{key[0]}x{key[1]}"
-        if seg is not None and btid is not None:
+        if seg is not None and flight.btid is not None:
             battrs = {"batch_size": len(batch), "bucket": bucket,
-                      "iters": iters,
+                      "iters": flight.iters,
                       "request_ids": [r.trace_id for r in batch
                                       if r.trace_id is not None]}
+            # pad_bucket also says what the dispatch was staged at (rows
+            # / real_px / bucket_px, engine._pad_pairs); launch whether
+            # it ran ahead of the dispatch before it
+            extra = {"pad_bucket": seg.get("pad_px") or {},
+                     "launch": {"ahead": flight.ahead}}
             for name, window in (("pad_bucket", seg.get("pad")),
                                  ("launch", seg.get("launch")),
+                                 ("device_queued", seg.get("device_queued")),
                                  ("device_wait", seg.get("device_wait")),
                                  ("host_fetch", seg.get("host_fetch"))):
                 if window:
-                    # pad_bucket also says what the dispatch was staged
-                    # at (rows / real_px / bucket_px, engine._pad_pairs)
-                    extra = (seg.get("pad_px") or {}
-                             if name == "pad_bucket" else {})
-                    self.tracer.record(name, *window, btid,
-                                       attrs={**battrs, **extra})
+                    self.tracer.record(
+                        name, *window, flight.btid,
+                        attrs={**battrs, **extra.get(name, {})})
         for r in batch:
             if r.trace_id is None:
                 continue
             self.tracer.record(
                 "queue_wait", r.t_enqueue, t_run0, r.trace_id,
                 attrs={"bucket": bucket})
-            attrs = {"bucket": bucket, "iters": iters, "degraded": degraded,
-                     "batch_size": len(batch)}
+            attrs = {"bucket": bucket, "iters": flight.iters,
+                     "degraded": flight.degraded, "batch_size": len(batch),
+                     "ahead": flight.ahead}
             if error is not None:
                 attrs["error"] = str(error)
             if seg is None:
@@ -387,16 +525,28 @@ class DynamicBatcher:
             parent = self.tracer.record(
                 "dispatch", t_run0, seg["dispatch"][1], r.trace_id,
                 attrs=attrs)
-            if seg.get("pad"):
-                self.tracer.record("pad_bucket", *seg["pad"], r.trace_id,
-                                   parent_id=parent,
-                                   attrs=seg.get("pad_px"))
-            self.tracer.record("device_compute", *seg["dispatch"],
-                               r.trace_id, parent_id=parent)
+            for name, window, cattrs in (
+                    ("pad_bucket", seg.get("pad"), seg.get("pad_px")),
+                    ("launch", seg.get("launch"), None),
+                    ("device_queued", seg.get("device_queued"), None),
+                    ("device_compute", seg["dispatch"], None)):
+                if window:
+                    self.tracer.record(name, *window, r.trace_id,
+                                       parent_id=parent, attrs=cattrs)
             self.tracer.record("host_fetch", *seg["host_fetch"], r.trace_id)
 
-    def _dispatch(self, key: _Key, batch, backlog: int,
-                  btid: Optional[str] = None) -> None:
+    def _fail(self, flight: _Flight, error: Exception) -> None:
+        """A failed dispatch fails its own batch and nothing else."""
+        self.metrics.errors.inc(len(flight.batch))
+        if self.tracer is not None:
+            self._trace_batch(flight, time.perf_counter(), error=error)
+        for r in flight.batch:
+            r.future._resolve(exc=error)
+
+    def _launch(self, key: _Key, batch, backlog: int, btid: Optional[str],
+                ahead: bool) -> None:
+        """Fail the timed-out, stage and launch the rest, and hand the
+        flight to the finisher — without waiting on the device."""
         now = time.perf_counter()
         alive = []
         for r in batch:
@@ -422,31 +572,40 @@ class DynamicBatcher:
                      else self.cfg.iters)
         if degraded:
             self.metrics.degraded_batches.inc()
-        t_run0 = time.perf_counter()
+        flight = _Flight(key, alive, iters, degraded, time.perf_counter(),
+                         btid, ahead, None)
         try:
-            disps = self.engine.infer_batch(
-                [(r.image1, r.image2) for r in alive], iters,
-                mode=key[3])
+            flight.pending = self._halves.launch_batch(
+                [(r.image1, r.image2) for r in alive], iters, mode=key[3])
         except Exception as e:  # fail the batch, keep serving
-            self.metrics.errors.inc(len(alive))
-            if self.tracer is not None:
-                self._trace_batch(key, alive, iters, degraded, t_run0,
-                                  time.perf_counter(), error=e)
-            for r in alive:
-                r.future._resolve(exc=e)
+            self._fail(flight, e)
             return
+        if ahead:
+            self.metrics.launched_ahead.inc()
+        with self._cv:
+            self._flying.append(flight)
+            self._cv.notify_all()
+
+    def _answer(self, flight: _Flight) -> None:
+        try:
+            disps = self._halves.finish_batch(flight.pending)
+        except Exception as e:  # fail the batch, keep serving
+            self._fail(flight, e)
+            return
+        alive = flight.batch
         # reply_handoff: from the engine's return (disparities un-padded)
         # until every future of the batch is resolved.
-        with self._phase("reply_handoff", btid, batch_size=len(alive)):
+        with self._phase("reply_handoff", flight.btid,
+                         batch_size=len(alive)):
             done = time.perf_counter()
             if self.tracer is not None:
-                self._trace_batch(key, alive, iters, degraded, t_run0, done,
-                                  btid=btid)
+                self._trace_batch(flight, done)
             self.metrics.batch_size.observe(len(alive))
             for r, d in zip(alive, disps):
                 latency = done - r.t_enqueue
                 self.metrics.latency.observe(latency)
                 self.metrics.responses.inc()
                 r.future._resolve(value=ServeResult(
-                    disparity=d, iters=iters, degraded=degraded,
+                    disparity=d, iters=flight.iters,
+                    degraded=flight.degraded,
                     batch_size=len(alive), latency_s=latency))

@@ -21,14 +21,22 @@ Three shape decisions keep the XLA compile count small and predictable:
   row count of them, so the first real request never pays the
   multi-second XLA compile.
 
-The engine is deliberately synchronous and lock-serialized: ordering and
-batching policy live in the batcher; this layer owns shapes, compiles and
-device dispatch only.
+The engine is lock-serialized: ordering and batching policy live in the
+batcher; this layer owns shapes, compiles and device dispatch only.  A
+dispatch has two halves — ``_launch`` (compile bookkeeping and the
+asynchronous jitted call, under the engine lock, so the device's order is
+the order of the launches) and ``_finish`` (wait, fetch, metrics).  The
+plain path offers them as ``launch_batch`` / ``finish_batch`` so the
+batcher can stage and launch the next full batch while the one before it
+runs; ``infer_batch`` is ``finish(launch(...))``, and every other path
+(warm-start, ``--sched``, cascade, spatial) runs both halves under the
+lock in ``_dispatch``, synchronous as before.
 """
 
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import logging
 import math
 import threading
@@ -69,6 +77,31 @@ def split_rows(n: int, counts: Sequence[int]) -> List[int]:
         out += [c] * (n // c)
         n %= c
     return out
+
+
+@dataclasses.dataclass
+class _Launched:
+    """One dispatch between its two halves (``_launch`` / ``_finish``):
+    the device result nobody has waited for yet, and the phase windows so
+    far.  The windows ride here and not on the thread-local
+    ``last_segments``: one thread may launch a plain dispatch and another
+    finish it (serve/batcher.py)."""
+
+    key: Tuple
+    labels: Dict[str, str]
+    miss: bool
+    out_dev: object
+    launch: Tuple[float, float]
+    pad: Optional[Tuple[float, float]]
+    pad_px: Optional[Dict[str, int]]
+    # The dispatch launched before this one on the same engine: until its
+    # ``ready_at`` the device is not free to take this one up.
+    behind: Optional["_Launched"]
+    padders: Sequence[BucketPadder] = ()  # plain path: one per real row
+    ready_at: Optional[float] = None  # device_wait's end, set by _finish
+    # Every window of the dispatch, as ``last_segments`` documents them;
+    # set by ``_finish``.
+    segments: Optional[Dict[str, object]] = None
 
 
 class BatchEngine:
@@ -175,8 +208,10 @@ class BatchEngine:
         # whole set for a stable compiled_buckets listing) — and the
         # sched_* / cascade_* phases below.
         self._compiled: Set[Tuple] = set()  # guarded_by: _stats_lock
-        self.last_batch_runtime: float = float("nan")  # guarded_by: _lock
         self.last_included_compile: bool = True  # guarded_by: _lock
+        # The newest launch: the next one lies behind it on the device
+        # until its ``ready_at`` (``_finish``'s ``device_queued``).
+        self._last_launched: Optional[_Launched] = None  # guarded_by: _lock
         # Per-thread phase timing of the most recent dispatch THIS thread
         # ran (the batcher worker and concurrent stream handlers each read
         # their own): thread-local because an attribute would be overwritten
@@ -497,11 +532,9 @@ class BatchEngine:
         config's mode only) so a warmed accuracy tier never compiles
         under traffic either, and every row count of each, so no batch
         the batcher can form does.  A row count is warmed by a batch of
-        that many zero pairs: the eager staging programs of
-        ``_stage_pairs`` depend on the row count alone, so they all run
-        here as well and no dispatch meets one for the first time under
-        traffic.  Returns the (h, w, iters, "batch", "rN", input_mode,
-        mode) keys warmed.
+        that many zero pairs; staging is host work (``_stage_pairs``) and
+        has no program of its own to warm.  Returns the (h, w, iters,
+        "batch", "rN", input_mode, mode) keys warmed.
         """
         buckets = list(buckets or self.cfg.buckets)
         # sorted, not set-ordered: the default {iters, degraded_iters} set
@@ -563,15 +596,20 @@ class BatchEngine:
 
     @property
     def last_segments(self) -> Optional[Dict[str, object]]:
-        """Phase timing of the last dispatch on THIS thread:
-        ``{"pad", "launch", "device_wait", "dispatch", "host_fetch"}`` as
-        (perf_counter t0, t1) windows plus ``"compile"`` — the raw
+        """Phase timing of the last dispatch this thread ran to its end
+        (``infer_batch`` and the synchronous paths; a dispatch the
+        batcher launches and finishes on two threads carries its own,
+        ``_Launched.segments``): ``{"pad", "launch", "device_queued",
+        "device_wait", "dispatch", "host_fetch"}`` as (perf_counter t0,
+        t1) windows plus ``"pad_px"`` and ``"compile"`` — the raw
         material the batcher and stream runner turn into trace spans
         (obs/trace.py).  ``dispatch`` (the ``device_compute`` span) is
         ``launch`` (the jitted call until it returns) followed by
-        ``device_wait`` (``block_until_ready``).  Each window is read
-        inside a ``timed_phase`` of the same name, so a profile capture
-        shows the same phases as host events."""
+        ``device_wait`` (``block_until_ready``); behind another dispatch
+        it starts where ``device_queued`` ends (``_finish``).  ``launch``,
+        ``device_wait`` and ``host_fetch`` are read inside a
+        ``timed_phase`` of the same name, so a profile capture shows the
+        same phases as host events."""
         return getattr(self._seg, "last", None)
 
     def _pad_pairs(self, pairs, rows: int):
@@ -605,37 +643,36 @@ class BatchEngine:
         assert all(p.bucket_hw == hw for p in padders), (
             "mixed buckets in one batch: "
             f"{sorted({p.bucket_hw for p in padders})}")
-        lefts, rights = [], []
-        # Staging under _device_ctx too, not just the jit call: a pinned
-        # replica's inputs must land on ITS device — staged on the global
-        # default they would pay a device-to-device copy per dispatch and
-        # serialize every replica's staging on one chip's stream.
+        # Staged on the HOST, row by row into one array a side, then one
+        # transfer each: no device program.  The eager form (an expand,
+        # two pads and a concatenate per row: ~50 tiny programs for eight
+        # rows) blocked in the runtime's bound on enqueued programs
+        # whenever a dispatch was running, so nothing could be staged
+        # behind it (PERF.md §6, PR 35).  Fresh arrays every time: the
+        # transfer may read them after this returns.
+        pad_rows = rows - len(pairs)
+        shape = (rows, *hw, pairs[0][0].shape[2])
+        # zeros only on the warm-start path (a plain dispatch is staged
+        # at its own length): the rows nobody sent
+        left, right = ((np.zeros if pad_rows else np.empty)(
+            shape, np.float32) for _ in range(2))
+        for i, ((im1, im2), padder) in enumerate(zip(pairs, padders)):
+            padder.pad_into(left[i], im1)
+            padder.pad_into(right[i], im2)
+        # Under _device_ctx: a pinned replica's inputs must land on ITS
+        # device — put on the global default they would pay a
+        # device-to-device copy per dispatch.
         with self._device_ctx():
-            for (im1, im2), padder in zip(pairs, padders):
-                i1, i2 = padder.pad(jnp.asarray(im1, jnp.float32)[None],
-                                    jnp.asarray(im2, jnp.float32)[None])
-                lefts.append(i1)
-                rights.append(i2)
-            pad_rows = rows - len(pairs)
-            if pad_rows:
-                # Warm-start path only (a plain dispatch is staged at its
-                # own length).  The rows nobody sent are ONE zero row,
-                # repeated: the eager programs below then depend on
-                # ``rows`` and never on the occupancy.
-                zero = jnp.zeros_like(lefts[0])
-                lefts += [zero] * pad_rows
-                rights += [zero] * pad_rows
-            i1 = jnp.concatenate(lefts, axis=0)
-            i2 = jnp.concatenate(rights, axis=0)
+            i1, i2 = jnp.asarray(left), jnp.asarray(right)
         return padders, hw, i1, i2, pad_rows
 
-    def _dispatch(self, key, call):
-        """Lock-serialized device dispatch with compile-cache bookkeeping:
-        runs ``call`` under the engine lock, fetches every output to host
-        (fetch = completion), records timing/metrics.  Returns
-        ``(host_outputs, included_compile)`` — the flag is per-call, not
-        read back from shared engine state, so concurrent callers cannot
-        race each other's compile accounting."""
+    def _launch(self, key, call, padders=()) -> _Launched:
+        """First half of a dispatch: compile-cache bookkeeping and the
+        asynchronous call of the jitted function, under the engine lock —
+        the device runs what it is handed in the order of the launches.
+        Returns at once (a first call compiles, synchronously); the
+        result is not waited for.  The pad window is this thread's
+        (``_pad_pairs`` ran on it just before)."""
         # mode = the key's kind (always at position 3); tier = its
         # precision-mode component (always last): a compile under traffic
         # must be attributable to the tier whose warmup missed it.
@@ -654,47 +691,92 @@ class BatchEngine:
             if self.metrics is not None:
                 (self.metrics.compile_misses if miss
                  else self.metrics.compile_hits).labels(**labels).inc()
-            # Three measured phases: launch (the asynchronous call of the
-            # jitted function until it returns — a slow launch is host
-            # time, not device time), device_wait (until the result
-            # exists on device) and the device->host copy.  All under the
-            # engine lock — fetch-before-release is the engine's
-            # completion contract.
+            # launch: the asynchronous call of the jitted function until
+            # it returns — a slow launch is host time, not device time.
             with timed_phase("launch", bucket=labels["bucket"],
                              iters=key[2]) as ph_launch:
                 with self._device_ctx():
                     out_dev = call()
-            with timed_phase("device_wait") as ph_wait:
-                jax.block_until_ready(out_dev)
-            with timed_phase("host_fetch") as ph_fetch:
-                out = [np.asarray(o, np.float32) for o in out_dev]
-            start, t_compute, t_fetch = ph_launch.t0, ph_wait.t1, ph_fetch.t1
-            runtime = t_fetch - start
-            self.last_batch_runtime = runtime
             self.last_included_compile = miss
-            with self._stats_lock:
+            with self._stats_lock:  # the call has compiled what it missed
                 self._compiled.add(key)
-            if miss and self.tracer is not None:
-                # the bucket's own compile span, beside the per-program
-                # ones of the jax.monitoring listener (trace ``xla``)
-                self.tracer.record(
-                    "compile", ph_launch.t0, ph_wait.t1, "xla",
-                    attrs={"kind": "bucket", **labels,
-                           **self._program_facts(key)})
-        self._seg.last = {
-            "pad": getattr(self._seg, "pad", None),
-            "pad_px": getattr(self._seg, "pad_px", None),
-            "launch": ph_launch.window,
-            "device_wait": ph_wait.window,
+            launched = _Launched(
+                key, labels, miss, out_dev, ph_launch.window,
+                getattr(self._seg, "pad", None),
+                getattr(self._seg, "pad_px", None),
+                behind=self._last_launched, padders=padders)
+            self._last_launched = launched
+        return launched
+
+    def _finish(self, launched: _Launched):
+        """Second half: wait until the result exists on the device
+        (``device_wait``), copy every output to the host (``host_fetch``;
+        fetch = completion), record timing and metrics.  Returns
+        ``(host_outputs, included_compile)`` — the flag is per-call, not
+        read back from shared engine state, so concurrent callers cannot
+        race each other's compile accounting — and leaves every window
+        of the dispatch in ``launched.segments``.
+
+        ``device_queued`` is the time the dispatch lay behind the one
+        launched before it: from ``launch``'s end to that one's
+        ``device_wait`` end, zero-length when the device was free.  A
+        dispatch that was queued counts its ``device_wait`` and its
+        ``dispatch`` window (the ``device_compute`` span) from where
+        ``device_queued`` ends, so both go on reading the device's time
+        for ONE dispatch; one that was not counts ``dispatch`` from
+        ``launch``'s start, as ever."""
+        ph_wait = timed_phase("device_wait")
+        try:
+            with ph_wait:
+                jax.block_until_ready(launched.out_dev)
+            with timed_phase("host_fetch") as ph_fetch:
+                out = [np.asarray(o, np.float32) for o in launched.out_dev]
+        finally:
+            # also where the wait raised: the device is free again, and
+            # neither the chain nor the result outlives the dispatch
+            launched.ready_at = t_compute = ph_wait.t1
+            behind, launched.behind = launched.behind, None
+            launched.out_dev = None
+        t_launch0, t_launch1 = launched.launch
+        # behind.ready_at is None where a later launch was finished first
+        # (concurrent direct callers): the wait was then the other's.
+        t_free = max(t_launch1, (behind.ready_at or 0.0) if behind else 0.0)
+        queued = t_free > t_launch1
+        start = t_free if queued else t_launch0
+        t_fetch = ph_fetch.t1
+        if launched.miss and self.tracer is not None:
+            # the bucket's own compile span, beside the per-program
+            # ones of the jax.monitoring listener (trace ``xla``)
+            self.tracer.record(
+                "compile", t_launch0, t_compute, "xla",
+                attrs={"kind": "bucket", **launched.labels,
+                       **self._program_facts(launched.key)})
+        launched.segments = {
+            "pad": launched.pad,
+            "pad_px": launched.pad_px,
+            "launch": launched.launch,
+            "device_queued": (t_launch1, t_free),
+            "device_wait": (t_free if queued else ph_wait.t0, t_compute),
             "dispatch": (start, t_compute),
             "host_fetch": (t_compute, t_fetch),
-            "compile": miss,
+            "compile": launched.miss,
         }
-        if self.metrics is not None and not miss:
-            # The local, not self.last_batch_runtime: the lock is released
-            # and a concurrent dispatch may have overwritten it (RSA301).
-            self.metrics.batch_latency.observe(runtime)
-        return out, miss
+        if self.metrics is not None and not launched.miss:
+            self.metrics.batch_latency.observe(t_fetch - start)
+        return out, launched.miss
+
+    def _dispatch(self, key, call):
+        """Lock-serialized synchronous dispatch: both halves under the
+        engine lock (re-entrant), so nothing is launched between this
+        call and its result — fetch-before-release is the completion
+        contract of the warm-start, ``--sched``, cascade and spatial
+        paths.  Returns ``(host_outputs, included_compile)`` and leaves
+        the windows in this thread's ``last_segments``."""
+        with self._lock:
+            launched = self._launch(key, call)
+            out = self._finish(launched)
+        self._seg.last = launched.segments
+        return out
 
     def infer_batch(self, pairs: Sequence[Tuple[np.ndarray, np.ndarray]],
                     iters: int, mode: Optional[str] = None
@@ -719,22 +801,43 @@ class BatchEngine:
                 f"mixed buckets in one batch: {sorted(buckets)}")
         out, start = [], 0
         for rows in split:
-            out += self._infer_rows(pairs[start:start + rows], iters, mode)
+            launched = self.launch_batch(pairs[start:start + rows], iters,
+                                         mode)
+            out += self.finish_batch(launched)
+            # direct callers read the windows where they always did
+            self._seg.last = launched.segments
             start += rows
         return out
 
-    def _infer_rows(self, pairs, iters: int, mode: Optional[str]):
-        """One plain dispatch: ``len(pairs)`` is a compiled row count."""
+    def launch_batch(self, pairs, iters: int,
+                     mode: Optional[str] = None) -> _Launched:
+        """First half of one plain dispatch (``len(pairs)`` is a compiled
+        row count): stage the rows and launch the program, without
+        waiting for it.  A caller that launches the next batch before it
+        finishes this one has that batch queued on the device behind this
+        one, staged and ready to start the moment this one ends
+        (serve/batcher.py keeps at most two in flight)."""
         rows = len(pairs)
+        assert rows in self.row_counts, (
+            f"{rows} rows is not a compiled row count {self.row_counts}")
         padders, hw, i1, i2, _ = self._pad_pairs(pairs, rows)
         m = self._mode(mode)
-        key = self._batch_key(hw, iters, rows, m)
-        (flow_up,), _ = self._dispatch(
-            key, lambda: [self._fn(iters, m)(self.variables, i1, i2)[1]])
+        return self._launch(
+            self._batch_key(hw, iters, rows, m),
+            lambda: [self._fn(iters, m)(self.variables, i1, i2)[1]],
+            padders)
+
+    def finish_batch(self, launched: _Launched) -> List[np.ndarray]:
+        """Second half: wait, fetch, count, un-pad — one (H, W) disparity
+        per pair of the launch, in its order.  Finish in launch order:
+        the device runs in that order, and ``device_queued`` is read
+        from the dispatch before."""
+        (flow_up,), _ = self._finish(launched)
         if self.metrics is not None:
-            self.metrics.batch_rows.labels(rows=str(rows)).inc()
+            self.metrics.batch_rows.labels(
+                rows=str(len(launched.padders))).inc()
         return [padder.unpad(flow_up[i:i + 1])[0, ..., 0]
-                for i, padder in enumerate(padders)]
+                for i, padder in enumerate(launched.padders)]
 
     def infer_stream_batch(self, pairs: Sequence[Tuple[np.ndarray,
                                                        np.ndarray]],
@@ -905,7 +1008,6 @@ class BatchEngine:
                 out = call()
             jax.block_until_ready(out)
             t_done = time.perf_counter()
-            self.last_batch_runtime = t_done - start
             self.last_included_compile = miss
             with self._stats_lock:
                 self._compiled.add(key)

@@ -289,9 +289,8 @@ class ServeMetrics:
             "serve_xla_compiles_total",
             "programs this process built (kind=compile) or read back from "
             "the persistent compile cache (kind=cache_load), counted by "
-            "jax.monitoring: EVERY program, the eager per-occupancy "
-            "staging ones included, which the engine's own hit/miss "
-            "counters cannot see",
+            "jax.monitoring: EVERY program, eager ones included, which "
+            "the engine's own hit/miss counters cannot see",
             labels=("kind",))
         r.device_memory_gauges("serve")
         self.queue_depth = r.gauge(
@@ -306,6 +305,13 @@ class ServeMetrics:
             "many real rows; serve_batch_size counts the rows of a batch "
             "the batcher closed",
             labels=("rows",))
+        self.launched_ahead = r.counter(
+            "serve_batch_launched_ahead_total",
+            "plain dispatches the batcher closed and launched while "
+            "another was in flight (closed_by=full_ahead): staged and "
+            "queued on the device behind the running one; over "
+            "serve_batch_rows_total, the share of dispatches the "
+            "launch-ahead rule took")
         self.latency = r.histogram(
             "serve_request_latency_seconds",
             "submit-to-result latency per request (queue wait + compute)")

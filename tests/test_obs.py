@@ -698,10 +698,10 @@ class TestPhase:
                 pass
         per_phase = (time.perf_counter() - t0) / n
         assert per_phase < 200e-6
-        # A dispatch adds eleven phases (three live in the batcher, four
+        # A dispatch adds twelve phases (three live in the batcher, five
         # recorded, four timed in the engine) and each of its requests
         # two: under the contract's 2 % of even a 10 ms dispatch.
-        assert (11 + 2 * 8) * per_phase < 0.02 * 0.25
+        assert (12 + 2 * 8) * per_phase < 0.02 * 0.25
 
     def test_unsampled_span_guard_still_holds_for_record(self):
         tracer = Tracer(capacity=4)
@@ -710,33 +710,28 @@ class TestPhase:
         assert len(Tracer.new_span_id()) == 16
 
 
-class _StageEngine:
-    """The engine's side of the batcher contract, phases and all, with no
-    model behind it."""
+def _stage_engine(cfg, hold=None):
+    """The real engine's two halves over a stand-in program (the left
+    image's first channel; no model), so every phase window is the
+    engine's own.  ``hold`` (an Event) keeps every ``finish_batch`` from
+    starting until it is set."""
+    from raftstereo_tpu.serve.engine import BatchEngine
 
-    def __init__(self):
-        self.last_segments = None
+    eng = BatchEngine(None, {}, cfg)
+    eng._fn = lambda iters, mode: lambda v, a, b: (None, a[..., :1])
+    if hold is not None:
+        finish = eng.finish_batch
 
-    def bucket_of(self, shape):
-        return (64, 96)
+        def held(launched):
+            assert hold.wait(10.0)
+            return finish(launched)
 
-    def infer_batch(self, pairs, iters, mode=None):
-        from raftstereo_tpu.obs.trace import timed_phase
+        eng.finish_batch = held
+    return eng
 
-        with timed_phase("pad_bucket") as pad:
-            time.sleep(0.002)
-        with timed_phase("launch") as launch:
-            time.sleep(0.001)
-        with timed_phase("device_wait") as wait:
-            time.sleep(0.005)
-        with timed_phase("host_fetch") as fetch:
-            time.sleep(0.001)
-        self.last_segments = {
-            "pad": pad.window, "launch": launch.window,
-            "device_wait": wait.window,
-            "dispatch": (launch.t0, wait.t1),
-            "host_fetch": (wait.t1, fetch.t1), "compile": False}
-        return [np.zeros((4, 4), np.float32) for _ in pairs]
+
+def _holes(spans):
+    return sum(max(b.t0 - a.t1, 0.0) for a, b in zip(spans, spans[1:]))
 
 
 class TestWorkerPhases:
@@ -747,40 +742,117 @@ class TestWorkerPhases:
         cfg = ServeConfig(max_batch_size=2, max_wait_ms=max_wait_ms,
                           queue_limit=8)
         img = np.zeros((60, 90, 3), np.float32)
-        with DynamicBatcher(_StageEngine(), cfg, tracer=tracer) as b:
+        with DynamicBatcher(_stage_engine(cfg), cfg, tracer=tracer) as b:
             futs = [b.submit(img, img, trace_id=f"req{i}")
                     for i in range(n_requests)]
             for f in futs:
                 f.result(10)
         return tracer.spans()
 
-    def test_phases_tile_the_cycle_once_per_dispatch(self):
+    def test_phases_tile_each_thread_once_per_dispatch(self):
+        """One dispatch of two rows: the launcher's phases ``batch_form
+        -> pad_bucket -> launch`` tile its thread, the finisher's
+        ``device_wait -> host_fetch -> reply_handoff`` tile its own,
+        each recorded ONCE under the batch's trace."""
         spans = self._serve(2, max_wait_ms=2000.0)
         batch = [s for s in spans if s.trace_id.startswith("batch:")
                  and s.name != "queue_empty"]
         btid = {s.trace_id for s in batch if s.name == "launch"}
         assert len(btid) == 1                   # one dispatch of two rows
-        mine = sorted((s for s in batch if s.trace_id in btid),
-                      key=lambda s: s.t0)
-        assert [s.name for s in mine] == [
-            "batch_form", "pad_bucket", "launch", "device_wait",
-            "host_fetch", "reply_handoff"]      # each ONCE, in order
-        holes = sum(max(b.t0 - a.t1, 0.0) for a, b in zip(mine, mine[1:]))
-        assert holes < 1e-3, holes
-        assert all(b.t0 >= a.t0 for a, b in zip(mine, mine[1:]))
-        by = {s.name: s for s in mine}
+        by = {}
+        for s in batch:
+            if s.trace_id in btid:
+                assert s.name not in by, s.name  # each ONCE
+                by[s.name] = s
+        launcher = [by[n] for n in ("batch_form", "pad_bucket", "launch")]
+        finisher = [by[n] for n in ("device_wait", "host_fetch",
+                                    "reply_handoff")]
+        assert sorted(by) == sorted(
+            [s.name for s in launcher + finisher] + ["device_queued"])
+        for thread in (launcher, finisher):
+            assert _holes(thread) < 1e-3, [s.name for s in thread]
+            assert all(b.t0 >= a.t0 for a, b in zip(thread, thread[1:]))
+        assert finisher[0].t0 >= launcher[-1].t1  # handed over, in order
+        # nothing was in flight: the device was free at launch's end
+        assert by["device_queued"].t0 == by["device_queued"].t1 \
+            == by["launch"].t1
         assert by["batch_form"].attrs["closed_by"] == "full"
         assert by["batch_form"].attrs["batch_size"] == 2
+        assert by["batch_form"].attrs["ahead"] is False
         assert by["launch"].attrs["request_ids"] == ["req0", "req1"]
-        assert by["launch"].attrs["bucket"] == "64x96"
-        # the per-request copies stay as they were: one set a request,
-        # and launch + device_wait are what device_compute spans
+        assert by["launch"].attrs["bucket"] == "64x128"
+        assert by["launch"].attrs["ahead"] is False
+        # the per-request copies: one set a request, and launch +
+        # device_wait are what device_compute spans
         for rid in ("req0", "req1"):
             names = sorted(s.name for s in spans if s.trace_id == rid)
-            assert names == ["device_compute", "dispatch", "host_fetch",
-                             "pad_bucket", "queue_wait"]
+            assert names == ["device_compute", "device_queued", "dispatch",
+                             "host_fetch", "launch", "pad_bucket",
+                             "queue_wait"]
         dc = next(s for s in spans if s.name == "device_compute")
         assert dc.t0 == by["launch"].t0 and dc.t1 == by["device_wait"].t1
+
+    def test_a_request_launched_ahead_sums_and_fills(self):
+        """req0 rides alone and is held in ``finish``; req1 and req2
+        fill a batch behind it and are launched ahead.  For them too
+        ``queue_wait + dispatch + host_fetch`` is the server-side latency
+        (enqueue to the result on the host), and ``pad_bucket + launch +
+        device_queued + device_compute`` fill ``dispatch``: the device's
+        time for THIS dispatch starts where the one before it ended."""
+        from raftstereo_tpu.serve.batcher import DynamicBatcher
+
+        tracer, hold = Tracer(capacity=256), threading.Event()
+        cfg = ServeConfig(max_batch_size=2, max_wait_ms=1.0, queue_limit=8)
+        img = np.zeros((60, 90, 3), np.float32)
+        b = DynamicBatcher(_stage_engine(cfg, hold), cfg,
+                           tracer=tracer).start()
+        try:
+            futs = [b.submit(img, img, trace_id="req0")]
+            deadline = time.time() + 10
+            while not b._flying and time.time() < deadline:
+                time.sleep(0.002)
+            t_sub = time.perf_counter()
+            futs += [b.submit(img, img, trace_id=f"req{i}") for i in (1, 2)]
+            while len(b._flying) < 2 and time.time() < deadline:
+                time.sleep(0.002)
+            assert len(b._flying) == 2 and not futs[0].done()
+            time.sleep(0.01)  # the batch lies queued behind req0 a while
+            hold.set()
+            for f in futs:
+                f.result(10)
+        finally:
+            hold.set()
+            b.stop()
+        spans = tracer.spans()
+        first = {s.name: s for s in spans if s.trace_id == "req0"}
+        for rid in ("req1", "req2"):
+            by = {s.name: s for s in spans if s.trace_id == rid}
+            assert by["dispatch"].attrs["ahead"] is True
+            core = [by[n] for n in ("queue_wait", "dispatch", "host_fetch")]
+            assert _holes(core) == 0.0 and core[0].t0 >= t_sub
+            assert sum(s.duration_s for s in core) == pytest.approx(
+                core[-1].t1 - core[0].t0, abs=1e-9)
+            parts = [by[n] for n in ("pad_bucket", "launch",
+                                     "device_queued", "device_compute")]
+            assert all(s.parent_id == by["dispatch"].span_id
+                       for s in parts)
+            assert parts[0].t0 >= by["dispatch"].t0
+            assert parts[-1].t1 == by["dispatch"].t1
+            assert _holes(parts) < 1e-3
+            assert all(b.t0 >= a.t1 for a, b in zip(parts, parts[1:]))
+            # behind req0: queued until ITS device_compute ended
+            assert by["device_queued"].duration_s >= 0.01
+            assert by["device_queued"].t1 == by["device_compute"].t0 \
+                == first["device_compute"].t1
+        assert first["dispatch"].attrs["ahead"] is False
+        assert first["device_queued"].duration_s == 0.0
+        waits = sorted((s for s in spans if s.name == "device_wait"),
+                       key=lambda s: s.t0)
+        assert len(waits) == 2 and waits[1].t0 == waits[0].t1
+        forms = sorted((s for s in spans if s.name == "batch_form"),
+                       key=lambda s: s.t0)
+        assert [s.attrs["closed_by"] for s in forms] == ["deadline",
+                                                         "full_ahead"]
 
     def test_deadline_closes_a_short_batch_and_the_idle_wait_is_named(self):
         spans = self._serve(1, max_wait_ms=20.0)
@@ -883,14 +955,14 @@ class TestLightCapture:
 
 
 class TestCompileAndMemoryInstruments:
-    def test_compile_counter_sees_the_staging_programs(self):
-        """The engine stages a batch with eager ops whose shapes depend on
-        the row count staged and never on the number of real rows (on the
-        plain path the two are equal; the warm-start path stages zero
-        rows behind the real ones): a new row count is new programs,
-        which ``serve_xla_compiles_total`` counts (once) and the engine's
-        own hit/miss counters never saw; a new occupancy of a row count
-        already staged is none."""
+    def test_compile_counter_sees_every_program_and_staging_has_none(self):
+        """``serve_xla_compiles_total`` counts every program the process
+        builds — the eager ones too, which the engine's own hit/miss
+        counters never see — and the engine's staging is not among them:
+        a batch is staged on the host (row by row into one array, one
+        transfer a side), whatever the row count, the occupancy or the
+        pair's size (until PR 35 staging ran ~6 eager programs a row,
+        and a new row count met them under traffic)."""
         from raftstereo_tpu.serve.engine import BatchEngine
         from raftstereo_tpu.serve.server import watch_xla_compiles
 
@@ -902,19 +974,20 @@ class TestCompileAndMemoryInstruments:
         pair = (np.ones((40, 136, 3), np.float32),) * 2
         unwatch = watch_xla_compiles(metrics, tracer)
         try:
-            engine._pad_pairs([pair], 1)        # one row: several
-            before = metrics.xla_compiles.value
-            assert before >= 1
-            # two rows, one of them real: the zero row and concatenate
-            engine._pad_pairs([pair], 2)
+            engine._pad_pairs([pair], 1)        # one row
+            engine._pad_pairs([pair], 2)        # two rows, one of them real
+            engine._pad_pairs([pair, pair], 2)  # another occupancy
+            engine._pad_pairs([(np.ones((33, 130, 3), np.float32),) * 2], 1)
+            assert metrics.xla_compiles.value == 0
+            # an eager program of a shape nobody has built: counted once
+            jax.numpy.pad(jax.numpy.ones((3, 41, 137)), 1).block_until_ready()
             staged = metrics.xla_compiles.value
-            assert staged >= before + 2
-            engine._pad_pairs([pair, pair], 2)  # another occupancy: none
-            engine._pad_pairs([pair], 2)        # staged before: none
+            assert staged >= 1
+            jax.numpy.pad(jax.numpy.ones((3, 41, 137)), 1).block_until_ready()
             assert metrics.xla_compiles.value == staged
         finally:
             unwatch()
-        engine._pad_pairs([(np.ones((33, 130, 3), np.float32),) * 2], 1)
+        jax.numpy.pad(jax.numpy.ones((3, 43, 139)), 1).block_until_ready()
         assert metrics.xla_compiles.value == staged     # unsubscribed
         compiles = [s for s in tracer.spans() if s.name == "compile"]
         assert len(compiles) == staged

@@ -149,6 +149,32 @@ class TestBucketPadder:
                                       np.asarray(x * 2))
 
 
+    @pytest.mark.parametrize("hw,divis_by,multiple,mode", [
+        ((60, 90), 32, 64, "sintel"),     # both stages, split both ways
+        ((70, 100), 32, 64, "sintel"),
+        ((37, 50), 32, None, "sintel"),   # alignment only
+        ((64, 128), 32, 64, "sintel"),    # bucket-sized: nothing to add
+        ((61, 95), 8, 32, "kitti"),       # all height padding at the bottom
+        ((1, 1), 32, 64, "sintel"),       # one pixel, replicated everywhere
+    ])
+    def test_pad_into_is_pad_bit_for_bit_on_the_host(self, rng, hw,
+                                                     divis_by, multiple,
+                                                     mode):
+        """The serving engine's staging: one image into its row of a
+        NumPy batch, equal to the device's two-stage ``pad``."""
+        from raftstereo_tpu.ops.image import BucketPadder
+
+        x = rng.standard_normal((*hw, 3)).astype(np.float32)
+        p = BucketPadder(x.shape, divis_by=divis_by, bucket_multiple=multiple,
+                         mode=mode)
+        out = np.full((*p.bucket_hw, 3), np.nan, np.float32)
+        p.pad_into(out, x)
+        np.testing.assert_array_equal(out, np.asarray(p.pad(x[None]))[0])
+        # any dtype in, the batch's dtype out (an exact cast here)
+        p.pad_into(out, (x * 0 + 7).astype(np.uint8))
+        assert out.dtype == np.float32 and (out == 7).all()
+
+
 class TestConvexUpsample:
     def test_patches_order(self):
         x = jnp.arange(9.0).reshape(1, 3, 3, 1)

@@ -12,6 +12,7 @@ import contextlib
 import json
 import threading
 import time
+import types
 
 import numpy as np
 import pytest
@@ -147,20 +148,25 @@ class TestBatcher:
         metrics = ServeMetrics()
         b = DynamicBatcher(eng, cfg, metrics).start()
         try:
-            # Park the worker: it pops this request and blocks on the gate,
-            # so the backlog below builds up deterministically.
-            sentinel = b.submit(_img(), _img())
-            deadline = time.perf_counter() + 5.0
-            while b.queue_depth and time.perf_counter() < deadline:
-                time.sleep(0.002)
+            # Park the batcher: the sentinel blocks on the gate, a full
+            # batch is launched behind it, and with two in flight nothing
+            # more closes — the backlog below builds up deterministically.
+            parked = []
+            for n in (1, 2):
+                parked += [b.submit(_img(), _img()) for _ in range(n)]
+                deadline = time.perf_counter() + 5.0
+                while b.queue_depth and time.perf_counter() < deadline:
+                    time.sleep(0.002)
             futs = [b.submit(_img(), _img()) for _ in range(8)]
             gate.set()
-            sentinel.result(timeout=10)
+            for f in parked:
+                f.result(timeout=10)
             res = [f.result(timeout=10) for f in futs]
         finally:
             gate.set()
             b.stop()
-        iters_used = [it for _, it in eng.batches[1:]]  # drop the sentinel
+        assert eng.batches[:2] == [(1, 8), (2, 8)]  # the parked two
+        iters_used = [it for _, it in eng.batches[2:]]
         # Backlogs drain 8 -> 6 -> 4 -> 2 in batches of 2: the first three
         # cross the threshold (4) and degrade, the last recovers to full.
         assert iters_used == [2, 2, 2, 8]
@@ -323,6 +329,274 @@ class TestBatcherTakeRule:
         assert metrics.timeouts.value == 3
 
 
+class HalvesStub(StubEngine):
+    """The engine contract in two halves.  ``launch_batch`` records the
+    call and returns; ``finish_batch`` blocks on the gate of its batch
+    (keyed by the tag of its first row, see ``_tagged``), then records
+    itself.  ``calls`` is the order the batcher made them in."""
+
+    def __init__(self, row_counts=(1, 4), fail_launch=(), fail_finish=()):
+        super().__init__()
+        if row_counts is not None:
+            self.row_counts = row_counts
+        self.fail_launch, self.fail_finish = fail_launch, fail_finish
+        self.calls = []
+        self._gates = {}
+        self._lock = threading.Lock()
+
+    def gate_of(self, tag):
+        with self._lock:
+            return self._gates.setdefault(tag, threading.Event())
+
+    def open_all(self, tags):
+        for tag in tags:
+            self.gate_of(tag).set()
+
+    def launch_batch(self, pairs, iters, mode=None):
+        tags = [int(p[0][0, 0, 0]) for p in pairs]
+        with self._lock:
+            self.calls.append(("launch", tags))
+        if tags[0] in self.fail_launch:
+            raise RuntimeError(f"launch of {tags} failed")
+        return types.SimpleNamespace(tags=tags, iters=iters, segments=None)
+
+    def finish_batch(self, pending):
+        assert self.gate_of(pending.tags[0]).wait(10.0)
+        with self._lock:
+            self.calls.append(("finish", pending.tags))
+        if pending.tags[0] in self.fail_finish:
+            raise RuntimeError(f"finish of {pending.tags} failed")
+        return [np.full((60, 90), t, np.float32) for t in pending.tags]
+
+    def launches(self):
+        with self._lock:
+            return [tags for what, tags in self.calls if what == "launch"]
+
+    def most_in_flight(self):
+        """Launched and not yet finished, at its highest over ``calls``
+        (a failed launch never flew)."""
+        flying = most = 0
+        with self._lock:
+            for what, tags in self.calls:
+                if tags[0] in self.fail_launch:
+                    continue
+                flying += 1 if what == "launch" else -1
+                most = max(most, flying)
+        return most
+
+
+def _until(cond, what, timeout=10.0):
+    """Wait for the batcher's threads to get somewhere (no wall-clock
+    assertion: the bound is only there to fail instead of hanging)."""
+    deadline = time.perf_counter() + timeout
+    while not cond():
+        assert time.perf_counter() < deadline, f"never happened: {what}"
+        time.sleep(0.002)
+
+
+class TestLaunchAhead:
+    """Launch-ahead of depth one: with one dispatch in flight a batch
+    closes only when the queued rows fill the largest compiled count,
+    and is launched behind the running one; at most two fly."""
+
+    @staticmethod
+    @contextlib.contextmanager
+    def _running(eng, **cfg_kw):
+        """A started batcher with request 0 launched alone and held in
+        ``finish`` behind its gate; on leaving every gate opens."""
+        cfg = _cfg(**{"max_batch_size": 4, "max_wait_ms": 1.0,
+                      "queue_limit": 64, "degrade_queue_depth": 64,
+                      **cfg_kw})
+        metrics = ServeMetrics()
+        b = DynamicBatcher(eng, cfg, metrics).start()
+        try:
+            first = b.submit(*_tagged(0))
+            _until(lambda: eng.launches() == [[0]], "request 0 launched")
+            yield b, metrics, first
+        finally:
+            eng.open_all(range(64))
+            b.stop()
+
+    @pytest.mark.parametrize("max_batch,row_counts", [
+        (4, (1, 4)), (4, None), (1, (1,)), (8, (1, 4, 8))])
+    def test_full_batch_is_launched_before_the_running_one_finishes(
+            self, max_batch, row_counts):
+        """Call order launch A, launch B, finish A, finish B — with
+        ``max_batch_size`` 1 one row is a full batch."""
+        eng = HalvesStub(row_counts)
+        with self._running(eng, max_batch_size=max_batch) as (b, metrics,
+                                                              first):
+            tags = list(range(1, max_batch + 1))
+            futs = [b.submit(*_tagged(t)) for t in tags]
+            _until(lambda: eng.launches() == [[0], tags],
+                   "the full batch launched behind the running one")
+            assert not first.done()
+            eng.open_all([0, 1])
+            res = [f.result(timeout=10) for f in [first] + futs]
+        assert eng.calls == [("launch", [0]), ("launch", tags),
+                             ("finish", [0]), ("finish", tags)]
+        assert [int(r.disparity[0, 0]) for r in res] == [0] + tags
+        assert [r.batch_size for r in res] == [1] + [max_batch] * max_batch
+        assert metrics.launched_ahead.value == 1
+        assert metrics.batch_size.count == 2
+
+    @pytest.mark.parametrize("row_counts,then", [
+        ((1, 4), [[1], [2], [3]]), (None, [[1, 2, 3]]),
+        ((1, 2, 4), [[1, 2], [3]])])
+    def test_partial_queue_waits_and_is_taken_by_the_old_rule(
+            self, row_counts, then):
+        """Three rows of a possible four are NOT launched ahead; the
+        moment the running dispatch is answered the rule for no
+        dispatch in flight takes them (their deadline has passed)."""
+        eng = HalvesStub(row_counts)
+        with self._running(eng) as (b, metrics, first):
+            futs = [b.submit(*_tagged(t)) for t in (1, 2, 3)]
+            _until(lambda: b.queue_depth == 3, "three rows queued")
+            time.sleep(0.05)  # fifty deadlines: time to get it wrong
+            assert eng.calls == [("launch", [0])]
+            eng.open_all([1, 2, 3])  # only request 0 holds anything up
+            eng.gate_of(0).set()
+            res = [f.result(timeout=10) for f in futs]
+        want = [("launch", [0]), ("finish", [0])]
+        for tags in then:
+            want += [("launch", tags), ("finish", tags)]
+        assert eng.calls == want
+        assert [int(r.disparity[0, 0]) for r in res] == [1, 2, 3]
+        assert metrics.launched_ahead.value == 0
+
+    def test_never_more_than_two_in_flight_and_replies_in_launch_order(
+            self):
+        eng = HalvesStub((1, 4))
+        answered = []
+        with self._running(eng) as (b, metrics, first):
+            first.add_done_callback(lambda f: answered.append(0))
+            futs = []
+            for t in range(1, 13):  # three full batches behind request 0
+                futs.append(b.submit(*_tagged(t)))
+                futs[-1].add_done_callback(
+                    lambda f, t=t: answered.append(t))
+            _until(lambda: len(eng.launches()) == 2, "one batch ahead")
+            _until(lambda: b.queue_depth == 8, "two batches queued")
+            time.sleep(0.05)
+            assert eng.launches() == [[0], [1, 2, 3, 4]]  # and no third
+            # one answered, one launched: each behind a batch still held
+            for tag, n in ((0, 3), (1, 4)):
+                eng.gate_of(tag).set()
+                _until(lambda: len(eng.launches()) == n,
+                       f"launch {n} once batch {tag} was answered")
+            eng.open_all(range(13))
+            for f in futs:
+                f.result(timeout=10)
+        assert eng.launches() == [[0], [1, 2, 3, 4], [5, 6, 7, 8],
+                                  [9, 10, 11, 12]]
+        assert eng.most_in_flight() == 2
+        assert answered == list(range(13))
+        # every full batch found one in flight when it closed
+        assert metrics.launched_ahead.value == 3
+
+    @pytest.mark.parametrize("where,failing", [
+        ("finish", 0), ("finish", 1), ("launch", 1)])
+    def test_a_failed_dispatch_fails_its_own_batch_and_nothing_else(
+            self, where, failing):
+        """Batch A = request 0, batch B = requests 1-4 launched ahead:
+        whichever fails, in whichever half, the other is answered."""
+        eng = HalvesStub((1, 4), **{f"fail_{where}": (failing,)})
+        with self._running(eng) as (b, metrics, first):
+            futs = [b.submit(*_tagged(t)) for t in (1, 2, 3, 4)]
+            _until(lambda: len(eng.launches()) == 2, "B launched ahead")
+            eng.open_all([0, 1])
+            batches = {0: [first], 1: futs}
+            for f in batches.pop(failing):
+                with pytest.raises(RuntimeError, match=f"{where} of"):
+                    f.result(timeout=10)
+            (sound,) = batches.values()
+            assert [int(f.result(timeout=10).disparity[0, 0])
+                    for f in sound] == ([0] if failing else [1, 2, 3, 4])
+            # and the batcher keeps serving
+            again = b.submit(*_tagged(9))
+            eng.gate_of(9).set()
+            assert int(again.result(timeout=10).disparity[0, 0]) == 9
+        assert metrics.errors.value == (4 if failing else 1)
+        assert eng.most_in_flight() <= 2
+
+    def test_many_submitters_lose_nothing_and_never_fly_three(self):
+        """Sixteen submitting threads against the two batcher threads,
+        the interpreter switching every 10 us: every request is answered
+        with its own row, launched exactly once, batches are finished in
+        the order they were launched, and never more than two fly."""
+        import sys
+
+        eng = HalvesStub((1, 4))
+        n_threads, per_thread = 16, 20
+        total = n_threads * per_thread
+        eng.open_all(range(total))  # finish never blocks
+        cfg = _cfg(max_batch_size=4, max_wait_ms=1.0, queue_limit=total,
+                   degrade_queue_depth=total + 1)
+        got, errors = {}, []
+
+        def submitter(b, base):
+            try:
+                for t in range(base, base + per_thread):
+                    got[t] = b.submit(*_tagged(t))
+            except Exception as e:  # surfaced below
+                errors.append(e)
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            with DynamicBatcher(eng, cfg) as b:
+                threads = [threading.Thread(target=submitter,
+                                            args=(b, i * per_thread))
+                           for i in range(n_threads)]
+                for th in threads:
+                    th.start()
+                for th in threads:
+                    th.join(30.0)
+                    assert not th.is_alive()
+                assert not errors and len(got) == total
+                for t, f in got.items():
+                    assert int(f.result(timeout=30).disparity[0, 0]) == t
+        finally:
+            sys.setswitchinterval(interval)
+        launched = eng.launches()
+        assert sorted(t for tags in launched for t in tags) \
+            == list(range(total))
+        assert [tags for what, tags in eng.calls if what == "finish"] \
+            == launched
+        assert eng.most_in_flight() <= 2
+
+    @pytest.mark.parametrize("drain", [True, False])
+    def test_stop_answers_both_in_flight(self, drain):
+        """``stop(drain=True)`` answers the two in flight and the rows
+        still queued; ``stop(drain=False)`` fails only the queued."""
+        from raftstereo_tpu.serve.batcher import ShuttingDown
+
+        eng = HalvesStub((1, 4))
+        with self._running(eng) as (b, metrics, first):
+            flying = [b.submit(*_tagged(t)) for t in (1, 2, 3, 4)]
+            _until(lambda: len(eng.launches()) == 2, "B launched ahead")
+            queued = [b.submit(*_tagged(t)) for t in (5, 6)]
+            stopper = threading.Thread(target=b.stop,
+                                       kwargs={"drain": drain})
+            stopper.start()
+            if not drain:  # failed at once, with both batches still held
+                for f in queued:
+                    with pytest.raises(ShuttingDown):
+                        f.result(timeout=10)
+                assert not first.done() and not flying[0].done()
+            eng.open_all(range(7))
+            stopper.join(10.0)
+            assert not stopper.is_alive()
+            assert [int(f.result(timeout=10).disparity[0, 0])
+                    for f in [first] + flying] == [0, 1, 2, 3, 4]
+            if drain:
+                assert [int(f.result(timeout=10).disparity[0, 0])
+                        for f in queued] == [5, 6]
+        assert eng.launches() == [[0], [1, 2, 3, 4]] + (
+            [[5], [6]] if drain else [])
+        assert b._thread.is_alive() is b._finisher.is_alive() is False
+
+
 # ------------------------------------------------------------------- engine
 
 class TestEngine:
@@ -404,12 +678,16 @@ class TestRowCounts:
                                          queue_limit=32), metrics)
         seen = []
 
-        def spy(key, call):
-            rows = int(key[4][1:])
+        def spy_launch(key, call, padders=()):
             seen.append((key, dict(eng._seg.pad_px)))
+            return types.SimpleNamespace(key=key, padders=padders,
+                                         segments=None)
+
+        def spy_finish(launched):
+            rows = int(launched.key[4][1:])
             return [np.zeros((rows, 32, 64, 1), np.float32)], False
 
-        eng._dispatch = spy
+        eng._launch, eng._finish = spy_launch, spy_finish
         # bucket-sized images (32x64): every staged pixel is a real one
         pairs = [(_img(32, 64, i), _img(32, 64, 50 + i)) for i in range(n)]
         out = eng.infer_batch(pairs, 3)
@@ -428,19 +706,25 @@ class TestRowCounts:
         assert metrics.batch_rows.value == len(want)
 
     @pytest.mark.parametrize("n", [1, 2, 4])
-    def test_plain_staging_holds_the_rows_in_order(self, n):
+    def test_plain_staging_holds_the_rows_in_order(self, n, retrace_guard):
         """What ``_stage_pairs`` hands the program at ``rows == n``: the
-        ``n`` real rows in order, and nothing else."""
+        ``n`` real rows in order, each padded as the device's
+        ``BucketPadder.pad`` pads it, and nothing else — staged on the
+        host, with no device program at any row count."""
         eng = BatchEngine(None, {}, _cfg(max_batch_size=4))
         pairs = [(_img(20, 40, i), _img(20, 40, 9 + i)) for i in range(n)]
-        padders, hw, i1, i2, pad_rows = eng._pad_pairs(pairs, n)
+        with retrace_guard(0, what="staging is host copies and a transfer"):
+            padders, hw, i1, i2, pad_rows = eng._pad_pairs(pairs, n)
         assert hw == (32, 64) and pad_rows == 0
         assert i1.shape == i2.shape == (n, 32, 64, 3)
+        assert isinstance(i1, jax.Array) and i1.dtype == np.float32
         for i, (padder, (a, b)) in enumerate(zip(padders, pairs)):
             np.testing.assert_array_equal(
                 padder.unpad(np.asarray(i1[i:i + 1]))[0], a)
             np.testing.assert_array_equal(
                 padder.unpad(np.asarray(i2[i:i + 1]))[0], b)
+            np.testing.assert_array_equal(
+                np.asarray(i1[i:i + 1]), np.asarray(padder.pad(a[None])))
 
     def test_stream_batch_still_pads_to_max_batch_size(self, serve_model):
         """The warm-start path keeps one program a ladder level: every
@@ -493,7 +777,7 @@ class TestRowCountEngine:
     def test_warm_n_compiles_nothing_and_matches_evaluator(
             self, warm_engine, retrace_guard, n):
         """After ``warmup()`` no ``n`` meets a program for the first
-        time — the eager staging programs included, so the guard has no
+        time — staging is host work and has none, so the guard has no
         duration floor — and every reply is bitwise the Evaluator's at
         ``batch_pad`` = the row count of the dispatch it rode in."""
         from raftstereo_tpu.eval import Evaluator
@@ -520,6 +804,121 @@ class TestRowCountEngine:
             np.testing.assert_array_equal(disp, ev(*pair))
 
 
+    @pytest.mark.parametrize("n", [1, 3])
+    def test_finish_of_launch_is_infer_batch_bit_for_bit(
+            self, warm_engine, retrace_guard, n):
+        eng, metrics = warm_engine
+        pairs = [(_img(seed=30 + i), _img(seed=40 + i)) for i in range(n)]
+        want = eng.infer_batch(pairs, 2)
+        before = metrics.batch_rows.labels(rows=str(n)).value
+        with retrace_guard(0, what="the two halves run infer_batch's "
+                                   "programs"):
+            launched = eng.launch_batch(pairs, 2)
+            assert launched.segments is None  # nothing waited for yet
+            got = eng.finish_batch(launched)
+        assert len(got) == n
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(a, b)
+        assert metrics.batch_rows.labels(rows=str(n)).value == before + 1
+        seg = launched.segments
+        assert seg["pad_px"]["rows"] == n and not seg["compile"]
+        # nothing was in flight: the device was free at launch's end,
+        # and device_compute is launch + device_wait as ever
+        assert seg["device_queued"] == (seg["launch"][1],) * 2
+        assert seg["dispatch"] == (seg["launch"][0], seg["device_wait"][1])
+        assert launched.out_dev is None and launched.behind is None
+        with pytest.raises(AssertionError, match="not a compiled row count"):
+            eng.launch_batch(pairs[:1] * 2, 2)
+
+    def test_two_launches_before_either_finish_keep_their_own_rows(
+            self, warm_engine, retrace_guard):
+        """Launch A (one row), launch B (three rows), finish A, finish
+        B: each batch gets its own rows' answers, and B's windows say it
+        lay behind A — ``device_queued`` from its launch's end to A's
+        ``device_wait`` end, ``device_wait`` / ``device_compute`` from
+        there."""
+        eng, _ = warm_engine
+        pa = [(_img(seed=50), _img(seed=51))]
+        pb = [(_img(seed=60 + i), _img(seed=70 + i)) for i in range(3)]
+        want_a, want_b = eng.infer_batch(pa, 2), eng.infer_batch(pb, 2)
+        with retrace_guard(0, what="two dispatches in flight, both warm"):
+            a = eng.launch_batch(pa, 2)
+            b = eng.launch_batch(pb, 2)
+            assert b.behind is a and a.ready_at is None
+            got_a = eng.finish_batch(a)
+            got_b = eng.finish_batch(b)
+        for got, want in ((got_a, want_a), (got_b, want_b)):
+            assert len(got) == len(want)
+            for x, y in zip(got, want):
+                np.testing.assert_array_equal(x, y)
+        sa, sb = a.segments, b.segments
+        assert sb["device_queued"] == (sb["launch"][1],
+                                       sa["device_wait"][1])
+        assert sb["device_queued"][1] > sb["device_queued"][0]
+        assert sb["device_wait"][0] == sb["dispatch"][0] \
+            == sb["device_queued"][1]
+        assert sb["host_fetch"][0] == sb["dispatch"][1] \
+            == sb["device_wait"][1] >= sb["device_wait"][0]
+        assert b.behind is None  # the chain ends with the dispatch
+
+    def test_batcher_counts_what_it_launched_ahead_on_the_real_engine(
+            self, warm_engine):
+        """One row in flight (held in ``finish``), three queued behind
+        it: a full batch, launched ahead — the counter, ``ahead`` on the
+        spans, ``closed_by=full_ahead``, and the same bits as
+        ``infer_batch``."""
+        from raftstereo_tpu.obs import Tracer
+
+        eng, _ = warm_engine
+        gate = threading.Event()
+
+        class HeldInFinish:
+            row_counts, bucket_of = eng.row_counts, eng.bucket_of
+            launch_batch = staticmethod(eng.launch_batch)
+
+            @staticmethod
+            def finish_batch(launched):
+                assert gate.wait(60.0)
+                return eng.finish_batch(launched)
+
+        pairs = [(_img(seed=80 + i), _img(seed=90 + i)) for i in range(4)]
+        want = eng.infer_batch(pairs[:1], 2) + eng.infer_batch(pairs[1:], 2)
+        metrics, tracer = ServeMetrics(), Tracer(capacity=256)
+        cfg = _cfg(max_batch_size=3, iters=2, degraded_iters=2,
+                   max_wait_ms=1.0)
+        b = DynamicBatcher(HeldInFinish, cfg, metrics, tracer).start()
+        try:
+            futs = [b.submit(*pairs[0], trace_id="req0")]
+            _until(lambda: b.queue_depth == 0 and len(b._flying) == 1,
+                   "the single launched")
+            futs += [b.submit(*p, trace_id=f"req{i + 1}")
+                     for i, p in enumerate(pairs[1:])]
+            _until(lambda: len(b._flying) == 2, "the full batch launched")
+            assert not futs[0].done()
+            gate.set()
+            res = [f.result(timeout=60) for f in futs]
+        finally:
+            gate.set()
+            b.stop()
+        for r, d in zip(res, want):
+            np.testing.assert_array_equal(r.disparity, d)
+        assert [r.batch_size for r in res] == [1, 3, 3, 3]
+        assert metrics.launched_ahead.value == 1
+        spans = tracer.spans()
+        assert {s.trace_id: s.attrs["ahead"] for s in spans
+                if s.name == "dispatch"} == {
+            "req0": False, "req1": True, "req2": True, "req3": True}
+        by_batch = lambda name: [
+            s.attrs for s in sorted(spans, key=lambda s: s.t0)
+            if s.name == name and s.trace_id.startswith("batch:")]
+        assert [a["ahead"] for a in by_batch("launch")] == [False, True]
+        assert [(a["closed_by"], a["ahead"]) for a in by_batch(
+            "batch_form")] == [("deadline", False), ("full_ahead", True)]
+        queued = [s for s in spans if s.name == "device_queued"
+                  and s.trace_id.startswith("batch:")]
+        assert sorted(s.duration_s > 0 for s in queued) == [False, True]
+
+
 class TestKeyKinds:
     """Every kind of program the engine can compile names its kind at
     position 3 of its cache key: no two kinds share a tuple at one
@@ -534,19 +933,20 @@ class TestKeyKinds:
     @pytest.fixture(scope="class")
     def engine_and_keys(self, serve_model):
         """One engine and, per kind, the key its OWN entry point hands to
-        ``_dispatch`` / ``_dispatch_state`` — captured by a spy, so no
-        program compiles."""
+        ``_launch`` (the plain path) / ``_dispatch`` / ``_dispatch_state``
+        — captured by a spy, so no program compiles."""
         model, variables = serve_model
         metrics = ServeMetrics()
         eng = BatchEngine(model, variables,
                           _cfg(max_batch_size=2, spatial_shards=4), metrics)
-        real = eng._dispatch, eng._dispatch_state
+        real = eng._launch, eng._dispatch, eng._dispatch_state
 
         def spy(via):
-            def capture(key, call):
+            def capture(key, call, *padders):
                 raise _KeyCaptured(key, via)
             return capture
 
+        eng._launch = spy("_launch")  # the plain path's first half
         eng._dispatch = spy("_dispatch")
         eng._dispatch_state = spy("_dispatch_state")
         a, hw, it = _img(), (64, 96), 3
@@ -575,7 +975,7 @@ class TestKeyKinds:
             with pytest.raises(_KeyCaptured) as ei:
                 entry()
             keys[kind] = ei.value.args  # (key, the dispatcher it took)
-        eng._dispatch, eng._dispatch_state = real
+        eng._launch, eng._dispatch, eng._dispatch_state = real
         return eng, metrics, keys
 
     @pytest.mark.parametrize("kind", KINDS)
