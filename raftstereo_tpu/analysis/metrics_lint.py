@@ -91,6 +91,7 @@ def run_metrics_lint() -> List[Finding]:
     serve.stream_tier_pushes.labels(outcome="ok").inc()
     serve.wire_bytes.labels(direction="in", format="binary").inc(1024)
     serve.wire_tiles.labels(direction="out", coding="stored").inc()
+    serve.host_wait.labels(point="reply_lock").inc(0.25)
     serve.wire_negotiations.labels(request="binary",
                                    response="json").inc()
     serve.cascade_schedules.labels(schedule="int8:24+fp32:8").inc()

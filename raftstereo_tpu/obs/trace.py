@@ -29,6 +29,12 @@ Two recording styles:
   with ``timed_phase`` and hand its ``window`` to whoever records it.
   ``clock_annotation`` ties the two clocks (docs/observability.md).
 
+Every phase also reads its thread's CPU time and run-queue delay at both
+ends (``thread_ms``: ``cpu_ms``, and ``runq_ms`` where
+``/proc/thread-self/schedstat`` can be read); a phase's span carries them
+in its attrs.  Wall − cpu − runq is the time the thread was blocked: a
+lock, the GIL, a socket, a condition.
+
 Timestamps are ``time.perf_counter`` values (monotonic, ns-resolution);
 the export converts them to epoch microseconds with one process-wide
 offset so spans from every thread share a clock.
@@ -101,34 +107,127 @@ def _annotation(name: str, attrs: Dict):
     return _annotation_cls(name, **attrs)
 
 
+# The run-queue delay is the second field of the thread's schedstat
+# (ns spent runnable but waiting for a core).  Opened once per thread.
+_SCHEDSTAT = "/proc/thread-self/schedstat"
+
+
+class _RunQueue:
+    """This thread's schedstat file, held open; ``read()`` is the run-queue
+    delay in ns so far, or None where the file cannot be read (not Linux,
+    or a kernel without schedstat)."""
+
+    __slots__ = ("fd",)
+
+    def __init__(self):
+        try:
+            self.fd = os.open(_SCHEDSTAT, os.O_RDONLY)
+        except OSError:
+            self.fd = -1
+
+    def read(self) -> Optional[int]:
+        if self.fd < 0:
+            return None
+        try:
+            return int(os.pread(self.fd, 64, 0).split()[1])
+        except (OSError, IndexError, ValueError):
+            return None
+
+    def __del__(self, _close=os.close):  # the thread's locals die with it
+        if self.fd >= 0:
+            _close(self.fd)
+
+
+_threads = threading.local()
+
+
+def _forget_threads() -> None:
+    # a forked child's threads are not its parent's: open anew
+    global _threads
+    _threads = threading.local()
+
+
+os.register_at_fork(after_in_child=_forget_threads)
+
+
+def _runq_ns() -> Optional[int]:
+    rq = getattr(_threads, "runq", None)
+    if rq is None:
+        rq = _threads.runq = _RunQueue()
+    return rq.read()
+
+
 class timed_phase:
     """``with timed_phase("launch", batch_size=8) as ph:`` holds a
     ``TraceAnnotation`` open and reads ``perf_counter`` just inside it
     at both ends: ``ph.window`` is the ``(t0, t1)`` a ring span of the
     same phase is recorded from (``Tracer.record(name, *ph.window,
-    ...)``), so the annotation and the span bracket the same code."""
+    ...)``), so the annotation and the span bracket the same code.
 
-    __slots__ = ("name", "attrs", "t0", "t1", "_ann")
+    Inside the two ``perf_counter`` reads it reads the thread's CPU time
+    and run-queue delay; ``ph.thread_ms`` is their change over the phase,
+    ``{"cpu_ms", "runq_ms"}`` (``runq_ms`` left out where schedstat
+    cannot be read), so each is at most the window's length.
 
-    def __init__(self, name: str, **attrs):
+    Adjacent phases meet on one clock read: ``start`` begins the window
+    at a time read before (the end of the phase before), and
+    ``ends_at(t)`` ends it at a time read elsewhere — the window then no
+    longer brackets the thread reads, and ``thread_ms`` is empty."""
+
+    __slots__ = ("name", "attrs", "start", "t0", "t1", "thread_ms",
+                 "_ann", "_end", "_c0", "_r0")
+
+    def __init__(self, name: str, start: Optional[float] = None, **attrs):
         self.name = name
         self.attrs = attrs
+        self.start = start
         self.t0 = self.t1 = 0.0
+        self.thread_ms: Dict[str, float] = {}
+        self._end = None
+
+    def ends_at(self, t: float) -> None:
+        self._end = t
 
     def __enter__(self) -> "timed_phase":
         self._ann = _annotation(self.name, self.attrs)
         self._ann.__enter__()
-        self.t0 = time.perf_counter()
+        t0 = time.perf_counter()
+        self._c0 = time.thread_time_ns()
+        self._r0 = _runq_ns()
+        self.t0 = t0 if self.start is None else self.start
         return self
 
     def __exit__(self, *exc) -> bool:
+        c1 = time.thread_time_ns()
+        r1 = _runq_ns() if self._r0 is not None else None
         self.t1 = time.perf_counter()
         self._ann.__exit__(*exc)
+        if self._end is not None:
+            self.t1 = self._end
+            return False
+        self.thread_ms["cpu_ms"] = (c1 - self._c0) * 1e-6
+        if r1 is not None:
+            self.thread_ms["runq_ms"] = (r1 - self._r0) * 1e-6
         return False
 
     @property
     def window(self) -> Tuple[float, float]:
         return self.t0, self.t1
+
+
+class _Wall:
+    """The clock of a plain ``span``: two ``perf_counter`` reads."""
+
+    __slots__ = ("t0", "t1")
+    thread_ms: Dict[str, float] = {}
+
+    def __enter__(self) -> "_Wall":
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.perf_counter()
+        return False
 
 
 def clock_annotation():
@@ -148,14 +247,21 @@ def clock_annotation():
 
 
 class _Live:
-    """Handle yielded by ``Tracer.span`` — mutate ``attrs`` mid-span."""
+    """Handle yielded by ``Tracer.span`` — mutate ``attrs`` mid-span;
+    ``t0`` is the span's start, ``t1`` its end once it has closed."""
 
-    __slots__ = ("trace_id", "span_id", "attrs")
+    __slots__ = ("trace_id", "span_id", "attrs", "t0", "t1", "_clock")
 
-    def __init__(self, trace_id: str, span_id: str, attrs: Dict):
+    def __init__(self, trace_id: str, span_id: str, attrs: Dict, clock):
         self.trace_id = trace_id
         self.span_id = span_id
         self.attrs = attrs
+        self._clock = clock
+        self.t0 = self.t1 = 0.0
+
+    def ends_at(self, t: float) -> None:
+        """A phase's ``timed_phase.ends_at``."""
+        self._clock.ends_at(t)
 
 
 class Tracer:
@@ -223,15 +329,33 @@ class Tracer:
             self._spans.append(span)
         return sid
 
-    @contextlib.contextmanager
     def span(self, name: str, trace_id: Optional[str] = None,
-             parent_id: Optional[str] = None, **attrs) -> Iterator[_Live]:
+             parent_id: Optional[str] = None, **attrs):
         """Context-managed span; nests via a thread-local stack.
 
         With no explicit ``trace_id`` the span joins this thread's current
         trace (becoming a child of the innermost open span) or starts a
         fresh trace when there is none.
         """
+        return self._open(name, trace_id, parent_id, attrs, _Wall())
+
+    def phase(self, name: str, trace_id: Optional[str] = None,
+              parent_id: Optional[str] = None,
+              start: Optional[float] = None, **attrs):
+        """``span`` timed by a ``timed_phase``: one ring span, and while a
+        profile runs one ``jax.profiler.TraceAnnotation`` event of the
+        same name on the trace's host plane, held open for its length.
+        The span's attrs gain the phase's ``cpu_ms`` / ``runq_ms``.  The
+        annotation sees the attrs given here; attrs set on the yielded
+        handle later go to the ring only.  ``start`` and the handle's
+        ``ends_at`` are ``timed_phase``'s."""
+        return self._open(name, trace_id, parent_id, attrs,
+                          timed_phase(name, start, **attrs))
+
+    @contextlib.contextmanager
+    def _open(self, name: str, trace_id: Optional[str],
+              parent_id: Optional[str], attrs: Dict,
+              clock) -> Iterator[_Live]:
         cur = self.current()
         if trace_id is None:
             if cur is not None:
@@ -243,36 +367,26 @@ class Tracer:
         elif parent_id is None and cur is not None and cur[0] == trace_id:
             parent_id = cur[1]
         sid = self.new_span_id()
-        live = _Live(trace_id, sid, dict(attrs))
+        live = _Live(trace_id, sid, dict(attrs), clock)
         stack = getattr(self._tls, "stack", None)
         if stack is None:
             stack = self._tls.stack = []
         stack.append((trace_id, sid))
-        t0 = time.perf_counter()
         try:
-            yield live
+            with clock:
+                live.t0 = clock.t0
+                yield live
         finally:
-            t1 = time.perf_counter()
             stack.pop()
+            live.t0, live.t1 = clock.t0, clock.t1
+            live.attrs.update(clock.thread_ms)
             span = Span(trace_id=trace_id, span_id=sid, parent_id=parent_id,
-                        name=name, t0=t0, t1=t1,
+                        name=name, t0=clock.t0, t1=clock.t1,
                         thread=threading.current_thread().name,
                         attrs=live.attrs)
             with self._lock:
                 self._recorded += 1
                 self._spans.append(span)
-
-    @contextlib.contextmanager
-    def phase(self, name: str, trace_id: Optional[str] = None,
-              parent_id: Optional[str] = None, **attrs) -> Iterator[_Live]:
-        """``span`` plus a ``jax.profiler.TraceAnnotation(name, **attrs)``
-        held open for its length: one ring span, and while a profile
-        runs one event of the same name on the trace's host plane.  The
-        annotation sees the attrs given here; attrs set on the yielded
-        handle later go to the ring only."""
-        with _annotation(name, attrs), \
-                self.span(name, trace_id, parent_id, **attrs) as live:
-            yield live
 
     # -------------------------------------------------------------- reading
 

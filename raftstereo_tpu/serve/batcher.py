@@ -356,13 +356,14 @@ class DynamicBatcher:
     def _timed_out(self, r: _Request, now: float) -> bool:
         return now - r.t_enqueue > self.cfg.request_timeout_ms / 1000.0
 
-    def _phase(self, name: str, btid: Optional[str], **attrs):
+    def _phase(self, name: str, btid: Optional[str],
+               start: Optional[float] = None, **attrs):
         """One phase of the worker's cycle: a ring span under the batch's
         trace plus a profiler annotation (``Tracer.phase``); without a
         tracer the annotation alone."""
         if self.tracer is None:
-            return timed_phase(name, **attrs)
-        return self.tracer.phase(name, trace_id=btid, **attrs)
+            return timed_phase(name, start, **attrs)
+        return self.tracer.phase(name, trace_id=btid, start=start, **attrs)
 
     def _await_close(self, max_wait_s: float) -> Optional[str]:  # guarded_by: _cv
         """Block until the oldest group's batch may close; returns why
@@ -402,22 +403,31 @@ class DynamicBatcher:
         """The launcher's cycle, in phases that leave no hole on its
         thread, each recorded ONCE per dispatch under the batch's own
         trace ``batch:<seq>``: ``queue_empty`` (nothing queued) ->
-        ``batch_form`` (first request seen -> batch closed; the wait for
-        a dispatch in flight is in it) -> ``pad_bucket`` -> ``launch``
+        ``batch_form`` (first request queued -> batch closed; the wait
+        for a dispatch in flight is in it) -> ``pad_bucket`` -> ``launch``
         (the engine's, handed over in ``pending.segments``).  The
-        finisher's follow in ``_finish_loop``."""
+        finisher's follow in ``_finish_loop``.  ``queue_empty`` ends and
+        ``batch_form`` starts at one clock read, the enqueue of the
+        request that ended the wait: the launcher's own wake-up is
+        batch_form's, and the two meet exactly."""
         max_wait_s = self.cfg.max_wait_ms / 1000.0
         try:
             while True:
                 btid = f"batch:{next(_BATCH_SEQ)}"
                 with self._cv:
+                    t_first = None
                     if not self._closed and self._depth == 0:
-                        with self._phase("queue_empty", btid):
+                        with self._phase("queue_empty", btid) as empty:
                             while not self._closed and self._depth == 0:
                                 self._cv.wait()
+                            if self._depth:
+                                t_first = self._queues[
+                                    self._oldest_key()][0].t_enqueue
+                                empty.ends_at(t_first)
                     if self._depth == 0:  # closed and drained
                         return
-                    with self._phase("batch_form", btid) as form:
+                    with self._phase("batch_form", btid,
+                                     start=t_first) as form:
                         closed_by = self._await_close(max_wait_s)
                         if closed_by is None:  # drained by a non-drain stop
                             continue
@@ -485,16 +495,21 @@ class DynamicBatcher:
                if error is None else None)
         bucket = f"{key[0]}x{key[1]}"
         if seg is not None and flight.btid is not None:
-            battrs = {"batch_size": len(batch), "bucket": bucket,
-                      "iters": flight.iters,
-                      "request_ids": [r.trace_id for r in batch
+            # the batch's size and bucket are on its batch_form
+            battrs = {"request_ids": [r.trace_id for r in batch
                                       if r.trace_id is not None]}
             # pad_bucket also says what the dispatch was staged at (rows
-            # / real_px / bucket_px, engine._pad_pairs); launch whether
-            # it ran ahead of the dispatch before it
-            extra = {"pad_bucket": seg.get("pad_px") or {},
+            # / real_px / bucket_px, engine._pad_pairs), its two parts
+            # the row count; launch whether it ran ahead of the dispatch
+            # before it; every timed phase its thread's cpu_ms / runq_ms
+            px = seg.get("pad_px") or {}
+            rows = {"rows": px["rows"]} if "rows" in px else {}
+            extra = {"pad_bucket": px, "stage_copy": rows, "h2d_put": rows,
                      "launch": {"ahead": flight.ahead}}
+            thread_ms = seg.get("thread_ms") or {}
             for name, window in (("pad_bucket", seg.get("pad")),
+                                 ("stage_copy", seg.get("stage_copy")),
+                                 ("h2d_put", seg.get("h2d_put")),
                                  ("launch", seg.get("launch")),
                                  ("device_queued", seg.get("device_queued")),
                                  ("device_wait", seg.get("device_wait")),
@@ -502,13 +517,13 @@ class DynamicBatcher:
                 if window:
                     self.tracer.record(
                         name, *window, flight.btid,
-                        attrs={**battrs, **extra.get(name, {})})
+                        attrs={**battrs, **extra.get(name, {}),
+                               **thread_ms.get(name, {})})
         for r in batch:
             if r.trace_id is None:
                 continue
-            self.tracer.record(
-                "queue_wait", r.t_enqueue, t_run0, r.trace_id,
-                attrs={"bucket": bucket})
+            self.tracer.record("queue_wait", r.t_enqueue, t_run0,
+                               r.trace_id)
             attrs = {"bucket": bucket, "iters": flight.iters,
                      "degraded": flight.degraded, "batch_size": len(batch),
                      "ahead": flight.ahead}
@@ -595,8 +610,7 @@ class DynamicBatcher:
         alive = flight.batch
         # reply_handoff: from the engine's return (disparities un-padded)
         # until every future of the batch is resolved.
-        with self._phase("reply_handoff", flight.btid,
-                         batch_size=len(alive)):
+        with self._phase("reply_handoff", flight.btid):
             done = time.perf_counter()
             if self.tracer is not None:
                 self._trace_batch(flight, done)

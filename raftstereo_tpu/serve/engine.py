@@ -94,6 +94,10 @@ class _Launched:
     launch: Tuple[float, float]
     pad: Optional[Tuple[float, float]]
     pad_px: Optional[Dict[str, int]]
+    # ``stage_copy`` / ``h2d_put`` windows (the two parts of ``pad``), and
+    # every phase's thread times so far (``timed_phase.thread_ms``)
+    stage: Dict[str, Tuple[float, float]]
+    thread_ms: Dict[str, Dict[str, float]]
     # The dispatch launched before this one on the same engine: until its
     # ``ready_at`` the device is not free to take this one up.
     behind: Optional["_Launched"]
@@ -599,11 +603,14 @@ class BatchEngine:
         """Phase timing of the last dispatch this thread ran to its end
         (``infer_batch`` and the synchronous paths; a dispatch the
         batcher launches and finishes on two threads carries its own,
-        ``_Launched.segments``): ``{"pad", "launch", "device_queued",
-        "device_wait", "dispatch", "host_fetch"}`` as (perf_counter t0,
-        t1) windows plus ``"pad_px"`` and ``"compile"`` — the raw
-        material the batcher and stream runner turn into trace spans
-        (obs/trace.py).  ``dispatch`` (the ``device_compute`` span) is
+        ``_Launched.segments``): ``{"pad", "stage_copy", "h2d_put",
+        "launch", "device_queued", "device_wait", "dispatch",
+        "host_fetch"}`` as (perf_counter t0, t1) windows (``stage_copy``
+        and ``h2d_put`` tile ``pad``; a plain dispatch's) plus
+        ``"pad_px"``, ``"compile"`` and ``"thread_ms"`` (per timed phase,
+        ``timed_phase.thread_ms``) — the raw material the batcher and
+        stream runner turn into trace spans (obs/trace.py).
+        ``dispatch`` (the ``device_compute`` span) is
         ``launch`` (the jitted call until it returns) followed by
         ``device_wait`` (``block_until_ready``); behind another dispatch
         it starts where ``device_queued`` ends (``_finish``).  ``launch``,
@@ -632,17 +639,14 @@ class BatchEngine:
             staged = self._stage_pairs(pairs, rows)
         self._seg.pad = ph.window
         self._seg.pad_px = px
+        # the finished phases, for the next _launch on this thread
+        self._seg.staged = (ph, *self._seg.staged)
         return staged
 
     def _stage_pairs(self, pairs, rows: int):
         assert len(pairs) <= rows <= self.cfg.max_batch_size, (
             f"batch {len(pairs)} does not fit {rows} rows of "
             f"max_batch_size {self.cfg.max_batch_size}")
-        padders = [self._padder(p[0].shape) for p in pairs]
-        hw = padders[0].bucket_hw
-        assert all(p.bucket_hw == hw for p in padders), (
-            "mixed buckets in one batch: "
-            f"{sorted({p.bucket_hw for p in padders})}")
         # Staged on the HOST, row by row into one array a side, then one
         # transfer each: no device program.  The eager form (an expand,
         # two pads and a concatenate per row: ~50 tiny programs for eight
@@ -650,20 +654,30 @@ class BatchEngine:
         # whenever a dispatch was running, so nothing could be staged
         # behind it (PERF.md §6, PR 35).  Fresh arrays every time: the
         # transfer may read them after this returns.
-        pad_rows = rows - len(pairs)
-        shape = (rows, *hw, pairs[0][0].shape[2])
-        # zeros only on the warm-start path (a plain dispatch is staged
-        # at its own length): the rows nobody sent
-        left, right = ((np.zeros if pad_rows else np.empty)(
-            shape, np.float32) for _ in range(2))
-        for i, ((im1, im2), padder) in enumerate(zip(pairs, padders)):
-            padder.pad_into(left[i], im1)
-            padder.pad_into(right[i], im2)
+        # Two phases that tile pad_bucket, meeting on one clock read:
+        # stage_copy (the host copies) and h2d_put (the transfers).
+        with timed_phase("stage_copy", rows=rows) as ph_copy:
+            padders = [self._padder(p[0].shape) for p in pairs]
+            hw = padders[0].bucket_hw
+            assert all(p.bucket_hw == hw for p in padders), (
+                "mixed buckets in one batch: "
+                f"{sorted({p.bucket_hw for p in padders})}")
+            pad_rows = rows - len(pairs)
+            shape = (rows, *hw, pairs[0][0].shape[2])
+            # zeros only on the warm-start path (a plain dispatch is
+            # staged at its own length): the rows nobody sent
+            left, right = ((np.zeros if pad_rows else np.empty)(
+                shape, np.float32) for _ in range(2))
+            for i, ((im1, im2), padder) in enumerate(zip(pairs, padders)):
+                padder.pad_into(left[i], im1)
+                padder.pad_into(right[i], im2)
         # Under _device_ctx: a pinned replica's inputs must land on ITS
         # device — put on the global default they would pay a
         # device-to-device copy per dispatch.
-        with self._device_ctx():
-            i1, i2 = jnp.asarray(left), jnp.asarray(right)
+        with timed_phase("h2d_put", ph_copy.t1, rows=rows) as ph_put:
+            with self._device_ctx():
+                i1, i2 = jnp.asarray(left), jnp.asarray(right)
+        self._seg.staged = (ph_copy, ph_put)
         return padders, hw, i1, i2, pad_rows
 
     def _launch(self, key, call, padders=()) -> _Launched:
@@ -700,10 +714,15 @@ class BatchEngine:
             self.last_included_compile = miss
             with self._stats_lock:  # the call has compiled what it missed
                 self._compiled.add(key)
+            # the staging phases belong to this launch alone
+            staged, self._seg.staged = getattr(self._seg, "staged", ()), ()
             launched = _Launched(
                 key, labels, miss, out_dev, ph_launch.window,
                 getattr(self._seg, "pad", None),
                 getattr(self._seg, "pad_px", None),
+                stage={ph.name: ph.window for ph in staged[1:]},
+                thread_ms={ph.name: ph.thread_ms
+                           for ph in (*staged, ph_launch)},
                 behind=self._last_launched, padders=padders)
             self._last_launched = launched
         return launched
@@ -751,8 +770,14 @@ class BatchEngine:
                 "compile", t_launch0, t_compute, "xla",
                 attrs={"kind": "bucket", **launched.labels,
                        **self._program_facts(launched.key)})
+        # a queued dispatch's device_wait window starts later than the
+        # phase did, and so carries no thread times
+        thread_ms = {**launched.thread_ms, "host_fetch": ph_fetch.thread_ms}
+        if not queued:
+            thread_ms["device_wait"] = ph_wait.thread_ms
         launched.segments = {
             "pad": launched.pad,
+            **launched.stage,
             "pad_px": launched.pad_px,
             "launch": launched.launch,
             "device_queued": (t_launch1, t_free),
@@ -760,6 +785,7 @@ class BatchEngine:
             "dispatch": (start, t_compute),
             "host_fetch": (t_compute, t_fetch),
             "compile": launched.miss,
+            "thread_ms": thread_ms,
         }
         if self.metrics is not None and not launched.miss:
             self.metrics.batch_latency.observe(t_fetch - start)

@@ -45,7 +45,7 @@ class Counter:
         self._lock = threading.Lock()
         self._value = 0  # guarded_by: _lock
 
-    def inc(self, n: int = 1) -> None:
+    def inc(self, n: float = 1) -> None:
         with self._lock:
             self._value += n
 
@@ -471,6 +471,16 @@ class ServeMetrics:
             "— how often the per-tile decision of wire/format.py "
             "engages; raw (compress=false) planes count no tile",
             labels=("direction", "coding"))
+        # The server's serial points on a binary request's path
+        # (serve/server.py): the `decode_slot_wait` and `reply_wait` spans.
+        self.host_wait = r.counter(
+            "serve_host_wait_seconds_total",
+            "seconds /predict handler threads spent waiting to acquire "
+            "one of the server's serial points (decode_slot = a decode "
+            "slot for a binary body, reply_lock = the turn to encode a "
+            "binary reply); its rate over wall time is the mean number "
+            "of waiters there",
+            labels=("point",))
         self.wire_negotiations = r.counter(
             "wire_negotiations_total",
             "/predict format negotiations by resolved request dialect "

@@ -59,6 +59,7 @@ stays dumb on purpose.
 from __future__ import annotations
 
 import base64
+import contextlib
 import dataclasses
 import json
 import logging
@@ -330,14 +331,28 @@ class _Handler(JsonRequestHandler):
             self._reply_ok(srv, disparity, meta, endpoint, rid, t0, ph)
 
     def _req_phase(self, srv: "StereoServer", name: str, rid: str,
-                   parent_id: Optional[str] = None):
+                   parent_id: Optional[str] = None,
+                   start: Optional[float] = None):
         """``Tracer.phase`` under this request's (continued) trace id;
         an unsampled request (trace id None) keeps the profiler
-        annotation and records no span."""
+        annotation and records no span.  A child phase passes ``start``,
+        where the phase before it (or its parent) began or ended, so the
+        children of one phase tile it on shared clock reads."""
         tid = (self._trace or (rid, None))[0]
         if tid is None:
-            return timed_phase(name)
-        return srv.tracer.phase(name, trace_id=tid, parent_id=parent_id)
+            return timed_phase(name, start)
+        return srv.tracer.phase(name, trace_id=tid, parent_id=parent_id,
+                                start=start)
+
+    @contextlib.contextmanager
+    def _wait_phase(self, srv: "StereoServer", name: str, point: str,
+                    rid: str, start: float):
+        """A ``_req_phase`` around the acquisition of one of the server's
+        serial points (a child of the phase open on this thread); its
+        seconds also go to ``serve_host_wait_seconds_total{point=}``."""
+        with self._req_phase(srv, name, rid, start=start) as ph:
+            yield ph
+        srv.metrics.host_wait.labels(point=point).inc(ph.t1 - ph.t0)
 
     @staticmethod
     def _count_tiles(srv: "StereoServer", direction: str, ph,
@@ -361,8 +376,16 @@ class _Handler(JsonRequestHandler):
             return
         meta = dict(meta)
         meta["request_id"] = rid
-        with srv.reply_encode:
-            frame = wire.encode_response(disparity, meta, **ctx)
+        # reply_wait -> reply_encode -> reply_write tile `reply`
+        with self._wait_phase(srv, "reply_wait", "reply_lock", rid,
+                              ph.t0) as wait:
+            srv.reply_encode.acquire()
+        try:
+            with self._req_phase(srv, "reply_encode", rid,
+                                 start=wait.t1) as enc:
+                frame = wire.encode_response(disparity, meta, **ctx)
+        finally:
+            srv.reply_encode.release()
         srv.metrics.wire_bytes.labels(
             direction="out", format="binary").inc(len(frame))
         self._count_tiles(srv, "out", ph, wire.tile_census(frame))
@@ -372,8 +395,10 @@ class _Handler(JsonRequestHandler):
                           parent_id=parent,
                           attrs={"endpoint": endpoint, "status": 200,
                                  "outcome": "ok"})
-        self._send(200, frame, wire.WIRE_CONTENT_TYPE,
-                   {"X-Request-Id": rid})
+        with self._req_phase(srv, "reply_write", rid, start=enc.t1):
+            self._send(200, frame, wire.WIRE_CONTENT_TYPE,
+                       {"X-Request-Id": rid})
+            del frame  # freed inside the phase, not in reply's tail
 
     # ------------------------------------------------------------- endpoints
     def do_GET(self):
@@ -679,7 +704,13 @@ class _Handler(JsonRequestHandler):
         priority, accuracy, spatial)`` with the in-flight count taken
         (the caller owes ``end_predict``), or None when the request was
         refused and already answered."""
-        with srv.decode_slots:
+        # binary: decode_slot_wait -> body_read -> widen tile the
+        # request's wire_decode
+        with self._wait_phase(srv, "decode_slot_wait", "decode_slot", rid,
+                              ph.t0) \
+                if binary_in else contextlib.nullcontext() as slot:
+            srv.decode_slots.acquire()
+        try:
             if binary_in:
                 # Decoded planes may legitimately exceed the body byte
                 # count (tile compression, uint8->float32 promotion is
@@ -690,7 +721,9 @@ class _Handler(JsonRequestHandler):
                     max_payload_bytes=int(
                         srv.config.max_body_mb * 2 ** 20) * 8)
                 try:
-                    complete = self._read_body_stream(length, dec.feed)
+                    with self._req_phase(srv, "body_read", rid,
+                                         start=slot.t1) as body:
+                        complete = self._read_body_stream(length, dec.feed)
                 except wire.WireError as e:
                     # Mid-body reject: the unread remainder can never
                     # be reframed — the connection must close.
@@ -736,13 +769,20 @@ class _Handler(JsonRequestHandler):
             try:
                 if binary_in:
                     self._count_tiles(srv, "in", ph, dec.census())
-                    req = dec.request()
-                    # Mirror decode_array's contract: the engine always
-                    # sees float32 (exact for uint8/int16 payloads).
-                    left = np.ascontiguousarray(req.left, np.float32)
-                    right = np.ascontiguousarray(req.right, np.float32)
-                    payload = req.fields
-                    del dec, req
+                    # from the body's end: the gate and census above,
+                    # then the decoded planes as float32
+                    with self._req_phase(srv, "widen", rid, start=body.t1):
+                        req = dec.request()
+                        # Mirror decode_array's contract: the engine
+                        # always sees float32 (exact for uint8/int16
+                        # payloads).
+                        left = np.ascontiguousarray(req.left, np.float32)
+                        right = np.ascontiguousarray(req.right,
+                                                     np.float32)
+                        payload = req.fields
+                        # the decoded planes go here, not in the
+                        # parent's tail
+                        del dec, req
                 else:
                     payload = json.loads(raw)
                     left = decode_array(payload["left"])
@@ -783,6 +823,8 @@ class _Handler(JsonRequestHandler):
                              endpoint, rid, t_req0)
                 return None
             del raw, payload
+        finally:
+            srv.decode_slots.release()
         return (left, right, iters, session_id, seq_no, deadline_ms,
                 priority, accuracy, spatial)
 
@@ -1293,11 +1335,14 @@ class StereoServer(ThreadingHTTPServer):
             max(4, config.max_batch_size))
         # One binary reply is encoded at a time (the write is outside).
         # A batch's replies all become ready in the same millisecond,
-        # while the worker stages the next batch; the encode is a few
-        # ms of memory-bound host work that gains nothing from running
+        # while the worker stages the next batch; the encode is
+        # memory-bound host work (the median `reply_encode` span on a
+        # v5e host: 4.5 ms a 540x960 reply, 18-23 ms at 1080p, 109 ms
+        # at 1988x2964; PERF.md §5) that gains nothing from running
         # eight at once, and eight at once cost the staging beside them
-        # 8 ms a dispatch (PERF.md §6, PR 29).  In turn they leave a few
-        # ms apart, and so do the requests that answer them.
+        # 8 ms a dispatch (PERF.md §6).  In turn they leave one encode
+        # apart, and so do the requests that answer them: at 1080p the
+        # median `reply_wait` is 53-73 ms.
         self.reply_encode = threading.Lock()
         super().__init__((config.host, config.port), _Handler)
 

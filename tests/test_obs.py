@@ -557,8 +557,7 @@ class TestEndToEnd:
                       and set(s.attrs["request_ids"]) & set(rids)]
         assert sum(s.attrs["rows"] for s in batch_pads) == 5
         for s in batch_pads:
-            assert s.attrs["rows"] == s.attrs["batch_size"] \
-                == len(s.attrs["request_ids"])
+            assert s.attrs["rows"] == len(s.attrs["request_ids"])
             assert s.attrs["real_px"] == s.attrs["bucket_px"]
         after = {lv[0]: c.value
                  for lv, c in server.metrics.batch_rows.series()}
@@ -640,6 +639,11 @@ def _profile_events(log_dir):
     return out
 
 
+def _without_thread_ms(attrs):
+    return {k: v for k, v in attrs.items()
+            if k not in ("cpu_ms", "runq_ms")}
+
+
 class TestPhase:
     def test_phase_is_one_ring_span_and_one_profiler_event(self, tmp_path):
         """Inside a capture ``phase()`` leaves one span in the ring and one
@@ -658,7 +662,9 @@ class TestPhase:
             _stop_capture()
         (span,) = tracer.spans()
         assert span.name == "unit_phase" and span.trace_id == "batch:7"
-        assert span.attrs == {"rows": 3}
+        assert _without_thread_ms(span.attrs) == {"rows": 3}
+        # a sleeping phase: next to no CPU time
+        assert 0.0 <= span.attrs["cpu_ms"] < 0.5 * span.duration_s * 1e3
         events = _profile_events(str(tmp_path))
         ((start_ns, dur_ns, stats),) = events["unit_phase"]
         assert stats["rows"] == 3
@@ -689,7 +695,7 @@ class TestPhase:
             pass
         assert ph.t1 >= ph.t0 > 0 and ph.window == (ph.t0, ph.t1)
         (span,) = tracer.spans()
-        assert span.attrs == {"obj": h, "late": 1}
+        assert _without_thread_ms(span.attrs) == {"obj": h, "late": 1}
         n = 5000
         t0 = time.perf_counter()
         for _ in range(n):
@@ -698,10 +704,70 @@ class TestPhase:
                 pass
         per_phase = (time.perf_counter() - t0) / n
         assert per_phase < 200e-6
-        # A dispatch adds twelve phases (three live in the batcher, five
-        # recorded, four timed in the engine) and each of its requests
-        # two: under the contract's 2 % of even a 10 ms dispatch.
-        assert (12 + 2 * 8) * per_phase < 0.02 * 0.25
+        # A dispatch adds sixteen phases (three live in the batcher, seven
+        # recorded, six timed in the engine) and each of its binary
+        # requests eight (wire_decode and reply, three children each):
+        # under the contract's 2 % of even a 250 ms dispatch of eight.
+        assert (16 + 8 * 8) * per_phase < 0.02 * 0.25
+
+    def test_cpu_ms_is_the_wall_when_spinning_and_none_when_asleep(self):
+        """``cpu_ms`` never exceeds the phase's wall time: it is about
+        the wall for a phase that spins and about 0 for one that sleeps,
+        in ``Tracer.phase`` and ``timed_phase`` alike."""
+        from raftstereo_tpu.obs.trace import timed_phase
+
+        def spin(s):        # s seconds of this thread's CPU
+            t_end = time.thread_time() + s
+            while time.thread_time() < t_end:
+                pass
+
+        tracer = Tracer(capacity=8)
+        with tracer.phase("spin", trace_id="t"):
+            spin(0.05)
+        with tracer.phase("sleep", trace_id="t"):
+            time.sleep(0.05)
+        with timed_phase("spin") as ph:
+            spin(0.05)
+        spun, slept = tracer.spans()
+        for wall_s, cpu_ms in ((spun.duration_s, spun.attrs["cpu_ms"]),
+                               (ph.t1 - ph.t0, ph.thread_ms["cpu_ms"])):
+            # a loaded host may keep a spinning thread off its core a
+            # while: the wall then outgrows the CPU time, never under it
+            assert 50.0 <= cpu_ms <= wall_s * 1e3
+            assert cpu_ms > 0.25 * wall_s * 1e3
+        assert 0.0 <= slept.attrs["cpu_ms"] < 0.1 * slept.duration_s * 1e3
+        # a plain span and an after-the-fact record time nothing
+        with tracer.span("plain", trace_id="t"):
+            pass
+        tracer.record("window", 0.0, 1.0, "t")
+        assert all("cpu_ms" not in s.attrs for s in tracer.spans()[2:])
+
+    def test_runq_ms_where_schedstat_reads_and_absent_where_not(
+            self, monkeypatch):
+        """``runq_ms`` is on a phase where ``/proc/thread-self/schedstat``
+        can be read (Linux), between 0 and the wall; where it cannot be
+        read the phase leaves it out and raises nothing."""
+        from raftstereo_tpu.obs import trace
+
+        def one_phase(out):
+            tracer = Tracer(capacity=2)
+            with tracer.phase("p", trace_id="t"):
+                time.sleep(0.002)
+            out.append(tracer.spans()[0])
+
+        def in_new_thread():        # the file is opened once per thread
+            out = []
+            t = threading.Thread(target=one_phase, args=(out,))
+            t.start()
+            t.join(10)
+            return out[0]
+
+        span = in_new_thread()
+        if os.path.exists("/proc/thread-self/schedstat"):
+            assert 0.0 <= span.attrs["runq_ms"] <= span.duration_s * 1e3
+        monkeypatch.setattr(trace, "_SCHEDSTAT", "/nonexistent/schedstat")
+        span = in_new_thread()
+        assert "runq_ms" not in span.attrs and "cpu_ms" in span.attrs
 
     def test_unsampled_span_guard_still_holds_for_record(self):
         tracer = Tracer(capacity=4)
@@ -768,7 +834,8 @@ class TestWorkerPhases:
         finisher = [by[n] for n in ("device_wait", "host_fetch",
                                     "reply_handoff")]
         assert sorted(by) == sorted(
-            [s.name for s in launcher + finisher] + ["device_queued"])
+            [s.name for s in launcher + finisher]
+            + ["device_queued", "stage_copy", "h2d_put"])
         for thread in (launcher, finisher):
             assert _holes(thread) < 1e-3, [s.name for s in thread]
             assert all(b.t0 >= a.t0 for a, b in zip(thread, thread[1:]))
@@ -780,7 +847,7 @@ class TestWorkerPhases:
         assert by["batch_form"].attrs["batch_size"] == 2
         assert by["batch_form"].attrs["ahead"] is False
         assert by["launch"].attrs["request_ids"] == ["req0", "req1"]
-        assert by["launch"].attrs["bucket"] == "64x128"
+        assert by["batch_form"].attrs["bucket"] == "64x128"
         assert by["launch"].attrs["ahead"] is False
         # the per-request copies: one set a request, and launch +
         # device_wait are what device_compute spans
@@ -791,6 +858,36 @@ class TestWorkerPhases:
                              "queue_wait"]
         dc = next(s for s in spans if s.name == "device_compute")
         assert dc.t0 == by["launch"].t0 and dc.t1 == by["device_wait"].t1
+
+    def test_staging_parts_tile_pad_bucket_once_per_dispatch(self):
+        """``stage_copy`` (the host copies) and ``h2d_put`` (the
+        transfers) tile ``pad_bucket``, once a dispatch under the batch's
+        trace, with the row count; every phase the engine and the
+        launcher timed carries its thread's CPU time, within its wall."""
+        spans = self._serve(3, max_wait_ms=2000.0)
+        batch = [s for s in spans if s.trace_id.startswith("batch:")]
+        pads = [s for s in batch if s.name == "pad_bucket"]
+        assert sorted(s.attrs["rows"] for s in pads) == [1, 2]
+        for pad in pads:
+            parts = sorted((s for s in batch if s.trace_id == pad.trace_id
+                            and s.name in ("stage_copy", "h2d_put")),
+                           key=lambda s: s.t0)
+            assert [s.name for s in parts] == ["stage_copy", "h2d_put"]
+            assert all(s.attrs["rows"] == pad.attrs["rows"] for s in parts)
+            assert parts[0].t0 >= pad.t0 and parts[-1].t1 <= pad.t1
+            holes = (parts[0].t0 - pad.t0) + _holes(parts) \
+                + (pad.t1 - parts[-1].t1)
+            assert holes < 1e-3
+        # no request trace holds the two parts: they are the batch's
+        assert not [s for s in spans if not s.trace_id.startswith("batch:")
+                    and s.name in ("stage_copy", "h2d_put")]
+        timed = ("batch_form", "pad_bucket", "stage_copy", "h2d_put",
+                 "launch", "host_fetch", "reply_handoff")
+        for s in batch:
+            if s.name in timed:
+                assert 0.0 <= s.attrs["cpu_ms"] <= s.duration_s * 1e3, s
+            if s.name == "device_queued":     # a window no phase timed
+                assert "cpu_ms" not in s.attrs
 
     def test_a_request_launched_ahead_sums_and_fills(self):
         """req0 rides alone and is held in ``finish``; req1 and req2
@@ -864,6 +961,30 @@ class TestWorkerPhases:
         empty = [s for s in spans if s.name == "queue_empty"]
         assert empty and empty[0].trace_id == form.trace_id
         assert empty[0].t1 <= form.t0 + 1e-4
+        # one clock read, the request's enqueue, ends one and starts the
+        # other: the deadline counts from there
+        assert empty[0].t1 == form.t0
+
+
+class _AskedLock:
+    """A lock or semaphore that says when someone first asked for it."""
+
+    def __init__(self, lock):
+        self.lock = lock
+        self.asked = threading.Event()
+
+    def acquire(self):
+        self.asked.set()
+        return self.lock.acquire()
+
+    def release(self):
+        self.lock.release()
+
+
+def _predict_into(server, out):
+    client = ServeClient("127.0.0.1", server.port, timeout=120)
+    out["rid"] = client.predict(_img(), _img(seed=1))[1]["request_id"]
+    client.close()
 
 
 class TestRequestPhases:
@@ -891,6 +1012,104 @@ class TestRequestPhases:
                     and rid in s.attrs.get("request_ids", ())]
         assert len(launches) == 1
         assert launches[0].trace_id.startswith("batch:")
+
+    @staticmethod
+    def _spans_of(server, rid, last="reply"):
+        deadline = time.time() + 5      # `reply` closes after the write
+        while time.time() < deadline:
+            spans = server.tracer.spans(trace_id=rid)
+            if any(s.name == last for s in spans):
+                return spans
+            time.sleep(0.01)
+        raise AssertionError(f"no {last} span for {rid}")
+
+    def test_binary_children_tile_wire_decode_and_reply(self, obs_server):
+        """On a binary request ``decode_slot_wait -> body_read -> widen``
+        tile ``wire_decode`` and ``reply_wait -> reply_encode ->
+        reply_write`` tile ``reply``, holes under 1 ms in total; each
+        child is a phase with its thread's times."""
+        client = ServeClient("127.0.0.1", obs_server.port, timeout=120)
+        _, meta = client.predict(_img(), _img(seed=1))
+        client.close()
+        spans = self._spans_of(obs_server, meta["request_id"])
+        by = {s.name: s for s in spans}
+        for parent, names in (
+                ("wire_decode", ("decode_slot_wait", "body_read", "widen")),
+                ("reply", ("reply_wait", "reply_encode", "reply_write"))):
+            top = by[parent]
+            kids = [by[n] for n in names]
+            assert all(k.parent_id == top.span_id for k in kids)
+            assert all(b.t0 >= a.t1 for a, b in zip(kids, kids[1:]))
+            assert kids[0].t0 >= top.t0 and kids[-1].t1 <= top.t1
+            holes = (kids[0].t0 - top.t0) + _holes(kids) \
+                + (top.t1 - kids[-1].t1)
+            assert holes < 1e-3, (parent, holes)
+            for k in (top, *kids):
+                assert 0.0 <= k.attrs["cpu_ms"] <= k.duration_s * 1e3, k
+
+    def test_reply_wait_reads_the_lock_held(self, obs_server):
+        """With the reply lock held 50 ms after the handler asked for it,
+        ``reply_wait`` reads at least that, and so does
+        ``serve_host_wait_seconds_total{point="reply_lock"}``."""
+        spy = _AskedLock(obs_server.reply_encode)
+        obs_server.reply_encode = spy
+        before = obs_server.metrics.host_wait.labels(
+            point="reply_lock").value
+        out = {}
+        try:
+            with spy.lock:
+                t = threading.Thread(target=_predict_into,
+                                     args=(obs_server, out))
+                t.start()
+                assert spy.asked.wait(60)
+                time.sleep(0.05)
+            t.join(60)
+        finally:
+            obs_server.reply_encode = spy.lock
+        spans = self._spans_of(obs_server, out["rid"])
+        (wait,) = [s for s in spans if s.name == "reply_wait"]
+        assert wait.duration_s >= 0.05
+        after = obs_server.metrics.host_wait.labels(point="reply_lock").value
+        assert after - before >= 0.05
+
+    def test_decode_slot_wait_grows_when_every_slot_is_taken(
+            self, obs_server):
+        """A request that finds a decode slot free waits next to nothing;
+        with every slot taken its ``decode_slot_wait`` holds the time
+        until one is given back, and ``serve_host_wait_seconds_total``
+        counts both points and renders validator-clean."""
+        free = {}
+        _predict_into(obs_server, free)
+        (quick,) = [s for s in self._spans_of(obs_server, free["rid"])
+                    if s.name == "decode_slot_wait"]
+        spy = _AskedLock(obs_server.decode_slots)
+        obs_server.decode_slots = spy
+        slots = max(4, obs_server.config.max_batch_size)
+        out = {}
+        try:
+            for _ in range(slots):
+                spy.lock.acquire()
+            t = threading.Thread(target=_predict_into,
+                                 args=(obs_server, out))
+            t.start()
+            assert spy.asked.wait(60)
+            time.sleep(0.05)
+            for _ in range(slots):
+                spy.lock.release()
+            t.join(60)
+        finally:
+            obs_server.decode_slots = spy.lock
+        (slow,) = [s for s in self._spans_of(obs_server, out["rid"])
+                   if s.name == "decode_slot_wait"]
+        assert slow.duration_s >= 0.05 > quick.duration_s
+        scrape = parse_text(obs_server.metrics.render())
+        for point in ("decode_slot", "reply_lock"):
+            assert scrape.value("serve_host_wait_seconds_total",
+                                point=point) >= 0.0
+        assert scrape.value("serve_host_wait_seconds_total",
+                            point="decode_slot") >= 0.05
+        assert validate_prometheus(obs_server.metrics.render()) == []
+        assert lint_registry(obs_server.metrics.registry.entries()) == []
 
     def test_reply_closes_when_the_client_hangs_up(self, obs_server):
         from raftstereo_tpu.serve.server import _Handler
